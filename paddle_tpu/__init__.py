@@ -8,8 +8,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-from .utils import jax_compat as _jax_compat  # noqa: F401 — pre-import shims
-
 from .framework import (
     Tensor, Parameter, no_grad, enable_grad, is_grad_enabled, to_tensor,
     set_device, get_device, seed, get_rng_state, set_rng_state,

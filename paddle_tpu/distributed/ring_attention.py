@@ -323,13 +323,13 @@ def _ring_fwd(q, k, v, axis_name, causal, scale, use_pallas, zigzag):
         v_nxt = jax.lax.ppermute(v_cur, axis_name, perm)
         return (out, lse_new, k_nxt, v_nxt), None
 
-    # pvary: zero-init carries are axis-invariant constants, but the scan
+    # pcast to varying: zero-init carries are axis-invariant constants, but the scan
     # writes axis-varying values into them — required typing under the
     # (default) vma checker when shard_map is manual over a subset axis
-    out0 = jax.lax.pvary(jnp.zeros((b, s, h, d), jnp.float32),
-                         (axis_name,))
-    lse0 = jax.lax.pvary(jnp.full((b, h, s), _NEG_INF, jnp.float32),
-                         (axis_name,))
+    out0 = jax.lax.pcast(jnp.zeros((b, s, h, d), jnp.float32),
+                         (axis_name,), to="varying")
+    lse0 = jax.lax.pcast(jnp.full((b, h, s), _NEG_INF, jnp.float32),
+                         (axis_name,), to="varying")
     (out, lse, _, _), _ = jax.lax.scan(
         step, (out0, lse0, k, v), jnp.arange(n))
     return out.astype(q.dtype), lse
@@ -381,9 +381,11 @@ def _ring_core_bwd(axis_name, causal, scale, use_pallas, zigzag, res,
         dv_nxt = jax.lax.ppermute(dv_cur, axis_name, perm)
         return (dq, k_nxt, v_nxt, dk_nxt, dv_nxt), None
 
-    dq0 = jax.lax.pvary(jnp.zeros(q.shape, jnp.float32), (axis_name,))
-    dk0 = jax.lax.pvary(jnp.zeros(k.shape, jnp.float32), (axis_name,))
-    dv0 = jax.lax.pvary(jnp.zeros(v.shape, jnp.float32), (axis_name,))
+    def zeros(x):
+        return jax.lax.pcast(jnp.zeros(x.shape, jnp.float32),
+                             (axis_name,), to="varying")
+
+    dq0, dk0, dv0 = zeros(q), zeros(k), zeros(v)
     (dq, _, _, dk, dv), _ = jax.lax.scan(
         step, (dq0, k, v, dk0, dv0), jnp.arange(n))
     # after n hops the dk/dv accumulators are back at their home shard

@@ -74,7 +74,8 @@ def pallas_attention_plan(q, k, min_seq: int = 512):
     source of truth — flash_attention, flash_attention_segmented, and
     ring attention all route through here). Returns (block_q, block_k)
     when the kernel applies, else None."""
-    if jax.default_backend() in ("cpu", "gpu"):
+    from .pallas import interpret
+    if interpret():
         return None
     from ..utils.flags import FLAGS
     if not getattr(FLAGS, "use_pallas_kernels", True):

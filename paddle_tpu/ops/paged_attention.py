@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["KVCacheExhausted", "PagedKVCache", "paged_attention_decode",
-           "paged_attention_decode_reference", "quantize_kv_rows",
+           "paged_attention_decode_reference", "paged_attention_impl",
+           "quantize_kv_rows",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "reshape_and_cache"]
 
@@ -240,12 +241,12 @@ def ragged_paged_attention_reference(q, k_cache, v_cache, block_tables,
 def ragged_paged_attention(q, k_cache, v_cache, block_tables, row_seq,
                            row_ctx, scale: Optional[float] = None):
     """Ragged mixed prefill+decode attention; Pallas scalar-prefetch
-    kernel on TPU, jnp oracle elsewhere (CPU, or
-    FLAGS.use_pallas_kernels=False). Kernel eligibility is the decode
-    kernel's policy — same pool layout, same tiling constraints.
-    Quantized pools ((int8, scales) tuples) route to the kernel too:
-    the sidecar scales ride each page's DMA and dequant happens in
-    VMEM (see pallas/ragged_paged_attention.py)."""
+    kernel where paged_attention_impl admits it, jnp oracle elsewhere
+    (CPU, FLAGS.use_pallas_kernels=False, head_dim not a multiple of
+    128, quantized pools). The kernel's dequant-in-VMEM path for
+    (int8, scales) pools is kept and interpret-tested, but the chip's
+    compiler refuses its sidecar slice, so the gate keeps such pools
+    off it (ROADMAP S3)."""
     if _pallas_decode_ok(q, k_cache):
         from .pallas.ragged_paged_attention import \
             ragged_paged_attention_pallas
@@ -833,28 +834,47 @@ class PagedKVCache:
         self.v[layer] = nv
 
 
-def _pallas_decode_ok(q, k_cache):
-    if jax.default_backend() in ("cpu", "gpu"):
-        return False
+def paged_attention_impl(head_dim: int, block_size: int,
+                         quantized: bool) -> str:
+    """Which implementation the paged-attention entry points take for
+    a pool of this geometry: "pallas", or "reference (<why>)". The
+    kernel is admitted only where the chip's compiler accepts it: the
+    v5e's Mosaic refuses the HBM page slice [1, kvh, bs, 64] of a
+    head_dim-64 pool and the [1, kvh, bs] scale-sidecar slice of an
+    int8 pool ("must be aligned to tiling (128)" — ROADMAP S3), so
+    those two serve through the jnp reference until the kernels are
+    repaired. The serving engine logs this string per program family
+    at construction."""
+    from .pallas import interpret
+    if interpret():
+        return f"reference (backend {jax.default_backend()})"
     from ..utils.flags import FLAGS
     if not getattr(FLAGS, "use_pallas_kernels", True):
-        return False
-    d = q.shape[-1]
+        return "reference (FLAGS.use_pallas_kernels off)"
+    if quantized:
+        return "reference (int8 KV pool: kernel refused by Mosaic)"
+    if head_dim % 128:
+        return (f"reference (head_dim {head_dim} not a multiple of "
+                f"128: kernel refused by Mosaic)")
+    if block_size % 8:
+        return f"reference (block_size {block_size} not a multiple of 8)"
+    return "pallas"
+
+
+def _pallas_decode_ok(q, k_cache):
     # layout [num_blocks, kv_heads, block_size, d] (tuple-aware)
-    bs = _plane_values(k_cache).shape[2]
-    return d in (64, 128, 256) and bs % 8 == 0
+    return paged_attention_impl(
+        q.shape[-1], _plane_values(k_cache).shape[2],
+        isinstance(k_cache, tuple)) == "pallas"
 
 
 def paged_attention_decode(q, k_cache, v_cache, block_tables, context_lens,
                            scale: Optional[float] = None):
     """One-token decode attention over the paged cache; Pallas
-    scalar-prefetch kernel on TPU, jnp reference elsewhere. See
-    paged_attention_decode_reference for the signature. Quantized
-    pools run the reference path everywhere: the DENSE decode kernel
-    predates the sidecar-scale layout, and serving's TPU hot path is
-    the ragged program (whose kernel fuses the dequant) — the dense
-    per-phase scheduler is the CPU/debug fallback there."""
-    if not isinstance(k_cache, tuple) and _pallas_decode_ok(q, k_cache):
+    scalar-prefetch kernel where paged_attention_impl admits it, jnp
+    reference elsewhere. See paged_attention_decode_reference for the
+    signature."""
+    if _pallas_decode_ok(q, k_cache):
         from .pallas.paged_attention import paged_attention_decode_pallas
         return paged_attention_decode_pallas(q, k_cache, v_cache,
                                              block_tables, context_lens,

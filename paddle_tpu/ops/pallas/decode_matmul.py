@@ -20,16 +20,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import interpret as _interpret
+
 __all__ = ["decode_matmul", "decode_matmul_supported"]
 
 _MAX_ROWS = 32
 # per-buffer VMEM budget for one weight tile (double-buffered by the
 # pipeline; keep well under half of ~16 MB)
 _TILE_BYTES = 2 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pick_tile(dim: int, limit: int, cap: int = 2048,
@@ -64,7 +62,7 @@ def decode_matmul_supported(x, w) -> bool:
     """True when (x, w) fits this kernel: TPU backend, 2-d x with few
     rows, and a cleanly tiling K x N (w dense, or (int8, scale) /
     (int4-packed, scale) pairs)."""
-    if not _on_tpu() or x.ndim != 2 or x.shape[0] > _MAX_ROWS:
+    if _interpret() or x.ndim != 2 or x.shape[0] > _MAX_ROWS:
         return False
     K = x.shape[1]
     if isinstance(w, tuple):

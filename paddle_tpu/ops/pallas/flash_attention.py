@@ -38,17 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    # interpreter mode on non-TPU backends (CPU tests / numerics oracle)
-    return jax.default_backend() != "tpu" and not _on_tpu()
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu", "gpu")
-    except Exception:
-        return False
+from . import interpret as _interpret
 
 
 def _sds(shape, dtype, like):

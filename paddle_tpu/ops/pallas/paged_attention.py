@@ -29,18 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
+
 _NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu" and not _on_tpu()
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu", "gpu")
-    except Exception:
-        return False
 
 
 def _decode_kernel(tables_ref, ctx_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -149,8 +140,8 @@ def paged_attention_decode_pallas(q, k_cache, v_cache, block_tables,
         in_specs=[
             pl.BlockSpec((1, kvh, group, d),
                          lambda bi, tbl, ctx: (bi, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, kvh, group, d),
                                lambda bi, tbl, ctx: (bi, 0, 0, 0)),

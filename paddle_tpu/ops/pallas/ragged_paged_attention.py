@@ -37,18 +37,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
+
 _NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu" and not _on_tpu()
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform not in ("cpu", "gpu")
-    except Exception:
-        return False
 
 
 def _ragged_kernel(rowseq_ref, rowctx_ref, tables_ref, q_ref, *refs,
@@ -251,8 +242,8 @@ def ragged_paged_attention_pallas(q, k_cache, v_cache, block_tables,
     in_specs = [
         pl.BlockSpec((kvh, 1, tq * group, d),
                      lambda gi, rs_, rc_, tb_: (0, gi, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
     ]
     scratch_shapes = [
         pltpu.VMEM((2, kvh, P * bs, d), k_cache.dtype),
@@ -264,8 +255,8 @@ def ragged_paged_attention_pallas(q, k_cache, v_cache, block_tables,
     if quantized:
         # scale sidecars: HBM-resident like the pools, double-buffered
         # [kvh, P*bs] f32 VMEM slices, one DMA semaphore pair more
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec(memory_space=pl.ANY)]
         scratch_shapes += [pltpu.VMEM((2, kvh, P * bs), jnp.float32),
                            pltpu.VMEM((2, kvh, P * bs), jnp.float32)]
         sems += [pltpu.SemaphoreType.DMA((2, P)),

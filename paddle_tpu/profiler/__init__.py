@@ -57,8 +57,12 @@ def device_trace_summary(trace_dir: str) -> dict:
     procs = {e["pid"]: e["args"]["name"] for e in evs
              if e.get("ph") == "M" and e.get("name") == "process_name"
              and "name" in e.get("args", {})}
+    # /device:CUSTOM:* planes (e.g. the "Megascale Trace" libtpu adds
+    # to every capture once the TPU library is loaded, chip or not)
+    # are host-side bookkeeping, not accelerator timelines
     dev_pids = {pid for pid, nm in procs.items()
-                if "/device:" in nm and "CPU" not in nm}
+                if "/device:" in nm and "CPU" not in nm
+                and "CUSTOM" not in nm}
     kernels = Counter()
     n = 0
     for e in evs:

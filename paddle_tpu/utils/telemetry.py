@@ -698,7 +698,7 @@ class SLOMonitor:
 
 class Tracer:
     """Flight recorder + span tracer. See the module docstring for the
-    taxonomy; the record stream is a bounded deque of small dicts:
+    vocabulary; the record stream is a bounded deque of small dicts:
 
     - ``{"kind": "begin"/"end", "name": "request", "trace": id, ...}``
       — request lifecycle (async span endpoints);

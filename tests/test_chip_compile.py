@@ -1,0 +1,160 @@
+"""The main path's Pallas kernels compile for the chip, checked without one.
+
+The TPU compiler is installed beside jax and compiles for a DESCRIBED
+v5e (``jax.experimental.topologies``), so what Mosaic refuses on the
+chip it refuses here: interpret-mode tests cannot see a page slice that
+is not aligned to the tiling. Shapes are Llama-3-8B's serving heads
+(32 q / 8 kv, head_dim 128, bf16 pool) at block 16 and at the block
+``chip_smoke.py`` uses, ``llama_mid``'s training attention, and the int4
+weight-streaming matmul at the 8B MLP and vocabulary shapes. Two more
+tests pin the gate that keeps the two refused pools (head_dim 64, int8
+KV) off the kernels.
+
+All of it lives in this one file: the worker that runs it loads the TPU
+library and keeps it until it exits.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+
+BF16 = jnp.bfloat16
+SMOKE_BLOCK = chip_smoke.serve_config(tiny=False)[1]["block_size"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """Steer the repo's backend gates to their chip branch (they all ask
+    jax.default_backend()), and keep these compiles out of the
+    persistent cache: an entry compiled for a described chip cannot be
+    read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernels_in(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _sds(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _pool(sharding, block):
+    """A bf16 K or V pool at Llama-3-8B's 8 kv heads of 128."""
+    return _sds(sharding, (256, 8, block, 128), BF16)
+
+
+@pytest.mark.parametrize("block", sorted({16, SMOKE_BLOCK}))
+def test_ragged_paged_attention_llama3_8b_heads(one_chip, on_chip, block):
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    rows = 256              # one idle-drain prefill program's width
+    kv = _pool(one_chip, block)
+    assert _kernels_in(
+        ragged_paged_attention, _sds(one_chip, (rows, 32, 128), BF16),
+        kv, kv, _sds(one_chip, (5, 8192 // block)),
+        _sds(one_chip, (rows,)), _sds(one_chip, (rows,))) == 1
+
+
+@pytest.mark.parametrize("block", sorted({16, SMOKE_BLOCK}))
+def test_paged_attention_decode_llama3_8b_heads(one_chip, on_chip, block):
+    from paddle_tpu.ops.paged_attention import paged_attention_decode
+    kv = _pool(one_chip, block)
+    assert _kernels_in(
+        paged_attention_decode, _sds(one_chip, (8, 32, 128), BF16), kv,
+        kv, _sds(one_chip, (8, 8192 // block)), _sds(one_chip, (8,))) == 1
+
+
+def test_flash_attention_fwd_bwd_llama_mid(one_chip, on_chip):
+    from paddle_tpu.ops.flash_attention import flash_attention
+    q = _sds(one_chip, (4, 2048, 16, 128), BF16)
+    kv = _sds(one_chip, (4, 2048, 8, 128), BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    # forward, dq, dk/dv
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+@pytest.mark.parametrize("k,n", [(4096, 14336), (4096, 128256)])
+def test_decode_matmul_int4(one_chip, on_chip, k, n):
+    from paddle_tpu.ops.pallas.decode_matmul import (decode_matmul,
+                                                     decode_matmul_supported)
+    x = _sds(one_chip, (8, k), BF16)
+    w = (_sds(one_chip, (k // 2, n), jnp.int8),
+         _sds(one_chip, (n,), jnp.float32))
+    assert decode_matmul_supported(x, w)
+    assert _kernels_in(decode_matmul, x, w) == 1
+
+
+# -- the gate: what the chip's compiler refuses never reaches it ------------
+
+def test_gate_refuses_head_dim_64(on_chip):
+    from paddle_tpu.ops.paged_attention import (_pallas_decode_ok,
+                                                paged_attention_impl)
+    q = jax.ShapeDtypeStruct((8, 16, 64), BF16)
+    pool = jax.ShapeDtypeStruct((64, 16, 16, 64), BF16)
+    assert not _pallas_decode_ok(q, pool)
+    assert paged_attention_impl(64, 16, False).startswith("reference")
+    assert paged_attention_impl(128, 16, False) == "pallas"
+
+
+def test_gate_refuses_quantized_pool(on_chip):
+    from paddle_tpu.ops.paged_attention import (_pallas_decode_ok,
+                                                paged_attention_impl)
+    q = jax.ShapeDtypeStruct((8, 32, 128), BF16)
+    pool = (jax.ShapeDtypeStruct((64, 8, 16, 128), jnp.int8),
+            jax.ShapeDtypeStruct((64, 8, 16), jnp.float32))
+    assert not _pallas_decode_ok(q, pool)
+    assert _pallas_decode_ok(q, pool[0])
+    assert paged_attention_impl(128, 16, True).startswith("reference")
+
+
+def test_engine_names_the_reference_path_loudly(on_chip, caplog):
+    """A pool the kernels are refused for serves through the reference
+    by a logged decision: one WARNING line at engine construction
+    naming the implementation of every program family."""
+    import logging
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingEngine
+    from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_tiny())          # head_dim 32
+    with caplog.at_level(logging.INFO, logger="paddle_tpu.serving"):
+        eng = ServingEngine(model, num_blocks=16, block_size=8,
+                            ragged=True)
+    assert eng.attention_impls["ragged"].startswith(
+        "reference (head_dim 32")
+    assert eng.attention_impls["decode"] == eng.attention_impls["ragged"]
+    assert eng.attention_impls["prefill"] == "flash_attention"
+    [rec] = [r for r in caplog.records if "attention per program" in
+             r.getMessage()]
+    assert rec.levelno == logging.WARNING
+    assert "ragged=reference (head_dim 32" in rec.getMessage()

@@ -48,6 +48,16 @@ class TestDeviceNamespace:
         assert not paddle.device.is_compiled_with_cuda()
         assert paddle.device.cuda.device_count() == 0
 
+    def test_set_device_without_accelerator_raises(self):
+        """Asking for an accelerator and finding none is an error, not
+        a CPU device under the accelerator's name."""
+        before = paddle.get_device()
+        for name in ("tpu", "gpu:0", "accelerator"):
+            with pytest.raises(RuntimeError, match="no accelerator"):
+                paddle.set_device(name)
+        assert paddle.get_device() == before
+        assert paddle.set_device("cpu").platform == "cpu"
+
     def test_stream_event_noop_api(self):
         s = paddle.device.current_stream()
         e = s.record_event()
