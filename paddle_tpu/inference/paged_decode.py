@@ -985,46 +985,53 @@ class PagedLlamaDecoder(_TPDecoderMixin, _SpecDecodeMixin, _LoRAMixin):
         Returns (logits [rows, vocab], k_pool, v_pool)."""
         cfg = self.cfg
         r = ids.shape[0]
-        h = jnp.take(weights["embed"], ids, axis=0)        # [r, d]
+        # the named scopes are LlamaForCausalLM's, so a device trace of
+        # either hot path groups by the same names
+        with jax.named_scope("embed"):
+            h = jnp.take(weights["embed"], ids, axis=0)    # [r, d]
         # clamp like the chunked-prefill programs: pad rows of a tail
         # chunk may carry positions past max_position_embeddings
         pos = jnp.minimum(positions,
                           cfg.max_position_embeddings - 1)[:, None]
+        k_pool = list(k_pool)
+        v_pool = list(v_pool)
         for li, w in enumerate(weights["layers"]):
-            hn = rms_norm(h, w["ln1"], cfg.rms_norm_eps)
-            q, k, v = self._proj_qkv(w, hn[:, None, :], r, 1)
-            if lora is not None:
-                q = q + self._lora_delta(lora, row_seq, hn, li,
-                                         "wq").reshape(q.shape)
-                k = k + self._lora_delta(lora, row_seq, hn, li,
-                                         "wk").reshape(k.shape)
-                v = v + self._lora_delta(lora, row_seq, hn, li,
-                                         "wv").reshape(v.shape)
-            q = self._rope(q, pos)[:, 0]                   # [r, nh, d]
-            k = self._rope(k, pos)[:, 0]                   # [r, kvh, d]
-            v = v[:, 0]
-            from ..ops.paged_attention import reshape_and_cache
-            kp, vp = reshape_and_cache(k, v, k_pool[li], v_pool[li],
-                                       slots)
-            k_pool = list(k_pool)
-            v_pool = list(v_pool)
-            k_pool[li] = kp
-            v_pool[li] = vp
-            attn = ragged_paged_attention(q, kp, vp, tables, row_seq,
-                                          row_ctx)
-            af = attn.reshape(r, self._attn_dim)
-            o = _mm(af, w["wo"], self._allow_kernel)
-            if lora is not None:
-                o = o + self._lora_delta(lora, row_seq, af, li, "wo")
-            h = h + self._block_reduce(o)
-            hn = rms_norm(h, w["ln2"], cfg.rms_norm_eps)
-            mlp = self._mlp(w, hn) if lora is None \
-                else self._lora_mlp(w, hn, lora, row_seq, li)
-            h = h + self._block_reduce(mlp)
-        h = rms_norm(h, weights["norm"], cfg.rms_norm_eps)
-        logits = self._gather_logits(
-            _mm(h, weights["head"],
-                self._allow_kernel).astype(jnp.float32))
+            with jax.named_scope(f"layer{li}/attn"):
+                hn = rms_norm(h, w["ln1"], cfg.rms_norm_eps)
+                q, k, v = self._proj_qkv(w, hn[:, None, :], r, 1)
+                if lora is not None:
+                    q = q + self._lora_delta(lora, row_seq, hn, li,
+                                             "wq").reshape(q.shape)
+                    k = k + self._lora_delta(lora, row_seq, hn, li,
+                                             "wk").reshape(k.shape)
+                    v = v + self._lora_delta(lora, row_seq, hn, li,
+                                             "wv").reshape(v.shape)
+                q = self._rope(q, pos)[:, 0]               # [r, nh, d]
+                k = self._rope(k, pos)[:, 0]               # [r, kvh, d]
+                v = v[:, 0]
+                from ..ops.paged_attention import reshape_and_cache
+                kp, vp = reshape_and_cache(k, v, k_pool[li], v_pool[li],
+                                           slots)
+                k_pool[li] = kp
+                v_pool[li] = vp
+                attn = ragged_paged_attention(q, kp, vp, tables, row_seq,
+                                              row_ctx)
+                af = attn.reshape(r, self._attn_dim)
+                o = _mm(af, w["wo"], self._allow_kernel)
+                if lora is not None:
+                    o = o + self._lora_delta(lora, row_seq, af, li, "wo")
+                h = h + self._block_reduce(o)
+            with jax.named_scope(f"layer{li}/mlp"):
+                hn = rms_norm(h, w["ln2"], cfg.rms_norm_eps)
+                mlp = self._mlp(w, hn) if lora is None \
+                    else self._lora_mlp(w, hn, lora, row_seq, li)
+                h = h + self._block_reduce(mlp)
+        with jax.named_scope("final_norm"):
+            h = rms_norm(h, weights["norm"], cfg.rms_norm_eps)
+        with jax.named_scope("lm_head"):
+            logits = self._gather_logits(
+                _mm(h, weights["head"],
+                    self._allow_kernel).astype(jnp.float32))
         return logits, k_pool, v_pool
 
     def _decode_body(self, weights, k_pool, v_pool, last_ids, tables,

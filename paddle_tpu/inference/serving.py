@@ -123,6 +123,7 @@ needs (a disjoint device slice per tp-sharded replica).
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import os
@@ -137,6 +138,7 @@ import jax.numpy as jnp
 
 from ..framework.core import Tensor
 from ..ops.paged_attention import KVCacheExhausted, paged_attention_impl
+from ..utils import telemetry
 from ..utils.telemetry import (CompileWatch, Reservoir, SLOMonitor,
                                SLOPolicy)
 from .paged_decode import PagedLlamaDecoder
@@ -146,6 +148,67 @@ _log = logging.getLogger("paddle_tpu.serving")
 
 __all__ = ["EngineOverloaded", "SamplingParams", "Request",
            "ServingEngine", "SpecConfig"]
+
+
+# the stats() float each timed phase of an engine step feeds; a phase
+# that is not here (engine.step, .deadlines, .admit, .deliver) is a span
+# only, so the three floats keep the meaning they had before the phases
+# had names
+_PHASE_FLOAT = {
+    "engine.plan": "time_host_s",
+    "engine.dispatch": "time_host_s",
+    "engine.collect": "time_stall_s",
+    "engine.prefill_dispatch": "time_prefill_s",
+    "engine.prefill_collect": "time_prefill_s",
+}
+
+
+class _Phase:
+    """One named phase of an engine step: always adds its wall seconds
+    to the float ``_PHASE_FLOAT`` names and to ``time_by_phase_s``; a
+    ``telemetry.span`` under it records while someone listens. A
+    context manager, because the phases return early."""
+
+    __slots__ = ("eng", "name", "span", "t0")
+
+    def __init__(self, eng, name, attrs):
+        self.eng, self.name = eng, name
+        self.span = telemetry.span(name, tracer=eng.tracer,
+                                   pid=eng.replica_id, **attrs)
+
+    def set(self, **attrs):
+        self.span.set(**attrs)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        field_ = _PHASE_FLOAT.get(self.name)
+        if field_ is not None:
+            dt = time.perf_counter() - self.t0
+            eng = self.eng
+            setattr(eng, field_, getattr(eng, field_) + dt)
+            eng.time_by_phase_s[self.name] = \
+                eng.time_by_phase_s.get(self.name, 0.0) + dt
+        return self.span.__exit__(*exc)
+
+
+def _phased(name):
+    """Run a ServingEngine method as the phase ``name``; the body reaches
+    it as ``self._ph`` (``self._ph.set(T=8)``)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(self, *args, **kw):
+            outer = self._ph
+            with self._phase(name) as self._ph:
+                try:
+                    return fn(self, *args, **kw)
+                finally:
+                    self._ph = outer
+        return run
+    return deco
 
 
 class EngineOverloaded(RuntimeError):
@@ -721,6 +784,12 @@ class ServingEngine:
         self.time_prefill_s = 0.0
         self.time_stall_s = 0.0
         self.time_host_s = 0.0
+        self.time_by_phase_s = {}
+        self._ph = None             # the phase a @_phased method runs as
+        self._step_seq = 0          # engine.step spans' step=
+        self._dispatch_seq = 0      # device programs launched, ever
+        self._seq_cur = 0           # the one last launched or fetched:
+        #                             the dispatch= of request spans
         self._zeros_seen_cache: Dict[int, jax.Array] = {}
         # per-rung measured chunk cost (seconds/chunk), built by warmup;
         # empty → _pick_chunk uses the zero-waste heuristic
@@ -1576,6 +1645,7 @@ class ServingEngine:
                 fams.append(("spec_lora", self._spec_lora_j))
         return fams
 
+    @jax.named_scope("sample")
     def _sample(self, logits, temp, key):
         """In-program sampling: per-slot temperature (<=0 → greedy),
         engine-static top_k."""
@@ -1588,6 +1658,7 @@ class ServingEngine:
             key, logits / t, axis=-1).astype(jnp.int32)
         return jnp.where(temp > 0.0, sampled, greedy)
 
+    @jax.named_scope("sample")
     def _sample_rich(self, logits, temp, key, top_ks, top_ps, rep,
                      seen, allowed=None):
         """Per-request sampling, all mask-based so one compiled program
@@ -1744,7 +1815,7 @@ class ServingEngine:
         self.tracer.span(
             "prefill", req.trace_id, t0, now, pid=self.replica_id,
             epoch=req.epoch, n_cached=int(req.n_cached),
-            recompute=bool(req.resume))
+            recompute=bool(req.resume), dispatch=self._seq_cur)
         req.t_run = now
 
     def _trace_life_end(self, req: Request, reason: str, now: float):
@@ -1758,7 +1829,7 @@ class ServingEngine:
         if req.t_run is not None:
             tr.span("decode", req.trace_id, req.t_run, now,
                     pid=self.replica_id, epoch=req.epoch, reason=reason,
-                    tokens=len(req.out_tokens))
+                    tokens=len(req.out_tokens), dispatch=self._seq_cur)
         elif req.t_life:
             tr.span("prefill", req.trace_id, req.t_life, now,
                     pid=self.replica_id, epoch=req.epoch, reason=reason,
@@ -1772,6 +1843,23 @@ class ServingEngine:
         req.t_run = None
         req.t_life = 0.0
         req.t_wait = None
+
+    # -- phases of a step ----------------------------------------------------
+    def _phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self, name, attrs)
+
+    def _fetch(self, phase: str, kind: str, fn, arg, ch=None):
+        """The blocking fetch of one collection, timed as ``phase``:
+        (result, None), or (None, the _DispatchFailed that survived the
+        retries). ``ch``: the in-flight entry waited for."""
+        attrs = {}
+        if ch is not None:
+            self._seq_cur = attrs["dispatch"] = ch["seq"]
+        with self._phase(phase, **attrs):
+            try:
+                return self._device_call(kind, fn, arg), None
+            except _DispatchFailed as e:
+                return None, e
 
     # -- fault tolerance -----------------------------------------------------
     def _device_call(self, kind: str, fn, *args):
@@ -1834,6 +1922,8 @@ class ServingEngine:
                     # decode / merge / ragged) — the denominator of
                     # stats()["tokens_per_dispatch"]
                     self.device_dispatches += 1
+                    self._dispatch_seq += 1
+                    self._seq_cur = self._dispatch_seq
                 if self._prof_n:
                     self._prof_mark = time.perf_counter()
                 return out
@@ -2572,6 +2662,7 @@ class ServingEngine:
     # (a variant per group size would compile-storm on bursty arrivals)
     PREFILL_GROUP = 4
 
+    @_phased("engine.prefill_dispatch")
     def _dispatch_mid(self, req: Request) -> int:
         """Dispatch ONE fixed-size no-sample prefill chunk (width 1).
         The chunk prefills at global offset n_cached + prefill_sent
@@ -2584,7 +2675,6 @@ class ServingEngine:
         hides pad keys from real queries, so padding is inert).
         Returns the number of real tokens dispatched (0 when the
         dispatch failed and the request was unwound)."""
-        t0 = time.perf_counter()
         cache = self.dec.cache
         c = self.prefill_chunk or self._recompute_chunk
         toks = req.prefill_tokens
@@ -2597,7 +2687,6 @@ class ServingEngine:
             for j in range(take):
                 slots[0, j] = self._extend_with_preempt(req)
         except KVCacheExhausted as e:
-            self.time_prefill_s += time.perf_counter() - t0
             self._fail_request(req, f"KV pool exhausted mid-prefill "
                                     f"with no preemption victim: {e}")
             return 0
@@ -2621,7 +2710,6 @@ class ServingEngine:
                     self.dec.weights, cache.k, cache.v,
                     jnp.asarray(ids), jnp.asarray(slots))
         except _DispatchFailed as e:
-            self.time_prefill_s += time.perf_counter() - t0
             self._fail_request(req, f"prefill dispatch failed after "
                                     f"retries: {e}")
             return 0
@@ -2631,11 +2719,12 @@ class ServingEngine:
                 "dispatch", trace=req.trace_id, pid=self.replica_id,
                 kind="prefill_mid", rows=1, tokens=int(take),
                 offset=int(off))
+        self._ph.set(dispatch=self._dispatch_seq, prefill_tokens=int(take))
         self._inflight.append({"kind": "prefill", "toks": None,
+                               "seq": self._dispatch_seq,
                                "group": [], "free_after": []})
         if req.resume and req.prefill_sent >= req.suffix_len:
             self._resume_complete(req)
-        self.time_prefill_s += time.perf_counter() - t0
         return take
 
     def _resume_complete(self, req: Request):
@@ -2652,6 +2741,7 @@ class ServingEngine:
         self._fresh_slots.add(si)
         req.planned = len(req.out_tokens)
 
+    @_phased("engine.prefill_dispatch")
     def _dispatch_final(self, bucket: int, group, gp: int):
         """Dispatch one FINAL (first-token-sampling) prefill for rows
         whose remaining suffix fits a single bucketed dispatch —
@@ -2662,7 +2752,6 @@ class ServingEngine:
         and the covered pages ride along as a scratch-padded prefix
         table. The dispatch is queued; tokens are fetched at
         collection time."""
-        t0 = time.perf_counter()
         cache = self.dec.cache
         vocab = self.dec.cfg.vocab_size
         ids = np.zeros((gp, bucket), np.int32)
@@ -2707,7 +2796,6 @@ class ServingEngine:
             # reachable through an injected-fault storm on a
             # worst-case-admitted pool): the group shares one dispatch
             # and its rows are already entangled — fail it whole
-            self.time_prefill_s += time.perf_counter() - t0
             for req in members:
                 self._fail_request(
                     req, f"KV pool exhausted building prefill "
@@ -2748,7 +2836,6 @@ class ServingEngine:
             # dispatch, so coverage bookkeeping is still truthful here:
             # unwinding restarts exactly the readers whose spliced
             # blocks will now never be written
-            self.time_prefill_s += time.perf_counter() - t0
             for req in members:
                 self._fail_request(
                     req, f"prefill dispatch failed after retries: {e}")
@@ -2760,11 +2847,12 @@ class ServingEngine:
             self.tracer.event("dispatch", pid=self.replica_id,
                               kind="prefill", rows=int(gp),
                               bucket=int(bucket))
+        self._ph.set(dispatch=self._dispatch_seq)
         self._inflight.append({"kind": "prefill", "toks": toks,
+                               "seq": self._dispatch_seq,
                                "group": [(si, req, req.epoch)
                                          for si, req, _ in group],
                                "free_after": []})
-        self.time_prefill_s += time.perf_counter() - t0
 
     def _prefill_complete(self, toks: np.ndarray, group):
         """Post-fetch bookkeeping for one collected FINAL prefill:
@@ -3031,6 +3119,7 @@ class ServingEngine:
                 return e
         return None
 
+    @_phased("engine.dispatch")
     def _dispatch_chunk(self) -> bool:
         """Dispatch ONE decode chunk for the current RUNNING slots
         without waiting for the previous chunk: first tokens of
@@ -3038,13 +3127,11 @@ class ServingEngine:
         output (no host round trip); freshly admitted slots take their
         prefill token from the host. Slots still mid-prefill aim at the
         scratch page like inactive ones."""
-        t0 = time.perf_counter()
         cache = self.dec.cache
         active = [si for si in range(self.max_b)
                   if self._slots[si] is not None
                   and self._slots[si].state == "running"]
         if not active:
-            self.time_host_s += time.perf_counter() - t0
             return False
         T = self._force_chunk or self._pick_chunk(active)
         mb, mp = self.max_b, self.dec.max_pages
@@ -3144,7 +3231,6 @@ class ServingEngine:
         if all(s == 0 for s in steps_of.values()):
             # every active slot is budget-drained and just awaiting
             # collection — nothing to run
-            self.time_host_s += time.perf_counter() - t0
             return False
 
         # first tokens: device gather from the newest in-flight DECODE
@@ -3221,7 +3307,6 @@ class ServingEngine:
                     self._fail_request(
                         req, f"decode dispatch failed after retries: "
                              f"{e}")
-            self.time_host_s += time.perf_counter() - t0
             return False
         if self.tracer is not None:
             self.tracer.event(
@@ -3229,11 +3314,13 @@ class ServingEngine:
                 T=int(T), width=self.max_b,
                 rows=sum(1 for s in steps_of.values() if s > 0),
                 tokens=int(sum(steps_of.values())))
+        self._ph.set(dispatch=self._dispatch_seq, T=int(T),
+                     decode_cols=len(steps_of))
         self._inflight.append({"kind": "decode", "toks": toks,
+                               "seq": self._dispatch_seq,
                                "steps": steps_of, "reqs": reqs_of,
                                "epochs": epochs_of,
                                "T": T, "free_after": []})
-        self.time_host_s += time.perf_counter() - t0
         return True
 
     # -- ragged unified scheduler (ISSUE 5) ----------------------------------
@@ -3408,7 +3495,12 @@ class ServingEngine:
             return False
         while self._inflight:
             self._collect_oldest()
-        t0 = time.perf_counter()
+        return self._build_spec_chunk()
+
+    @_phased("engine.dispatch")
+    def _build_spec_chunk(self) -> bool:
+        """Draft, build and dispatch the verify chunk (the body of
+        ``_dispatch_spec_chunk``, on flushed history)."""
         cache = self.dec.cache
         mp = self.dec.max_pages
         dcols: List[Tuple[int, Request, np.ndarray]] = []
@@ -3436,7 +3528,6 @@ class ServingEngine:
             dcols.append((si, req, drafts))
             total_drafts += len(drafts)
         if total_drafts == 0:
-            self.time_host_s += time.perf_counter() - t0
             return False
         # draft rows COMPETE with prefill chunks under the per-step
         # row budget: both are extra rows of the same program, and the
@@ -3613,7 +3704,6 @@ class ServingEngine:
             finals[:] = [f for f in finals if f[0] is not req]
             del sched[rid]
         if not sched:
-            self.time_host_s += time.perf_counter() - t0
             return False
 
         tables = np.full((self.max_b + 1, mp), self._scratch_block,
@@ -3655,7 +3745,6 @@ class ServingEngine:
                     self._fail_request(
                         req, f"spec dispatch failed after retries: "
                              f"{e}")
-            self.time_host_s += time.perf_counter() - t0
             return False
 
         for rid, (req, epoch) in sched.items():
@@ -3673,12 +3762,15 @@ class ServingEngine:
                 W=int(W), drafts=int(total_drafts),
                 decode_cols=len(spec_of),
                 prefill_rows=int(sum(take_of.values())))
+        self._ph.set(dispatch=self._dispatch_seq, W=int(W),
+                     decode_cols=len(spec_of),
+                     prefill_tokens=int(sum(take_of.values())))
         self._inflight.append({
-            "kind": "spec", "toks": toks, "acc": acc, "W": W,
+            "kind": "spec", "seq": self._dispatch_seq,
+            "toks": toks, "acc": acc, "W": W,
             "spec": spec_of, "finals": list(finals),
             "real_rows": sum(take_of.values()),
             "free_after": []})
-        self.time_host_s += time.perf_counter() - t0
         return True
 
     def _dispatch_ragged_chunk(self) -> bool:
@@ -3697,13 +3789,19 @@ class ServingEngine:
         of this very chunk, and intra-program slot overlap would
         corrupt the survivor's KV), the ragged analogue of the dense
         path's neutralize-by-column. Returns True when dispatched."""
-        t0 = time.perf_counter()
+        with self._phase("engine.plan"):
+            plan = self._ragged_plan()
+        if not plan[1] and not plan[2]:
+            return False
+        return self._build_ragged_chunk(plan)
+
+    @_phased("engine.dispatch")
+    def _build_ragged_chunk(self, plan) -> bool:
+        """Build the schedule ``plan`` describes and dispatch it (the
+        body of ``_dispatch_ragged_chunk``)."""
         cache = self.dec.cache
         mp = self.dec.max_pages
-        T, dcols, takes, fused = self._ragged_plan()
-        if not dcols and not takes:
-            self.time_host_s += time.perf_counter() - t0
-            return False
+        T, dcols, takes, fused = plan
         ptotal = sum(t for _, t in takes)
         W = self._ragged_width(len(dcols)
                                + (-(-ptotal // T) if ptotal else 0))
@@ -3724,7 +3822,6 @@ class ServingEngine:
             # re-plan against the post-flush scheduler state
             T, dcols, takes, fused = self._ragged_plan()
             if not dcols and not takes:
-                self.time_host_s += time.perf_counter() - t0
                 return False
             ptotal = sum(t for _, t in takes)
             W = self._ragged_width(len(dcols)
@@ -3902,7 +3999,6 @@ class ServingEngine:
             del sched[rid]
         if not sched:
             # everything scheduled was evicted mid-build
-            self.time_host_s += time.perf_counter() - t0
             return False
 
         # one table row per slot (plus the scratch row at max_b): after
@@ -4047,7 +4143,6 @@ class ServingEngine:
                     self._fail_request(
                         req, f"ragged dispatch failed after retries: "
                              f"{e}")
-            self.time_host_s += time.perf_counter() - t0
             return False
 
         # post-dispatch bookkeeping: the scheduled prefill rows are now
@@ -4074,15 +4169,18 @@ class ServingEngine:
                 finals=len(finals),
                 k=int(self.multi_step if fused else 1),
                 decode_toks=int(sum(steps_of.values())))
+        self._ph.set(dispatch=self._dispatch_seq, T=int(T), W=int(W),
+                     decode_cols=len(col_of),
+                     prefill_tokens=int(sum(take_of.values())))
         self._inflight.append({
-            "kind": "ragged", "toks": toks, "T": T, "W": W,
+            "kind": "ragged", "seq": self._dispatch_seq,
+            "toks": toks, "T": T, "W": W,
             "cols": dict(col_of), "steps": dict(steps_of),
             "reqs": dict(reqs_of), "epochs": dict(epochs_of),
             "finals": list(finals),
             "real_rows": sum(take_of.values()),
             "k": self.multi_step if fused else 1,
             "free_after": []})
-        self.time_host_s += time.perf_counter() - t0
         return True
 
     def _collect_ragged(self, ch):
@@ -4091,14 +4189,11 @@ class ServingEngine:
         sampling-final rows deliver their request's first token
         (completing the prefill), mid-chunk prefill rows carry no
         result. ITL attribution matches the dense path."""
-        t0 = time.perf_counter()
-        try:
-            # THE designed blocking point of the ragged pipeline, in
-            # device program order (retried on transient fetch faults)
-            toks = np.asarray(self._device_call(  # flightcheck: disable=FC301
-                "collect:ragged", np.asarray, ch["toks"]))
-        except _DispatchFailed as e:
-            self.time_stall_s += time.perf_counter() - t0
+        # THE designed blocking point of the ragged pipeline, in
+        # device program order (retried on transient fetch faults)
+        toks, e = self._fetch("engine.collect", "collect:ragged",
+                              np.asarray, ch["toks"], ch)
+        if e is not None:
             for si, steps in ch["steps"].items():
                 req = ch["reqs"][si]
                 if steps > 0 and req.state == "running" \
@@ -4115,7 +4210,11 @@ class ServingEngine:
             for rid in ch["free_after"]:
                 self.dec.cache.free(rid)
             return
-        self.time_stall_s += time.perf_counter() - t0
+        with self._phase("engine.deliver", dispatch=ch["seq"]):
+            self._deliver_ragged(ch, toks)
+
+    def _deliver_ragged(self, ch, toks):
+        """Token bookkeeping of one fetched ragged chunk."""
         now = time.perf_counter()
         self.decode_steps += ch["T"]
         # ragged utilization accounting: the program ran T x W cells
@@ -4180,16 +4279,14 @@ class ServingEngine:
         so the next extend re-issues and overwrites them. Final
         prefill rows deliver their first token exactly like the ragged
         chunk's."""
-        t0 = time.perf_counter()
         cache = self.dec.cache
-        try:
-            # the spec pipeline's designed blocking point (sync by
-            # construction — acceptance decides the next schedule);
-            # one batched fetch for tokens + accepted mask
-            fetched = self._device_call(  # flightcheck: disable=FC301
-                "collect:spec", jax.device_get, [ch["toks"], ch["acc"]])
-        except _DispatchFailed as e:
-            self.time_stall_s += time.perf_counter() - t0
+        # the spec pipeline's designed blocking point (sync by
+        # construction — acceptance decides the next schedule);
+        # one batched fetch for tokens + accepted mask
+        fetched, e = self._fetch("engine.collect", "collect:spec",
+                                 jax.device_get, [ch["toks"], ch["acc"]],
+                                 ch)
+        if e is not None:
             for si, ent in ch["spec"].items():
                 req = ent["req"]
                 if req.state == "running" \
@@ -4206,9 +4303,13 @@ class ServingEngine:
             for rid in ch["free_after"]:
                 cache.free(rid)
             return
-        toks = np.asarray(fetched[0])
-        acc = np.asarray(fetched[1])
-        self.time_stall_s += time.perf_counter() - t0
+        with self._phase("engine.deliver", dispatch=ch["seq"]):
+            self._deliver_spec(ch, np.asarray(fetched[0]),
+                               np.asarray(fetched[1]))
+
+    def _deliver_spec(self, ch, toks, acc):
+        """Token bookkeeping and rollback of one fetched verify chunk."""
+        cache = self.dec.cache
         now = time.perf_counter()
         self.decode_steps += 1
         self.decode_slot_steps += ch["W"]
@@ -4326,36 +4427,29 @@ class ServingEngine:
             return
         if ch["kind"] == "prefill":
             if ch["toks"] is not None:
-                t0 = time.perf_counter()
-                try:
-                    # THE designed blocking point for a lone prefill
-                    # entry (runs of >1 batch through
-                    # _collect_prefill_run); retried on transient fetch
-                    # faults — a fetch never consumes the device buffer
-                    toks = np.asarray(self._device_call(  # flightcheck: disable=FC301
-                        "collect:prefill", np.asarray, ch["toks"]))
-                except _DispatchFailed as e:
-                    self.time_prefill_s += time.perf_counter() - t0
+                # THE designed blocking point for a lone prefill
+                # entry (runs of >1 batch through
+                # _collect_prefill_run); retried on transient fetch
+                # faults — a fetch never consumes the device buffer
+                toks, e = self._fetch(
+                    "engine.prefill_collect", "collect:prefill",
+                    np.asarray, ch["toks"], ch)
+                if e is not None:
                     self._fail_prefill_group(ch["group"], e)
                     for rid in ch["free_after"]:
                         self.dec.cache.free(rid)
                     return
-                self.time_prefill_s += time.perf_counter() - t0
-                self._prefill_complete(toks, ch["group"])
+                with self._phase("engine.deliver"):
+                    self._prefill_complete(toks, ch["group"])
             for rid in ch["free_after"]:
                 self.dec.cache.free(rid)
             return
-        t0 = time.perf_counter()
-        try:
-            # THE designed blocking point of the decode pipeline:
-            # collection fetches the oldest in-flight chunk, in device
-            # program order (retried on transient fetch faults; the
-            # outer asarray is a no-op re-wrap of the fetched host
-            # array)
-            toks = np.asarray(self._device_call(  # flightcheck: disable=FC301
-                "collect:decode", np.asarray, ch["toks"]))
-        except _DispatchFailed as e:
-            self.time_stall_s += time.perf_counter() - t0
+        # THE designed blocking point of the decode pipeline:
+        # collection fetches the oldest in-flight chunk, in device
+        # program order (retried on transient fetch faults)
+        toks, e = self._fetch("engine.collect", "collect:decode",
+                              np.asarray, ch["toks"], ch)
+        if e is not None:
             for si, steps in ch["steps"].items():
                 req = ch["reqs"][si]
                 if steps > 0 and req.state == "running" \
@@ -4367,7 +4461,11 @@ class ServingEngine:
             for rid in ch["free_after"]:
                 self.dec.cache.free(rid)
             return
-        self.time_stall_s += time.perf_counter() - t0
+        with self._phase("engine.deliver", dispatch=ch["seq"]):
+            self._deliver_decode(ch, toks)
+
+    def _deliver_decode(self, ch, toks):
+        """Token bookkeeping of one fetched dense decode chunk."""
         now = time.perf_counter()
         self.decode_steps += ch["T"]
         self.decode_slot_steps += ch["T"] * self.max_b
@@ -4400,33 +4498,30 @@ class ServingEngine:
         the batched fetch the old blocking admission used. No-sample
         mid entries carry no result and are skipped by the fetch."""
         chs = [self._inflight.popleft() for _ in range(n)]
-        t0 = time.perf_counter()
         fetch = [ch["toks"] for ch in chs if ch["toks"] is not None]
-        try:
-            # designed batched fetch: one blocking fetch per prefill
-            # run (retried whole on transient faults — fetches never
-            # consume device buffers)
-            fetched = (self._device_call(  # flightcheck: disable=FC301
-                "collect:prefill", jax.device_get, fetch)
-                if fetch else [])
-        except _DispatchFailed as e:
-            self.time_prefill_s += time.perf_counter() - t0
+        # designed batched fetch: one blocking fetch per prefill run
+        # (retried whole on transient faults — fetches never consume
+        # device buffers)
+        fetched, e = (self._fetch(
+            "engine.prefill_collect", "collect:prefill", jax.device_get,
+            fetch) if fetch else ([], None))
+        if e is not None:
             for ch in chs:
                 if ch["toks"] is not None:
                     self._fail_prefill_group(ch["group"], e)
                 for rid in ch["free_after"]:
                     self.dec.cache.free(rid)
             return
-        self.time_prefill_s += time.perf_counter() - t0
         it = iter(fetched)
-        for ch in chs:
-            if ch["toks"] is not None:
-                # re-wrap of the batched fetch above (already host
-                # memory — the sync was paid at the designed point)
-                self._prefill_complete(np.asarray(next(it)),  # flightcheck: disable=FC301
-                                       ch["group"])
-            for rid in ch["free_after"]:
-                self.dec.cache.free(rid)
+        with self._phase("engine.deliver"):
+            for ch in chs:
+                if ch["toks"] is not None:
+                    # re-wrap of the batched fetch above (already host
+                    # memory — the sync was paid at the designed point)
+                    self._prefill_complete(np.asarray(next(it)),  # flightcheck: disable=FC301
+                                           ch["group"])
+                for rid in ch["free_after"]:
+                    self.dec.cache.free(rid)
 
     def _fail_prefill_group(self, group, e: Exception):
         """Fail every request of an uncollectable final-prefill entry
@@ -4449,8 +4544,15 @@ class ServingEngine:
         expired request never costs another dispatch); dispatch/fetch
         errors and KV pressure are absorbed inside the phases — step()
         itself never raises on a per-request fault."""
-        self._enforce_deadlines()
-        self._admit()
+        self._step_seq += 1
+        with self._phase("engine.step", step=self._step_seq):
+            return self._step()
+
+    def _step(self) -> bool:
+        with self._phase("engine.deadlines"):
+            self._enforce_deadlines()
+        with self._phase("engine.admit"):
+            self._admit()
         if self.ragged:
             # unified ragged path: decode AND prefill rows ride ONE
             # device program per step (no separate prefill dispatches,
@@ -4980,6 +5082,7 @@ class ServingEngine:
         self.time_prefill_s = 0.0
         self.time_stall_s = 0.0
         self.time_host_s = 0.0
+        self.time_by_phase_s = {}
         # robustness counters reset alongside the prefix-cache ones so
         # a post-warmup stats() reflects only real traffic
         self.preemptions = 0
@@ -5104,6 +5207,10 @@ class ServingEngine:
             "time_prefill_s": self.time_prefill_s,
             "time_decode_stall_s": self.time_stall_s,
             "time_host_s": self.time_host_s,
+            # the same seconds by the phase that spent them (each phase
+            # feeds exactly one of the three above, so the values sum
+            # to their sum); the phases are the engine's span names
+            "time_by_phase_s": dict(self.time_by_phase_s),
             # device-program launches and delivered tokens per launch —
             # the ragged path's headline: one program per step instead
             # of merge + decode + N prefill dispatches. Accepted draft

@@ -20,6 +20,7 @@ Key pieces:
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -29,6 +30,7 @@ import numpy as np
 from ..framework.core import (
     Parameter, Tensor, apply, no_grad, with_rng_key, default_generator,
 )
+from ..utils import telemetry
 
 __all__ = ["functional_call", "to_static", "TrainStep", "save", "load",
            "not_to_static", "ignore_module"]
@@ -558,6 +560,12 @@ class TrainStep:
         self._gm_avg = bool(gradient_merge_avg)
         self._gm_accum = None
         self._gm_compiled = None
+        # spans go to this ring when one is set, else to the default ring
+        # while a profiler session is live (utils/telemetry.py)
+        self.tracer = None
+        # the step program(s) register here when built: ``compiles`` and
+        # ``seal()`` as on a ServingEngine
+        self.compile_watch = telemetry.CompileWatch()
 
     def _make_loss_and_grads(self):
         """Closure computing (loss, new_buffers, per-param grads) — the
@@ -659,10 +667,12 @@ class TrainStep:
 
         def step(param_arrays, buffer_arrays, opt_state, lr, key, inputs,
                  labels):
-            loss, new_bufs, full_grads = loss_and_grads(
-                param_arrays, buffer_arrays, key, inputs, labels)
-            new_params, new_opt_state = opt_update(
-                param_arrays, full_grads, opt_state, lr)
+            with jax.named_scope("fwd_bwd"):
+                loss, new_bufs, full_grads = loss_and_grads(
+                    param_arrays, buffer_arrays, key, inputs, labels)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = opt_update(
+                    param_arrays, full_grads, opt_state, lr)
             return loss, new_params, new_bufs, new_opt_state
 
         donate = (0, 2) if self._donate else ()
@@ -679,8 +689,9 @@ class TrainStep:
 
         def accum_step(param_arrays, buffer_arrays, accum, key, inputs,
                        labels):
-            loss, new_bufs, full_grads = loss_and_grads(
-                param_arrays, buffer_arrays, key, inputs, labels)
+            with jax.named_scope("fwd_bwd"):
+                loss, new_bufs, full_grads = loss_and_grads(
+                    param_arrays, buffer_arrays, key, inputs, labels)
             tg = [g for g, m in zip(full_grads, mask) if m]
             new_accum = [a + g.astype(jnp.float32)
                          for a, g in zip(accum, tg)]
@@ -688,8 +699,9 @@ class TrainStep:
 
         def apply_step(param_arrays, buffer_arrays, opt_state, lr, accum,
                        key, inputs, labels):
-            loss, new_bufs, full_grads = loss_and_grads(
-                param_arrays, buffer_arrays, key, inputs, labels)
+            with jax.named_scope("fwd_bwd"):
+                loss, new_bufs, full_grads = loss_and_grads(
+                    param_arrays, buffer_arrays, key, inputs, labels)
             it = iter(accum)
             merged = []
             for g, m in zip(full_grads, mask):
@@ -703,8 +715,9 @@ class TrainStep:
                 # behaves exactly like a plain step (keeps param dtype
                 # stable for donation)
                 merged.append(tot.astype(g.dtype))
-            new_params, new_opt_state = opt_update(
-                param_arrays, merged, opt_state, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = opt_update(
+                    param_arrays, merged, opt_state, lr)
             zero_accum = [jnp.zeros_like(a) for a in accum]
             return loss, new_params, new_bufs, new_opt_state, zero_accum
 
@@ -746,6 +759,15 @@ class TrainStep:
     def __call__(self, inputs, labels):
         """inputs / labels: a Tensor or tuple of Tensors. Model is called as
         model(*inputs); loss as loss_fn(model_out, *labels)."""
+        with self._span("train_step", step=self._step_i,
+                        annotation=jax.profiler.StepTraceAnnotation,
+                        step_num=self._step_i):
+            return self._step(inputs, labels)
+
+    def _span(self, name, **kw):
+        return telemetry.span(name, tracer=self.tracer, **kw)
+
+    def _step(self, inputs, labels):
         # DecompAware kernels read the prim flag at trace time: a toggle
         # must rebuild, not silently keep the other mode's trace (same
         # contract as to_static's (training, prim) mode token)
@@ -760,82 +782,94 @@ class TrainStep:
             self._step_i -= self._step_i % self._gm_k
         first = self._compiled is None and self._gm_compiled is None
         if first:
-            self._built_prim = _prim()
-            if self._gm_k > 1:
-                self._gm_compiled = self._build_gm()
-            else:
-                self._compiled = self._build()
-            import os as _os
-            from ..utils.flags import FLAGS
-            if getattr(FLAGS, "enable_watchdog", None) or \
-                    _os.environ.get("FLAGS_enable_watchdog", "").lower() \
-                    in ("1", "true"):
-                from ..distributed.watchdog import enable_watchdog
-                enable_watchdog()
-        if self.optimizer._state is None:
-            self.optimizer._state = self.optimizer.init_state(
-                [p._value for p in self.optimizer._parameter_list])
-        p_arrays = [p._value for p in self._p_tensors]
-        b_arrays = [b._value for b in self._b_tensors]
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        key = jax.random.fold_in(default_generator._key, self._step_i)
+            with self._span("train_step.build"):
+                self._built_prim = _prim()
+                if self._gm_k > 1:
+                    self._gm_compiled = self._build_gm()
+                    for name, fn in zip(("accum_step", "apply_step"),
+                                        self._gm_compiled):
+                        self.compile_watch.register(name, fn)
+                else:
+                    self._compiled = self._build()
+                    self.compile_watch.register("step", self._compiled)
+                import os as _os
+                from ..utils.flags import FLAGS
+                if getattr(FLAGS, "enable_watchdog", None) or \
+                        _os.environ.get("FLAGS_enable_watchdog", "").lower() \
+                        in ("1", "true"):
+                    from ..distributed.watchdog import enable_watchdog
+                    enable_watchdog()
+        with self._span("train_step.args"):
+            if self.optimizer._state is None:
+                self.optimizer._state = self.optimizer.init_state(
+                    [p._value for p in self.optimizer._parameter_list])
+            p_arrays = [p._value for p in self._p_tensors]
+            b_arrays = [b._value for b in self._b_tensors]
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            key = jax.random.fold_in(default_generator._key, self._step_i)
 
-        def _unwrap_batch(x):
-            if isinstance(x, Tensor):
-                return (x._value,)
-            if isinstance(x, (tuple, list)):
-                return tuple(e._value if isinstance(e, Tensor)
-                             else jnp.asarray(e) for e in x)
-            return (jnp.asarray(x),)
+            def _unwrap_batch(x):
+                if isinstance(x, Tensor):
+                    return (x._value,)
+                if isinstance(x, (tuple, list)):
+                    return tuple(e._value if isinstance(e, Tensor)
+                                 else jnp.asarray(e) for e in x)
+                return (jnp.asarray(x),)
 
-        in_arrays = _unwrap_batch(inputs)
-        label_arrays = _unwrap_batch(labels)
+            in_arrays = _unwrap_batch(inputs)
+            label_arrays = _unwrap_batch(labels)
         if self._gm_k > 1:
-            loss = self._call_gm(p_arrays, b_arrays, lr, key, in_arrays,
-                                 label_arrays)
+            loss, new_params, new_bufs, new_state = self._call_gm(
+                p_arrays, b_arrays, lr, key, in_arrays, label_arrays)
         else:
-            loss, new_params, new_bufs, new_state = self._compiled(
-                p_arrays, b_arrays, self.optimizer._state, lr, key,
-                in_arrays, label_arrays)
-            for p, a in zip(self._p_tensors, new_params):
-                p._replace(a)
+            loss, new_params, new_bufs, new_state = self._dispatch(
+                self._compiled, p_arrays, b_arrays, self.optimizer._state,
+                lr, key, in_arrays, label_arrays)
+        with self._span("train_step.rebind"):
             for b, a in zip(self._b_tensors, new_bufs):
                 b._replace(a)
-            self.optimizer._state = new_state
-            self.optimizer._step_count += 1
-        self._step_i += 1
-        from ..distributed.watchdog import notify_step
-        notify_step(self._step_i)
+            if new_params is not None:      # the optimizer stepped
+                for p, a in zip(self._p_tensors, new_params):
+                    p._replace(a)
+                self.optimizer._state = new_state
+                self.optimizer._step_count += 1
+            self._step_i += 1
+            from ..distributed.watchdog import notify_step
+            notify_step(self._step_i)
         return Tensor(loss)
+
+    def _dispatch(self, fn, *args):
+        """Call one step program; on its first call with a shape this
+        holds trace + lower + compile, which ``compile_watch`` counts."""
+        with self._span("train_step.dispatch") as sp:
+            t0 = time.perf_counter()
+            out = fn(*args)
+            # a compile it finds becomes a span where this one goes
+            self.compile_watch.tracer = sp.ring
+            self.compile_watch.observe(fn, t0, time.perf_counter(), args)
+        return out
 
     def _call_gm(self, p_arrays, b_arrays, lr, key, in_arrays,
                  label_arrays):
         """One gradient-merge micro-step: accumulate, or (every k-th
-        call) merge + optimizer apply. The optimizer steps — and its
-        step count / LR schedule advance — only on apply."""
+        call) merge + optimizer apply. Returns (loss, new_params,
+        new_bufs, new_state); new_params is None on an accumulate step:
+        the optimizer steps — and its step count / LR schedule advance —
+        only on apply."""
         accum_fn, apply_fn = self._gm_compiled
         if self._gm_accum is None:
             self._gm_accum = self._init_gm_accum()
         is_apply = (self._step_i + 1) % self._gm_k == 0
         if not is_apply:
-            loss, new_bufs, new_accum = accum_fn(
-                p_arrays, b_arrays, self._gm_accum, key, in_arrays,
-                label_arrays)
-            for b, a in zip(self._b_tensors, new_bufs):
-                b._replace(a)
-            self._gm_accum = new_accum
-            return loss
-        loss, new_params, new_bufs, new_state, new_accum = apply_fn(
-            p_arrays, b_arrays, self.optimizer._state, lr,
-            self._gm_accum, key, in_arrays, label_arrays)
-        for p, a in zip(self._p_tensors, new_params):
-            p._replace(a)
-        for b, a in zip(self._b_tensors, new_bufs):
-            b._replace(a)
-        self.optimizer._state = new_state
-        self.optimizer._step_count += 1
-        self._gm_accum = new_accum
-        return loss
+            loss, new_bufs, self._gm_accum = self._dispatch(
+                accum_fn, p_arrays, b_arrays, self._gm_accum, key,
+                in_arrays, label_arrays)
+            return loss, None, new_bufs, None
+        loss, new_params, new_bufs, new_state, self._gm_accum = \
+            self._dispatch(apply_fn, p_arrays, b_arrays,
+                           self.optimizer._state, lr, self._gm_accum, key,
+                           in_arrays, label_arrays)
+        return loss, new_params, new_bufs, new_state
 
 
 # ---------------------------------------------------------------------------

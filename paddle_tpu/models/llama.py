@@ -20,6 +20,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..framework.core import Tensor, apply
@@ -261,6 +262,10 @@ class LlamaAttention(nn.Layer):
 class LlamaDecoderLayer(nn.Layer):
     def __init__(self, cfg: LlamaConfig):
         super().__init__(dtype=cfg.dtype)
+        # the named scopes a device trace groups this layer's time by;
+        # LlamaModel puts the layer's index in
+        self._attn_scope = "layer/attn"
+        self._mlp_scope = "layer/mlp"
         self.input_layernorm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                           dtype=cfg.dtype)
         self.self_attn = LlamaAttention(cfg)
@@ -271,24 +276,31 @@ class LlamaDecoderLayer(nn.Layer):
         self.use_recompute = cfg.use_recompute
         self.recompute_granularity = cfg.recompute_granularity
 
+    def _mlp(self, h):
+        with jax.named_scope(self._mlp_scope):
+            return h + self.mlp(self.post_attention_layernorm(h))
+
     def _block(self, x):
-        h = x + self.self_attn(self.input_layernorm(x))
-        return h + self.mlp(self.post_attention_layernorm(h))
+        with jax.named_scope(self._attn_scope):
+            h = x + self.self_attn(self.input_layernorm(x))
+        return self._mlp(h)
 
     def forward(self, x, past_kv=None, pos=None):
         if past_kv is not None:
-            attn, new_kv = self.self_attn(self.input_layernorm(x),
-                                          past_kv=past_kv, pos=pos)
-            h = x + attn
-            return h + self.mlp(self.post_attention_layernorm(h)), new_kv
+            with jax.named_scope(self._attn_scope):
+                attn, new_kv = self.self_attn(self.input_layernorm(x),
+                                              past_kv=past_kv, pos=pos)
+                h = x + attn
+            return self._mlp(h), new_kv
         if self.use_recompute:
             from ..distributed.fleet import recompute
             gran = self.recompute_granularity
             if gran == "full":
                 return recompute(_LayerFn(self), x)
             if gran == "full_attn":
-                h = x + recompute(_AttnFn(self), x)
-                return h + self.mlp(self.post_attention_layernorm(h))
+                with jax.named_scope(self._attn_scope):
+                    h = x + recompute(_AttnFn(self), x)
+                return self._mlp(h)
             if gran == "core_attn":
                 # flash backward recomputes scores/probs from q/k/v by
                 # construction — the plain forward IS core_attn remat
@@ -339,22 +351,28 @@ class LlamaModel(nn.Layer):
             self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
         self.layers = nn.LayerList(
             [LlamaDecoderLayer(cfg) for _ in range(cfg.num_hidden_layers)])
+        for i, layer in enumerate(self.layers):
+            layer._attn_scope = f"layer{i}/attn"
+            layer._mlp_scope = f"layer{i}/mlp"
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                dtype=cfg.dtype)
 
     def forward(self, input_ids, caches=None, pos=None):
-        h = self.embed_tokens(input_ids)
-        if self.cfg.dtype != "float32":
-            h = h.astype(self.cfg.dtype)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
+            if self.cfg.dtype != "float32":
+                h = h.astype(self.cfg.dtype)
         if caches is not None:
             new_caches = []
             for layer, kv in zip(self.layers, caches):
                 h, nkv = layer(h, past_kv=kv, pos=pos)
                 new_caches.append(nkv)
-            return self.norm(h), new_caches
-        for layer in self.layers:
-            h = layer(h)
-        return self.norm(h)
+        else:
+            for layer in self.layers:
+                h = layer(h)
+        with jax.named_scope("final_norm"):
+            h = self.norm(h)
+        return h if caches is None else (h, new_caches)
 
 
 class LlamaForCausalLM(nn.Layer):
@@ -381,12 +399,13 @@ class LlamaForCausalLM(nn.Layer):
         if self.cfg.chunked_ce_tokens and caches is None:
             # chunked-CE training config: loss() owns the head matmul
             return h
-        if self.lm_head is None:
-            from ..tensor.linalg import matmul
-            logits = matmul(h, self.model.embed_tokens.weight,
-                            transpose_y=True)
-        else:
-            logits = self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if self.lm_head is None:
+                from ..tensor.linalg import matmul
+                logits = matmul(h, self.model.embed_tokens.weight,
+                                transpose_y=True)
+            else:
+                logits = self.lm_head(h)
         if caches is not None:
             return logits, new_caches
         return logits
@@ -398,13 +417,13 @@ class LlamaForCausalLM(nn.Layer):
         jax.checkpoint — the [B, S, V] logits (1 GB at b4 s2048 v32k
         f32, the single biggest activation) are never materialized; the
         backward rematerializes one chunk's logits at a time."""
-        if self.cfg.chunked_ce_tokens:
-            return self._chunked_loss(logits, labels)
-        from ..tensor.manipulation import reshape
-        v = logits.shape[-1]
-        shift_logits = logits[:, :-1, :].reshape([-1, v])
-        shift_labels = labels[:, 1:].reshape([-1])
-        return F.cross_entropy(shift_logits, shift_labels)
+        with jax.named_scope("loss"):
+            if self.cfg.chunked_ce_tokens:
+                return self._chunked_loss(logits, labels)
+            v = logits.shape[-1]
+            shift_logits = logits[:, :-1, :].reshape([-1, v])
+            shift_labels = labels[:, 1:].reshape([-1])
+            return F.cross_entropy(shift_logits, shift_labels)
 
     def _chunked_loss(self, hidden, labels):
         from ..nn.functional.loss import chunked_causal_lm_loss

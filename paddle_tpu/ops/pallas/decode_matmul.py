@@ -181,4 +181,5 @@ def decode_matmul(x, w):
         out_specs=pl.BlockSpec((b, tn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((b, tn), jnp.float32)],
+        name="decode_matmul",
     )(*ins)

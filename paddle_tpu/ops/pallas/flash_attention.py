@@ -157,6 +157,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k):
             _sds((b, h, sq, 1), jnp.float32, q),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*args)
     return out, lse[..., 0]
 
@@ -324,6 +325,7 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=_sds((b, h, sq, d), dq_dtype, q),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(*dq_args)
 
     # dk/dv: grid (b, hk, kblocks, group); q-head = hk_index*group + g
@@ -363,6 +365,7 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
             _sds((b, hk, sk, d), jnp.float32, q),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_args)
     return dq, dk, dv
 

@@ -160,6 +160,7 @@ def paged_attention_decode_pallas(q, k_cache, v_cache, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=_interpret(),
+        name="paged_attn_decode",
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       q4, k_cache, v_cache)
     return out.reshape(b, nh, d)

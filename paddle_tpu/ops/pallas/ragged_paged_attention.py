@@ -279,6 +279,7 @@ def ragged_paged_attention_pallas(q, k_cache, v_cache, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=_interpret(),
+        name="ragged_paged_attn",
     )(rs, rc, block_tables.astype(jnp.int32), q4, *operands)
     out = out.reshape(kvh, r_pad, group, d).transpose(1, 0, 2, 3) \
         .reshape(r_pad, nh, d)

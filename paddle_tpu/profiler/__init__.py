@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
 from enum import Enum
@@ -34,44 +35,217 @@ __all__ = [
 ]
 
 
-def device_trace_summary(trace_dir: str) -> dict:
-    """Summarize the DEVICE lanes of a jax.profiler (xprof) capture —
-    the hardware proof that the §5.1 profiler row records real TPU
-    kernel timelines, not just host spans (the reference's CudaTracer
-    analog: /root/reference/paddle/fluid/platform/profiler/
-    cuda_tracer.h). Parses the trace.json.gz the xprof plugin writes
-    next to the .xplane.pb and returns {"device_lanes": [...],
-    "device_events": N, "top_kernels": [...]} ({} lanes / 0 events on
-    a host-only capture)."""
+# -- reading a jax.profiler capture -------------------------------------------
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OPS_LINE = "XLA Ops"
+# the roots of the program's own spans on the host plane
+# (utils/telemetry.span in jit.TrainStep and ServingEngine.step)
+PROGRAM_SPANS = ("train_step", "engine.")
+_TARGET = re.compile(r'custom_call_target="([^"]+)"')
+_LAYER = re.compile(r"\blayer\d+/")
+
+
+def _varint(buf, i):
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return val, i
+
+
+def _fields(buf):
+    """(field number, wire type, value) of one protobuf message: varints
+    as ints, length-delimited fields as memoryviews, fixed ones skipped."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire, val = key & 7, None
+        if wire == 0:
+            val, i = _varint(buf, i)
+        elif wire == 2:
+            ln, i = _varint(buf, i)
+            val = buf[i:i + ln]
+            i += ln
+        elif wire == 1:
+            i += 8
+        elif wire == 5:
+            i += 4
+        else:
+            raise ValueError(f"protobuf wire type {wire}")
+        yield key >> 3, wire, val
+
+
+def _each(buf, num, wire=2):
+    return (v for n, w, v in _fields(buf) if n == num and w == wire)
+
+
+def _first(buf, num, wire=2, default=None):
+    return next(_each(buf, num, wire), default)
+
+
+def _text(buf, num) -> str:
+    return bytes(_first(buf, num, default=b"")).decode()
+
+
+def _op_scopes(path: str) -> Dict[str, Dict[str, str]]:
+    """{device plane: {operation's event name: its ``tf_op`` stat}}.
+
+    ``jax.profiler.ProfileData`` gives an event's own stats but not
+    those of its XEventMetadata, and that is where libtpu (0.0.34, jax
+    0.9.0) keeps the HLO ``op_name`` — the ``jax.named_scope`` path —
+    as the stat ``tf_op``; the event's name is the HLO line WITHOUT its
+    ``metadata={op_name=...}``. So this walks the file's wire format
+    for just that: XSpace.planes=1; XPlane.name=2, .event_metadata=4,
+    .stat_metadata=5 (maps: key=1, value=2); XEventMetadata.name=2,
+    .stats=5; XStatMetadata.name=2; XStat.metadata_id=1, .str_value=5,
+    .ref_value=7 (tsl/profiler/protobuf/xplane.proto)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: Dict[str, Dict[str, str]] = {}
+    for plane in _each(space, 1):
+        name = _text(plane, 2)
+        if not DEVICE_PLANE.match(name):
+            continue
+        stat_names = {
+            _first(entry, 1, 0, 0): _text(_first(entry, 2, default=b""), 2)
+            for entry in _each(plane, 5)}
+        scopes = out.setdefault(name, {})
+        for entry in _each(plane, 4):
+            meta = _first(entry, 2, default=b"")
+            for stat in _each(meta, 5):
+                if stat_names.get(_first(stat, 1, 0)) != "tf_op":
+                    continue
+                ref = _first(stat, 7, 0)
+                scope = _text(stat, 5) or stat_names.get(ref)
+                if scope:
+                    scopes[_text(meta, 2)] = scope
+    return out
+
+
+def scope_of(tf_op: str) -> str:
+    """``jit(step)/fwd_bwd/transpose(jvp(layer1/mlp))/dot_general:`` ->
+    ``fwd_bwd/transpose(jvp(layer*/mlp))``: the program's name and the
+    primitive go, the layer index is folded, and ``transpose(jvp(...))``
+    stays to mark the backward half."""
+    parts = tf_op.rstrip(":").split("/")
+    if parts and parts[0].startswith(("jit(", "pjit(")):
+        parts = parts[1:]
+    # the primitive is the last part; a part that closes a wrapper opened
+    # earlier ("mlp))") belongs to the scope
+    if parts and parts[-1].count(")") <= parts[-1].count("("):
+        parts = parts[:-1]
+    return _LAYER.sub("layer*/", "/".join(parts)) or "(no scope)"
+
+
+def kernel_of(event_name: str) -> Optional[str]:
+    """The ``name=`` of the Pallas kernel an operation's event ran, the
+    instance number folded (``%flash_fwd.2 = ... custom_call_target=
+    "tpu_custom_call"`` -> ``flash_fwd``); None for any other op."""
+    m = _TARGET.search(event_name)
+    if m is None or m.group(1) != "tpu_custom_call":
+        return None
+    return event_name.split(" = ", 1)[0].lstrip("%").rsplit(".", 1)[0]
+
+
+def _leaf_events(events):
+    """Events that hold no other event of their line: a ``while`` or a
+    call spans the operations of its body."""
+    evs = sorted(events, key=lambda e: (e[1], -e[2]))
+    out = []
+    for i, ev in enumerate(evs):
+        nxt = evs[i + 1] if i + 1 < len(evs) else None
+        if nxt is not None and ev[2] > 0 and nxt[1] < ev[1] + ev[2] \
+                and nxt[1] + nxt[2] <= ev[1] + ev[2]:
+            continue
+        out.append(ev)
+    return out
+
+
+def _ranked(total: dict, n: int):
+    return [[k, ns / 1e9, cnt] for k, (ns, cnt)
+            in sorted(total.items(), key=lambda kv: -kv[1][0])[:n]]
+
+
+def device_trace_summary(trace_dir: str, top: int = 20) -> dict:
+    """Where the device's time went in a jax.profiler (xprof) capture,
+    by the names the program gave: reads the newest ``.xplane.pb``
+    under ``trace_dir`` through ``jax.profiler.ProfileData`` and returns
+
+    - ``device_lanes`` / ``device_events``: the ``/device:TPU:n`` planes
+      and their operation events ([] / 0 on a host-only capture);
+    - ``by_scope``: [[scope, seconds, events]] of the first device, by
+      ``jax.named_scope`` path (``scope_of``), loop bodies counted and
+      not the loops around them;
+    - ``by_kernel``: the same by Pallas kernel ``name=``;
+    - ``top_ops``: [[op, scope, seconds, events]] of single operations
+      (``fusion.318``), which is how a run number gets a layer's name;
+    - ``top_kernels``: names of the most frequent operations;
+    - ``host_spans``: [[name, count, total seconds, median seconds]] of
+      the program's own spans (``PROGRAM_SPANS``) on the host plane."""
     import glob
-    import gzip
+    import statistics
     from collections import Counter
 
-    out = {"device_lanes": [], "device_events": 0, "top_kernels": []}
+    out = {"device_lanes": [], "device_events": 0, "top_kernels": [],
+           "by_scope": [], "by_kernel": [], "top_ops": [],
+           "host_spans": []}
     paths = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz")))
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     if not paths:
         return out
-    tr = json.loads(gzip.open(paths[-1]).read())
-    evs = tr.get("traceEvents", [])
-    procs = {e["pid"]: e["args"]["name"] for e in evs
-             if e.get("ph") == "M" and e.get("name") == "process_name"
-             and "name" in e.get("args", {})}
-    # /device:CUSTOM:* planes (e.g. the "Megascale Trace" libtpu adds
-    # to every capture once the TPU library is loaded, chip or not)
-    # are host-side bookkeeping, not accelerator timelines
-    dev_pids = {pid for pid, nm in procs.items()
-                if "/device:" in nm and "CPU" not in nm
-                and "CUSTOM" not in nm}
-    kernels = Counter()
-    n = 0
-    for e in evs:
-        if e.get("ph") == "X" and e.get("pid") in dev_pids:
-            n += 1
-            kernels[e.get("name", "?")] += 1
-    out["device_lanes"] = sorted(procs[p] for p in dev_pids)
-    out["device_events"] = n
-    out["top_kernels"] = [k for k, _ in kernels.most_common(5)]
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(paths[-1])
+    scopes = _op_scopes(paths[-1])
+    calls = Counter()
+    host: Dict[str, List[float]] = {}
+    first = None
+    for plane in data.planes:
+        if DEVICE_PLANE.match(plane.name):
+            out["device_lanes"].append(plane.name)
+            for line in plane.lines:
+                if line.name != OPS_LINE:
+                    continue
+                evs = [(e.name, int(e.start_ns), int(e.duration_ns))
+                       for e in line.events]
+                out["device_events"] += len(evs)
+                calls.update(e[0].split(" = ", 1)[0].lstrip("%")
+                             for e in evs)
+                if first is None or plane.name < first[0]:
+                    first = (plane.name, evs)
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(PROGRAM_SPANS):
+                        host.setdefault(e.name, []).append(
+                            e.duration_ns / 1e9)
+    out["device_lanes"].sort()
+    out["top_kernels"] = [k for k, _ in calls.most_common(5)]
+    out["host_spans"] = [[k, len(v), sum(v), statistics.median(v)]
+                         for k, v in sorted(host.items())]
+    if first is None:
+        return out
+    by_scope, by_kernel, by_op = {}, {}, {}
+    names = scopes.get(first[0], {})
+
+    def add(total, key, dur):
+        ns, cnt = total.get(key, (0, 0))
+        total[key] = (ns + dur, cnt + 1)
+
+    for name, _, dur in _leaf_events(first[1]):
+        scope = scope_of(names[name]) if name in names else "(no scope)"
+        add(by_scope, scope, dur)
+        add(by_op, (name.split(" = ", 1)[0].lstrip("%"), scope), dur)
+        kernel = kernel_of(name)
+        if kernel is not None:
+            add(by_kernel, kernel, dur)
+    out["by_scope"] = _ranked(by_scope, top)
+    out["by_kernel"] = _ranked(by_kernel, top)
+    out["top_ops"] = [[op, scope, s, n] for (op, scope), s, n
+                      in _ranked(by_op, top)]
     return out
 
 

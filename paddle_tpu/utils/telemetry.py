@@ -65,6 +65,48 @@ were not:
   snapshot (``tools/metrics_export.py`` runs it standalone over an
   exported trace).
 
+Spans inside the program (ISSUE 27) are ONE primitive,
+``span(name, **attrs)``: a context manager that always enters a
+``jax.profiler.TraceAnnotation`` (a TraceMe on the profiler's host
+plane, attrs as its stats) and, WHILE SOMEONE LISTENS, appends one span
+record to a ``Tracer`` ring with its own ``id``, the ``parent`` id of
+the enclosing open span on this thread (the span that caused it) and
+the ``step`` its unit of work shares (a trainer / engine step index,
+inherited by children). Someone listens when a ``Tracer`` was attached
+explicitly (``tracer=``) or a jax profiler session is live
+(``TraceAnnotation.is_enabled()``: ``jax.profiler.start_trace`` /
+``trace()``, ``paddle_tpu.profiler.Profiler``, the benchmark's
+``--trace 1``); in the second case records go to ``default_tracer()``,
+a process-wide ring that outlives the engine or trainer that wrote to
+it and so covers exactly the interval the device trace covers. There
+is no flag: starting the profiler is what switches the program's spans
+on. ``TrainStep`` (``train_step`` + ``.build/.args/.dispatch/.rebind``)
+and ``ServingEngine.step`` (``engine.step`` + ``.deadlines/.admit/
+.plan/.dispatch/.collect/.deliver``) are instrumented with it.
+
+One clock: the ring stamps ``time.perf_counter()``, the xplane stamps
+nanoseconds of the profiler's clock, and every span is in both. Any
+span present in both gives the offset (``xplane start_ns - ring ts *
+1e9``); the benchmark's ``bench:window`` annotation does, because the
+harness reads ``perf_counter`` right after entering it. Off cost, per
+span, on this repo's CPU host (jax 0.9.0): one ``is_enabled()`` (0.02
+us), one TraceMe enter/exit (0.36 us) and the Python object around them,
+0.9 us in all (1.7 us with three attrs), nothing appended; recorded into
+a ring it is 4.6 us (the registry's counter and histogram included).
+
+Compile path: ``jax.monitoring`` listeners, registered once on first
+use, turn jax's own trace / lower / backend-compile / cache-load
+durations into ``compile.*`` events in the default ring ALWAYS (they
+fire only when something compiles, so the steady state pays nothing)
+and into the default registry's ``compile.trace_s / lower_s /
+backend_s / cache_load_s / cache_hits / cache_requests`` counters. In
+jax 0.9.0 ``backend_compile_duration`` wraps ``compile_or_get_cached``,
+so a cache load is INSIDE ``backend_s``: ``cache_load_s`` is its part
+and is never added to it. A jitted function traced inside another's
+trace fires its own trace event inside the outer one's interval; each
+event carries ``self_s`` (its seconds minus the events nested in it),
+and the counters add ``self_s``, so sums are wall seconds.
+
 Overhead contract: ``tracer=None`` (the default everywhere) is a
 BITWISE no-op — every hook is behind an ``if tracer is not None``
 guard, no PRNG key is drawn, no device call is made, no schedule array
@@ -95,11 +137,15 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
+import jax.monitoring
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "MetricsRegistry", "Reservoir", "CompileWatch",
            "SLOPolicy", "SLOMonitor", "FLEET_PID",
-           "DEFAULT_TIME_BUCKETS_S", "openmetrics_text"]
+           "DEFAULT_TIME_BUCKETS_S", "openmetrics_text", "span",
+           "default_tracer", "listening", "compile_seconds"]
 
 # the pid Chrome-trace track fleet-level records render on (routing,
 # breaker transitions, migration, request async spans); engine records
@@ -441,9 +487,7 @@ class CompileWatch:
         program) that refuses any step just yields fewer fields."""
         out: Dict[str, float] = {}
         try:
-            t0 = time.perf_counter()
             lowered = fn.lower(*args)
-            out["lower_s"] = time.perf_counter() - t0
         except Exception:       # noqa: BLE001 — best-effort contract
             return out
         try:
@@ -458,9 +502,7 @@ class CompileWatch:
         except Exception:       # noqa: BLE001
             pass
         try:
-            t0 = time.perf_counter()
             compiled = lowered.compile()
-            out["compile_s"] = time.perf_counter() - t0
             ma = compiled.memory_analysis()
             out["temp_bytes"] = float(
                 getattr(ma, "temp_size_in_bytes", 0))
@@ -495,6 +537,9 @@ class CompileWatch:
         wall = max(0.0, float(t1) - float(t0))
         rec = {"family": name, "signature": self.signature_of(args),
                "wall_s": wall, "sealed": self.sealed}
+        # jax's own account of the interval: tracing, lowering and the
+        # backend's compile (or its load from the compile cache)
+        rec.update(compile_seconds(t0, t1))
         rec.update(fam["info"])
         if self.analyze and not fam["analyzed"]:
             fam["analyzed"] = True
@@ -791,14 +836,26 @@ class Tracer:
         return False
 
     def span(self, name: str, trace_id: Optional[int], t0: float,
-             t1: float, pid: int = 0, **attrs):
-        """One completed per-life phase slice [t0, t1] (perf_counter
-        seconds) on the replica track ``pid``."""
+             t1: float, pid: int = 0, id: Optional[int] = None,
+             parent: Optional[int] = None, step: Optional[int] = None,
+             **attrs):
+        """One completed slice [t0, t1] (perf_counter seconds) on the
+        replica track ``pid``: a request's per-life phase (``trace_id``
+        set) or a program span (``telemetry.span``). ``id`` is its own,
+        ``parent`` the span that caused it, ``step`` the unit of work
+        both belong to; left out, they are a fresh id and the innermost
+        span open on the calling thread, so a request phase closed
+        inside ``engine.step`` hangs under the phase that closed it."""
+        if id is None:
+            id = next(_span_ids)
+            parent, open_step = _enclosing()
+            step = open_step if step is None else step
         self._record({"kind": "span", "name": name,
                       "trace": (int(trace_id) if trace_id is not None
                                 else None),
                       "pid": int(pid), "ts": float(t0),
                       "dur": max(0.0, float(t1) - float(t0)),
+                      "id": id, "parent": parent, "step": step,
                       "args": attrs})
         self.metrics.inc(f"spans.{name}")
         self.metrics.histogram(f"span.{name}_s").observe(
@@ -807,13 +864,14 @@ class Tracer:
     def event(self, name: str, trace: Optional[int] = None,
               pid: int = 0, **attrs):
         """One per-step instant (dispatch, retry, injected fault,
-        breaker strike, kv alloc/evict/splice/rollback, ...)."""
-        self._record({"kind": "event", "name": name,
-                      "trace": (int(trace) if trace is not None
-                                else None),
-                      "pid": int(pid), "ts": time.perf_counter(),
-                      "args": attrs})
+        breaker strike, kv alloc/evict/splice/rollback, ...). Returns
+        the record as the ring holds it."""
+        rec = {"kind": "event", "name": name,
+               "trace": (int(trace) if trace is not None else None),
+               "pid": int(pid), "ts": time.perf_counter(), "args": attrs}
+        self._record(rec)
         self.metrics.inc(f"events.{name}")
+        return rec
 
     def counter(self, name: str, value, pid: int = 0):
         """One counter-track sample (ISSUE 14): exports as a Perfetto
@@ -938,7 +996,8 @@ class Tracer:
                              "name": r["name"], "pid": r["pid"],
                              "tid": tid, "ts": self._us(r["ts"]),
                              "dur": r["dur"] * 1e6,
-                             "args": r["args"]})
+                             "id": r.get("id"), "parent": r.get("parent"),
+                             "step": r.get("step"), "args": r["args"]})
             elif r["kind"] == "counter":
                 evts.append({"ph": "C", "cat": "track",
                              "name": r["name"], "pid": r["pid"],
@@ -956,3 +1015,193 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return path
+
+
+# -- spans inside the program, on the profiler's clock (ISSUE 27) ------------
+
+_open = threading.local()       # .stack: the spans open on this thread
+_span_ids = itertools.count(1)  # process-wide, so ids are unique across rings
+_default_lock = threading.Lock()
+_default: Optional[Tracer] = None
+
+
+def default_tracer() -> Tracer:
+    """The process-wide ring: where spans go while a profiler session is
+    live and no ``Tracer`` was attached, and where the ``compile.*``
+    events always go. It outlives the engine or trainer that wrote to
+    it, so a reader can ask for it after the system is freed."""
+    global _default
+    if _default is None:
+        with _default_lock:
+            if _default is None:
+                _default = Tracer()
+    return _default
+
+
+def listening(tracer: Optional[Tracer] = None) -> Optional[Tracer]:
+    """The ring a span opened now would be recorded in: the attached
+    ``tracer``, else the default ring while a jax profiler session is
+    live, else None (nobody listens, nothing is recorded)."""
+    if tracer is not None:
+        return tracer
+    return default_tracer() if TraceAnnotation.is_enabled() else None
+
+
+def _enclosing():
+    """(id, step) of the innermost span open on this thread."""
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else (None, None)
+
+
+class span:
+    """``with span("engine.plan", tracer=self.tracer, T=8): ...``
+
+    Always a TraceMe on the profiler's host plane (``annotation`` picks
+    ``jax.profiler.StepTraceAnnotation`` for a step root); a record in
+    the ring only while someone ``listening``. ``step`` names the unit
+    of work (children inherit their parent's); ``trace`` is a request's
+    trace id; ``attrs`` may be added to until the span closes
+    (``sp.attrs["W"] = 16``): the ring gets them all, the TraceMe those
+    known at entry plus ``set`` ones."""
+
+    __slots__ = ("name", "ring", "attrs", "step", "trace", "pid", "id",
+                 "parent", "t0", "_me")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None,
+                 step: Optional[int] = None, trace: Optional[int] = None,
+                 pid: int = 0, annotation=TraceAnnotation, **attrs):
+        self.name = name
+        self.ring = listening(tracer)
+        self.attrs = attrs
+        self.step, self.trace, self.pid = step, trace, pid
+        if step is not None:
+            attrs = dict(attrs, step=step)
+        self._me = annotation(name, **attrs)
+
+    def set(self, **attrs):
+        """Attributes learnt inside the span, to both records (to
+        neither while nobody listens)."""
+        if self.ring is not None:
+            self.attrs.update(attrs)
+            self._me.set_metadata(**attrs)
+
+    def __enter__(self):
+        self._me.__enter__()
+        if self.ring is not None:
+            self.parent, step = _enclosing()
+            if self.step is None:
+                self.step = step
+            self.id = next(_span_ids)
+            stack = getattr(_open, "stack", None)
+            if stack is None:
+                stack = _open.stack = []
+            stack.append((self.id, self.step))
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self.ring is not None:
+            t1 = time.perf_counter()
+            _open.stack.pop()
+            self.ring.span(self.name, self.trace, self.t0, t1, pid=self.pid,
+                           id=self.id, parent=self.parent, step=self.step,
+                           **self.attrs)
+        self._me.__exit__(*exc)
+        return False
+
+
+# jax.monitoring event -> (ring event name, registry counter); durations
+# first, plain counts after
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("compile.trace", "compile.trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lower", "compile.lower_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend", "compile.backend_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("compile.cache_load", "compile.cache_load_s"),
+}
+_COMPILE_COUNTS = {
+    "/jax/compilation_cache/cache_hits":
+        ("compile.cache_hit", "compile.cache_hits"),
+    "/jax/compilation_cache/compile_requests_use_cache":
+        ("compile.cache_request", "compile.cache_requests"),
+}
+# (start, seconds) of the events of each kind not yet found nested in a
+# later one; a jitted function traced inside another's trace ends first
+_compile_tail: Dict[str, List[tuple]] = {}
+_COMPILE_TAIL_MAX = 4096
+# events shorter than this (a process fires thousands: every eager
+# operation and every jitted helper traced inside a program's trace)
+# share one ring record per kind and second, so that they cannot push a
+# run's set-up out of the ring
+_COMPILE_SMALL_S = 1e-3
+_compile_small: Dict[str, dict] = {}
+
+
+def _on_compile_duration(event: str, seconds: float, **kw):
+    names = _COMPILE_DURATIONS.get(event)
+    if names is None:
+        return
+    kind, counter = names
+    seconds = float(seconds)
+    end = time.perf_counter()
+    start = end - seconds
+    small = seconds < _COMPILE_SMALL_S
+    ring = default_tracer()
+    with _default_lock:
+        tail = _compile_tail.setdefault(kind, [])
+        nested = 0.0
+        while tail and tail[-1][0] >= start:
+            nested += tail.pop()[1]
+        if len(tail) >= _COMPILE_TAIL_MAX:
+            del tail[:_COMPILE_TAIL_MAX // 2]
+        tail.append((start, seconds))
+        self_s = max(0.0, seconds - nested)
+        shared = _compile_small.get(kind) if small else None
+        if shared is not None and end - shared["ts"] >= 1.0:
+            shared = None
+        if shared is not None:
+            args = shared["args"]
+            args["seconds"] += seconds
+            args["self_s"] += self_s
+            args["n"] += 1
+    if shared is not None:
+        ring.metrics.inc(f"events.{kind}")
+    elif small:
+        rec = ring.event(kind, fun_name="(under 1 ms each)",
+                         seconds=seconds, self_s=self_s, n=1)
+        with _default_lock:
+            _compile_small[kind] = rec
+    else:
+        ring.event(kind, fun_name=str(kw.get("fun_name", "")),
+                   seconds=seconds, self_s=self_s)
+    ring.metrics.inc(counter, self_s)
+
+
+def _on_compile_event(event: str, **kw):
+    names = _COMPILE_COUNTS.get(event)
+    if names is not None:
+        ring = default_tracer()
+        ring.event(names[0])
+        ring.metrics.inc(names[1])
+
+
+def compile_seconds(t0: float, t1: float) -> Dict[str, float]:
+    """{"trace_s", "lower_s", "backend_s", "cache_load_s"}: wall seconds
+    of the ``compile.*`` events the default ring holds that ENDED inside
+    [t0, t1] (``perf_counter``), each kind's nested events counted once;
+    kinds with no event are left out."""
+    out: Dict[str, float] = {}
+    by_event = {ev: ctr.split(".", 1)[1]
+                for ev, ctr in _COMPILE_DURATIONS.values()}
+    for r in default_tracer().records():
+        key = by_event.get(r["name"]) if r["kind"] == "event" else None
+        if key is not None and t0 <= r["ts"] <= t1:
+            out[key] = out.get(key, 0.0) + r["args"]["self_s"]
+    return out
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_compile_event)

@@ -2,9 +2,10 @@
 
 The §5.1 profiler row delegates device timelines to jax.profiler; the
 hardware proof (real TPU kernel events in the artifact) runs in
-`bench.py profile` on the chip. Here: the summary parser against a real
-CPU capture (host-only -> zero device lanes, exercising the same code
-path), and a chip test that skips off-TPU. Reference analog:
+`bench.py profile` on the chip. Here: the summary against a real CPU
+capture (host-only -> zero device lanes, exercising the same code
+path), and a chip test that skips off-TPU; the summary over a trace cut
+from a chip run is in tests/test_tracing_spans.py. Reference analog:
 /root/reference/paddle/fluid/platform/profiler/cuda_tracer.h.
 """
 import os
@@ -35,9 +36,11 @@ def test_device_trace_summary_on_host_capture(tmp_path):
     s = profiler.device_trace_summary(d)
     assert s["device_events"] == 0
     assert s["device_lanes"] == []
+    assert s["by_scope"] == [] and s["by_kernel"] == []
     # missing dir -> empty summary, no crash
     assert profiler.device_trace_summary(str(tmp_path / "nope")) == {
-        "device_lanes": [], "device_events": 0, "top_kernels": []}
+        "device_lanes": [], "device_events": 0, "top_kernels": [],
+        "by_scope": [], "by_kernel": [], "top_ops": [], "host_spans": []}
 
 
 def test_profiler_exposes_device_trace_dir():

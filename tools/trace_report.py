@@ -6,7 +6,11 @@ Reads the Chrome-trace/Perfetto JSON written by
 a red gate run (or a bench artifact) needs without opening the UI:
 
 - per-phase latency breakdown: count / total / mean / p50 / p99 of
-  every span name (queued, prefill, splice_wait, decode, ...);
+  every span name (queued, prefill, splice_wait, decode, ..., and the
+  program's own engine.* / train_step* spans), and its SELF time: a
+  span's duration minus the part of it the spans it caused (``parent``
+  = its ``id``) cover, so that engine.step's self time is what no
+  named phase accounts for;
 - per-replica occupancy: span-busy seconds per replica track over the
   trace wall clock (an approximation — overlapping spans of different
   requests double-count busy time, so >100% means real concurrency);
@@ -61,6 +65,37 @@ def _pid_name(pid):
     return "fleet" if pid == 1000 else f"replica{pid}"
 
 
+def _self_times(spans) -> dict:
+    """{span name: summed self seconds}: each span's duration minus the
+    union of its children's intervals, a child being a span whose
+    ``parent`` is this span's ``id`` on the same track and which lies
+    inside it (a request phase closed inside an engine step began
+    before the step: it hangs under it but takes nothing from it).
+    Spans without an ``id`` (older traces) count whole; the 1e-3 us
+    lets through what rebasing to microseconds rounds."""
+    kids = defaultdict(list)
+    for s in spans:
+        if s.get("parent") is not None:
+            kids[(s["pid"], s["parent"])].append(s)
+    out: dict = defaultdict(float)
+    for s in spans:
+        if s["name"] == "compile":
+            continue
+        t0, dur = s["ts"], s.get("dur", 0.0)
+        inside = sorted(
+            (k["ts"], k["ts"] + k.get("dur", 0.0))
+            for k in kids.get((s["pid"], s.get("id")), ())
+            if s.get("id") is not None and k["ts"] >= t0 - 1e-3
+            and k["ts"] + k.get("dur", 0.0) <= t0 + dur + 1e-3)
+        covered, end = 0.0, t0
+        for a, b in inside:
+            if b > end:
+                covered += b - max(a, end)
+                end = b
+        out[s["name"]] += (dur - covered) / 1e6
+    return out
+
+
 def analyze(doc: dict, top: int = 5) -> dict:
     evts = doc.get("traceEvents", [])
     spans = [e for e in evts if e.get("ph") == "X"]
@@ -77,11 +112,13 @@ def analyze(doc: dict, top: int = 5) -> dict:
         if s["name"] == "compile":
             continue
         by_phase[s["name"]].append(s.get("dur", 0.0) / 1e6)
+    self_s = _self_times(spans)
     phases = {}
     for name, durs in sorted(by_phase.items()):
         phases[name] = {
             "count": len(durs),
             "total_s": round(sum(durs), 4),
+            "self_s": round(self_s.get(name, sum(durs)), 4),
             "mean_s": round(sum(durs) / len(durs), 5),
             "p50_s": round(_pct(durs, 0.50), 5),
             "p99_s": round(_pct(durs, 0.99), 5),
@@ -96,7 +133,10 @@ def analyze(doc: dict, top: int = 5) -> dict:
         # replica must not read as saturated. Compile spans are
         # warmup/one-off cost with their own table — a grid-warmed
         # trace must not read as a saturated replica either.
-        if s["name"] in ("queued", "splice_wait", "compile"):
+        # The program's own phase spans (no request id: tid 0) lie
+        # over the request spans they serve and would count twice.
+        if s["name"] in ("queued", "splice_wait", "compile") \
+                or not s.get("tid"):
             continue
         busy[s["pid"]] += s.get("dur", 0.0) / 1e6
     dispatch_mix: dict = defaultdict(Counter)
@@ -308,8 +348,8 @@ def format_report(rep: dict) -> str:
     for name, p in rep["phases"].items():
         lines.append(
             f"  {name:12s} n={p['count']:<5d} total={p['total_s']:<9g} "
-            f"mean={p['mean_s']:<9g} p50={p['p50_s']:<9g} "
-            f"p99={p['p99_s']:g}")
+            f"self={p['self_s']:<9g} mean={p['mean_s']:<9g} "
+            f"p50={p['p50_s']:<9g} p99={p['p99_s']:g}")
     lines.append("per-replica occupancy:")
     for name, r in rep["replicas"].items():
         occ = (f"{r['occupancy'] * 100:.1f}%"
