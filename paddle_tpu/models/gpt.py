@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from ..framework.core import apply
 from .. import nn
 from ..nn import functional as F
+from ..nn.functional.loss import causal_lm_loss
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "gpt_tiny",
            "gpt_345m", "ernie_45_dense_3b"]
@@ -226,10 +227,7 @@ class GPTForCausalLM(nn.Layer):
                 None if self.lm_head is None else self.lm_head.weight,
                 self.gpt.embed_tokens.weight,
                 int(self.cfg.chunked_ce_tokens))
-        v = logits.shape[-1]
-        shift_logits = logits[:, :-1, :].reshape([-1, v])
-        shift_labels = labels[:, 1:].reshape([-1])
-        return F.cross_entropy(shift_logits, shift_labels)
+        return causal_lm_loss(logits, labels)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

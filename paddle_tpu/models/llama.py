@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor, apply
 from .. import nn
 from ..nn import functional as F
+from ..nn.functional.loss import causal_lm_loss
 from ..ops.rope import build_rope_cache, rope_reference
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "llama_tiny",
@@ -68,7 +69,10 @@ class LlamaConfig:
     # >0: forward() returns hidden states and loss() computes the head
     # matmul + cross entropy in chunks of this many tokens under
     # jax.checkpoint (training-memory config; generate() still works —
-    # the cached decode path keeps the normal head)
+    # the cached decode path keeps the normal head). 0, the dense path,
+    # holds the [B, S, V] logits and their gradient in the model's
+    # dtype at the end of the forward pass (1 GB each at b4 s4096 v32k
+    # bf16) and nothing float32 of that shape
     chunked_ce_tokens: int = 0
 
 
@@ -411,19 +415,18 @@ class LlamaForCausalLM(nn.Layer):
         return logits
 
     def loss(self, logits, labels):
-        """Shifted causal-LM cross entropy. With
-        cfg.chunked_ce_tokens > 0, forward() returns HIDDEN states and
-        this computes the head matmul + CE in sequence chunks under
-        jax.checkpoint — the [B, S, V] logits (1 GB at b4 s2048 v32k
-        f32, the single biggest activation) are never materialized; the
-        backward rematerializes one chunk's logits at a time."""
+        """Shifted causal-LM cross entropy. The dense path shifts the
+        LABELS and takes the logits whole (``causal_lm_loss``): no
+        sliced copy of them, and the cross entropy's only residual is
+        their gradient in their own dtype, made in the forward pass.
+        With cfg.chunked_ce_tokens > 0, forward() returns HIDDEN states
+        and this computes the head matmul + CE in sequence chunks under
+        jax.checkpoint — the [B, S, V] logits are never materialized;
+        the backward rematerializes one chunk's logits at a time."""
         with jax.named_scope("loss"):
             if self.cfg.chunked_ce_tokens:
                 return self._chunked_loss(logits, labels)
-            v = logits.shape[-1]
-            shift_logits = logits[:, :-1, :].reshape([-1, v])
-            shift_labels = labels[:, 1:].reshape([-1])
-            return F.cross_entropy(shift_logits, shift_labels)
+            return causal_lm_loss(logits, labels)
 
     def _chunked_loss(self, hidden, labels):
         from ..nn.functional.loss import chunked_causal_lm_loss

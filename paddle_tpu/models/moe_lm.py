@@ -17,6 +17,7 @@ from dataclasses import dataclass
 
 from .. import nn
 from ..nn import functional as F
+from ..nn.functional.loss import causal_lm_loss
 from .llama import LlamaAttention, LlamaConfig, _LayerFn
 
 __all__ = ["MoEConfig", "MoEForCausalLM", "MoEModel", "moe_tiny",
@@ -219,10 +220,7 @@ class MoEForCausalLM(nn.Layer):
                 logits, labels, self.lm_head.weight, None,
                 int(self.cfg.chunked_ce_tokens))
         else:
-            v = logits.shape[-1]
-            shift_logits = logits[:, :-1, :].reshape([-1, v])
-            shift_labels = labels[:, 1:].reshape([-1])
-            ce = F.cross_entropy(shift_logits, shift_labels)
+            ce = causal_lm_loss(logits, labels)
         aux = self.model.aux_losses()
         if aux and self.cfg.aux_loss_weight:
             total_aux = aux[0]

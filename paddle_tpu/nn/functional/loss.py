@@ -2,10 +2,13 @@
 /root/reference/python/paddle/nn/functional/loss.py)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from ...framework.core import Tensor, apply
+from ...framework.core import Tensor, apply, apply_nodiff
+from ...utils import telemetry
 
 __all__ = [
     "cross_entropy", "softmax_with_cross_entropy", "mse_loss", "l1_loss",
@@ -26,16 +29,82 @@ def _reduce(loss, reduction):
     return loss
 
 
+def _ce_hard_fwd(logits, idx, ignore_index, reduction):
+    """(loss, d): the cross entropy of integer labels over the last axis
+    and its gradient w.r.t. the logits IN THE LOGITS' DTYPE, already
+    scaled by the reduction's denominator, both made in the forward
+    pass. The float32 cast lives in registers: nothing float32 of the
+    logits' shape leaves the fusions that make the row sums and ``d``."""
+    x = logits.astype(jnp.float32)
+    valid = idx != ignore_index
+    onehot = jax.lax.broadcasted_iota(idx.dtype, x.shape, x.ndim - 1) \
+        == jnp.where(valid, idx, 0)[..., None]
+    shifted = x - jax.lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+    e = jnp.exp(shifted)
+    s = jnp.sum(e, axis=-1)
+    # log_softmax's own arithmetic: -(shifted[idx] - log(sum(exp(shifted))))
+    picked = jnp.sum(jnp.where(onehot, shifted, 0.0), axis=-1)
+    loss = jnp.where(valid, jnp.log(s) - picked, 0.0)
+    rows = valid.astype(jnp.float32)
+    if reduction == "mean":
+        rows = rows / jnp.maximum(jnp.sum(rows), 1.0)
+    if reduction != "none":
+        loss = jnp.sum(loss * rows)
+    d = e * (rows / s)[..., None]
+    d = jnp.where(onehot, d - rows[..., None], d)
+    return loss, d.astype(logits.dtype)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(2, 3))
+def _ce_hard(logits, idx, ignore_index, reduction):
+    return _ce_hard_fwd(logits, idx, ignore_index, reduction)[0]
+
+
+@_ce_hard.defjvp
+def _ce_hard_jvp(ignore_index, reduction, primals, tangents):
+    """tangent = <d, t> with float32 accumulation. ``d`` is the only
+    thing the linear part reads, so it is the one residual of a vjp, and
+    the transpose is ``(g * d)`` rounded once to the logits' dtype: the
+    rounding autodiff's transpose of the float32 cast made."""
+    logits, idx = primals
+    loss, d = _ce_hard_fwd(logits, idx, ignore_index, reduction)
+    t_loss = jnp.einsum(
+        "...v,...v->..." if reduction == "none" else "...v,...v->",
+        d, tangents[0], preferred_element_type=jnp.float32)
+    return loss, t_loss
+
+
 def cross_entropy(input, label, weight=None, ignore_index=-100,
                   reduction="mean", soft_label=False, axis=-1,
                   use_softmax=True, label_smoothing=0.0, name=None):
+    """Integer labels over the last axis with ``use_softmax`` and neither
+    class weights nor smoothing (what every language model here calls)
+    take ``_ce_hard``: its vjp keeps ONE residual, the gradient w.r.t.
+    the logits in the logits' dtype, made in the forward pass. Every
+    other case is differentiated by jax through the body below, whose
+    residual is the float32 log-probabilities. Which of the two a call
+    took is counted in the default registry
+    (``loss.cross_entropy.grad_in_forward`` / ``.autodiff``)."""
     def f(logits, lbl, *w):
         n_classes = logits.shape[axis]
+        is_soft = soft_label or (lbl.ndim == logits.ndim
+                                 and lbl.shape == logits.shape)
+        hard_rule = (not is_soft and not w and use_softmax
+                     and label_smoothing == 0
+                     and axis % logits.ndim == logits.ndim - 1
+                     and jnp.issubdtype(lbl.dtype, jnp.integer)
+                     and reduction in ("mean", "sum", "none"))
+        telemetry.default_tracer().metrics.inc(
+            "loss.cross_entropy."
+            + ("grad_in_forward" if hard_rule else "autodiff"))
+        if hard_rule:
+            idx = jnp.squeeze(lbl, -1) if lbl.ndim == logits.ndim else lbl
+            return _ce_hard(logits, idx, ignore_index, reduction)
         if use_softmax:
             logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=axis)
         else:
             logp = jnp.log(jnp.maximum(logits.astype(jnp.float32), 1e-30))
-        if soft_label or (lbl.ndim == logits.ndim and lbl.shape == logits.shape):
+        if is_soft:
             soft = lbl.astype(logp.dtype)
             if label_smoothing > 0:
                 soft = soft * (1 - label_smoothing) + label_smoothing / n_classes
@@ -400,6 +469,23 @@ def chunked_causal_lm_loss(hidden, labels, lm_head_weight,
     return chunked_softmax_cross_entropy(
         hidden, labels, embedding_weight, chunk_tokens,
         transpose_weight=True, ignore_index=ignore_index)
+
+
+def causal_lm_loss(logits, labels, ignore_index: int = -100):
+    """Next-token cross entropy of dense [B, S, V] logits, the dense
+    counterpart of ``chunked_causal_lm_loss``. The causal shift is made
+    on the LABELS (``labels[:, 1:]`` with one ``ignore_index`` column
+    appended), so the logits are only reshaped to [B*S, V], a bitcast:
+    ``logits[:, :-1]`` would copy them into B*(S-1) rows, which no tile
+    divides. Same sum over the same B*(S-1) positions, same denominator."""
+    def shift(y):
+        last = jnp.full((y.shape[0], 1), ignore_index, y.dtype)
+        return jnp.concatenate([y[:, 1:], last], axis=1).reshape(-1)
+
+    v = logits.shape[-1]
+    return cross_entropy(logits.reshape([-1, v]),
+                         apply_nodiff("shift_labels", shift, labels),
+                         ignore_index=ignore_index)
 
 
 def poisson_nll_loss(input, label, log_input=True, full=False, epsilon=1e-8,

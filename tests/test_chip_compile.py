@@ -113,6 +113,34 @@ def test_decode_matmul_int4(one_chip, on_chip, k, n):
     assert _kernels_in(decode_matmul, x, w) == 1
 
 
+def test_head_and_dense_loss_at_the_training_cell_shape(one_chip, on_chip):
+    """Mistral-7B's head and the models' dense causal loss at batch 4 x
+    4096, forward and backward: what the end of the forward pass holds.
+    The loss hands its gradient back in bf16 from the forward pass and
+    shifts the labels, so the program needs the bf16 logits and their
+    gradient and nothing float32 of that shape. Reading: 1.0002 logits'
+    worth of temporaries (the gradient is written over the logits); the
+    form before it (sliced logits, log_softmax differentiated by jax)
+    read 3.0008, and its step program re-ran the head's matmul."""
+    import paddle_tpu as paddle
+    from paddle_tpu.nn.functional.loss import causal_lm_loss
+    b, s, d, v = 4, 4096, 4096, 32768
+
+    def head_loss(h, w, labels):
+        with paddle.no_grad():
+            logits = jnp.einsum("bsd,dv->bsv", h, w)
+            return causal_lm_loss(paddle.Tensor(logits),
+                                  paddle.Tensor(labels))._value
+
+    compiled = jax.jit(jax.value_and_grad(head_loss, argnums=(0, 1))).lower(
+        _sds(one_chip, (b, s, d), BF16), _sds(one_chip, (d, v), BF16),
+        _sds(one_chip, (b, s))).compile()
+    logits_bytes = b * s * v * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * logits_bytes + (64 << 20)
+    assert ".remat" not in compiled.as_text()
+
+
 # -- the gate: what the chip's compiler refuses never reaches it ------------
 
 def test_gate_refuses_head_dim_64(on_chip):
