@@ -4,6 +4,29 @@ against the plain reference, each number beside a limit of its own.
 Limits are data, ``benchmark/limits/<workload>.json``: {"<number>":
 {"limit": x, "lower": ..., "upper": ...}} with the readings each was set
 from. A number without an entry there is reported and not judged.
+
+**What a family's plain reference has** (``benchmark/reference/<name>.py``,
+named by the family module's ``REFERENCE``). It imports nothing of the
+program, makes its weights again from the seed (``benchmark.weights``)
+and computes in float32 at ``highest``. ``precision`` is ``"stated"``, or
+``"lower"`` for the control: the same equations in the nearest precision
+below the one the configuration states, which only ``readings.py`` and
+the tests ask for.
+
+``train_params(cfg, seed, precision="stated")``
+    {leaf name: float32 array}, every leaf of the family's list, as the
+    trainer's parameters start.
+``loss_and_grads(params, batch, cfg, precision="stated", rows=None)``
+    (loss as a float, {leaf name: gradient}): the mean next-token loss of
+    a [batch, seq] array of token ids and its gradients, in blocks that
+    fit beside the parameters; ``rows`` limits the mean to those rows (a
+    planted fault).
+``sequence_logits(cfg, seed, seqs, positions, precision="stated")``,
+    where the family serves: one [len(positions[i]), vocab] float32
+    array per sequence, the next-token logits at those positions.
+
+AdamW and the norms per leaf are no family's: ``benchmark/reference/
+adamw.py``.
 """
 import json
 import os
@@ -12,6 +35,12 @@ import statistics
 import numpy as np
 
 from . import manifest
+from .reference import adamw
+
+
+def reference_of(cfg: dict):
+    """The plain reference of a configuration's family."""
+    return manifest.module("reference", manifest.family_of(cfg).REFERENCE)
 
 
 def load_limits(workload: str) -> dict:
@@ -59,11 +88,10 @@ def gaps_below_best(logits, tokens):
 def serve_numbers(cfg: dict, seed: int, sample) -> dict:
     """The widest gap by which a served token's logit lies below the
     reference's best, over every served token of the sample."""
-    from .reference import llama_ref
     if not sample:
         return {"served_tokens_checked": 0}
     seqs, pos, toks = served_sequences(sample)
-    logits = llama_ref.sequence_logits(cfg, seed, seqs, pos)
+    logits = reference_of(cfg).sequence_logits(cfg, seed, seqs, pos)
     gaps = np.concatenate([gaps_below_best(l, t)
                            for l, t in zip(logits, toks)])
     return {"served_logit_gap_max": float(gaps.max()),
@@ -78,23 +106,19 @@ def reference_train_readings(cfg: dict, seed: int, batches,
                              precision: str = "stated", rows=None) -> dict:
     """The reference's own loss of each step, first gradient norm per leaf
     and change of every leaf after the steps."""
-    import jax.numpy as jnp
-    from .reference import llama_ref
-    model = cfg["model"]
-    act_fmt = "fp8" if precision == "lower" else None
-    params = llama_ref.train_params(cfg, seed, precision)
-    start = llama_ref.train_params(cfg, seed)
+    ref = reference_of(cfg)
+    params = ref.train_params(cfg, seed, precision)
+    start = ref.train_params(cfg, seed)
     state = {"m": {}, "v": {}}
     out = {"loss": [], "grad_norm": {}, "change_norm": {}}
     for t, batch in enumerate(batches, 1):
-        loss, grads = llama_ref.loss_and_grads(params, batch, model,
-                                               act_fmt, rows)
+        loss, grads = ref.loss_and_grads(params, batch, cfg, precision, rows)
         out["loss"].append(loss)
         if t == 1:
-            out["grad_norm"] = llama_ref.leaf_norms(grads)
-        params, state = llama_ref.adamw_step(params, grads, state,
-                                             cfg["optimizer"], t)
-    out["change_norm"] = llama_ref.leaf_norms(
+            out["grad_norm"] = adamw.leaf_norms(grads)
+        params, state = adamw.adamw_step(params, grads, state,
+                                         cfg["optimizer"], t)
+    out["change_norm"] = adamw.leaf_norms(
         {k: params[k] - start[k] for k in params})
     return out
 

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import systems, traffic, weights as W
+from .. import manifest, systems, traffic, weights as W
 from ._serving import span
 
 CHECK_STEPS = 3
@@ -46,9 +46,9 @@ def setup(cfg: dict, mix: dict, seed: int, log):
             for name in trainer.leaves:
                 readings["grad_norm"][name] = \
                     _norm(trainer.opt_leaf("m", name)) / (1.0 - b1)
-    for name, shape in W.leaf_shapes(cfg["model"]):
-        start = W.make_leaf(seed, name, shape, cfg["model"]["torch_dtype"],
-                            cfg["init_scale"]).astype(jnp.float32)
+    seeded = W.Leaves(manifest.family_of(cfg), cfg, seed)
+    for name in seeded.shapes:
+        start = seeded.make(name).astype(jnp.float32)
         now = trainer.opt_leaf("master", name)
         now = trainer.leaves[name]._value if now is None else now
         readings["change_norm"][name] = _norm(now - start)

@@ -1,4 +1,6 @@
-"""BENCHMARK.json and the data files it names, found by name alone."""
+"""BENCHMARK.json, the data files it names and the modules they name,
+found by name alone."""
+import importlib
 import json
 import os
 
@@ -38,6 +40,29 @@ def metric_file(name: str) -> dict:
 
 def peaks() -> dict:
     return load_json(DATA, "peaks.json")
+
+
+def module_names(kind: str):
+    """The modules ``benchmark/<kind>/*.py`` that a data file may name."""
+    return sorted(f[:-3] for f in os.listdir(os.path.join(HERE, kind))
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def module(kind: str, name: str):
+    """``benchmark/<kind>/<name>.py``: a driver, a reader, a family or a
+    reference is a module that exists."""
+    if name not in module_names(kind):
+        raise ValueError(f"no benchmark/{kind}/{name}.py; there are: "
+                         f"{', '.join(module_names(kind))}")
+    return importlib.import_module(f"{__package__}.{kind}.{name}")
+
+
+def family_of(cfg: dict):
+    """The family module of a configuration (``benchmark/families``)."""
+    if "family" not in cfg:
+        raise KeyError("the configuration names no \"family\"; there are: "
+                       f"{', '.join(module_names('families'))}")
+    return module("families", cfg["family"])
 
 
 def metrics_for(workload_name: str, kind: str):

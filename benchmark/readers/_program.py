@@ -26,6 +26,20 @@ def records(ctx) -> list:
         return []
 
 
+def counters(prefixes) -> dict:
+    """The counters of the program's process-wide registry whose names
+    start with one of ``prefixes`` (``compile.`` says what set-up
+    compiled or loaded, ``loss.`` which loss body a step was built
+    from); nothing where the program keeps no such registry."""
+    try:
+        from paddle_tpu.utils import telemetry
+        found = telemetry.default_tracer().metrics.snapshot()["counters"]
+    except (ImportError, AttributeError):
+        return {}
+    return {k: v for k, v in sorted(found.items())
+            if k.startswith(tuple(prefixes))}
+
+
 def spans(ctx) -> list:
     """Span records that lie inside the traced sub-window."""
     t0, t1 = ctx["trace_clock"]

@@ -1,8 +1,9 @@
 """A kernel's share of its roofline: the least time the chip could take
-for what the algorithm needs (``work`` names a count of ``_work.py``,
-``bound`` says whether FLOP/s or bytes/s bounds it) over the summed
-device time of the operations whose name matches ``pattern``."""
-from .. import costs, trace_reduce
+for what the algorithm needs (``work`` names a count of the family's
+``KERNEL_WORK`` table, ``bound`` says whether FLOP/s or bytes/s bounds it)
+over the summed device time of the operations whose name matches
+``pattern``."""
+from .. import trace_reduce
 from ._work import traced_work
 
 
@@ -11,13 +12,11 @@ def read(ctx, pattern, work, bound, within_modules=None):
                                         within_modules)
     if not n or seconds <= 0:
         return None
-    w = traced_work(ctx)
-    if work == "decode_kv_bytes":
-        need = costs.kv_read_bytes(ctx["model"], w.get("decode_pairs", 0))
-    elif work == "flash_flops":
-        need = w.get("flash_flops", 0)
-    else:
-        raise ValueError(f"unknown work {work!r}")
+    counts = ctx["family"].KERNEL_WORK
+    if work not in counts:
+        raise ValueError(f"{ctx['family'].__name__} counts no {work!r}; "
+                         f"it counts: {', '.join(sorted(counts))}")
+    need = counts[work](ctx["model"], traced_work(ctx))
     if not need:
         return None
     peak = ctx["peak"]["flops_per_s_bf16" if bound == "flops"

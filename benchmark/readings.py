@@ -33,16 +33,15 @@ def emit(out, **row):
 
 
 def serve(args):
-    import importlib
     import numpy as np
     from benchmark import manifest, traffic, check, systems, run as R
     from benchmark.drivers import _serving as S
-    from benchmark.reference import llama_ref
     names = args.workloads.split(",")
     cells = [manifest.workload(n) for n in names]
     cfg = manifest.config_of(cells[0])
     if any(c["config"] != cells[0]["config"] for c in cells):
         raise SystemExit("one process reads one configuration")
+    reference = check.reference_of(cfg)
     mixes = [traffic.load_mix(c["traffic"]) for c in cells]
     seeds = [int(s) for s in args.seeds.split(",")]
     eng, _ = S.setup(cfg, mixes[0], seeds[0], R.log)
@@ -54,8 +53,7 @@ def serve(args):
             R.log(f"seed {seed}: weights swapped in "
                   f"{time.perf_counter() - t0:.1f} s")
         for name, mix in zip(names, mixes):
-            driver = importlib.import_module(
-                f"benchmark.drivers.{mix['driver']}")
+            driver = manifest.module("drivers", mix["driver"])
             hooks = R.Hooks(False, mix, args.seconds)
             res = driver.run(eng, mix, cfg["model"]["vocab_size"], seed,
                              args.seconds, hooks)
@@ -74,7 +72,7 @@ def serve(args):
     controls = set(seeds[:args.control_seeds])
     for name, seed, (seqs, pos, toks), attempted, failed, comp in kept:
         t0 = time.perf_counter()
-        ref = llama_ref.sequence_logits(cfg, seed, seqs, pos)
+        ref = reference.sequence_logits(cfg, seed, seqs, pos)
         gaps = np.concatenate([check.gaps_below_best(l, t)
                                for l, t in zip(ref, toks)])
         row = {"workload": name, "seed": seed, "who": "program",
@@ -87,7 +85,7 @@ def serve(args):
         emit(args.out, **row)
         if seed in controls:
             t0 = time.perf_counter()
-            low = llama_ref.sequence_logits(cfg, seed, seqs, pos,
+            low = reference.sequence_logits(cfg, seed, seqs, pos,
                                             precision="lower")
             cg = np.concatenate([
                 check.gaps_below_best(r, np.asarray(l).argmax(-1))

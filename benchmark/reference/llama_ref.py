@@ -3,7 +3,8 @@ embedding in the half-split convention, grouped-query causal attention,
 SwiGLU, untied head), in straightforward ``jax.numpy``.
 
 Imports nothing of the program and takes nothing the program made: the
-weights come again from the seed (``benchmark.weights``), and where the
+weights come again from the seed (``benchmark.weights``, by the family's
+leaf list), and where the
 configuration states weight-only int8 the reference quantises them itself
 (per-output-channel absmax, the published scheme) and computes in float32
 with the dequantised values. Every matmul runs under
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import weights as W
+from ..families import llama as family
 
 F32 = jnp.float32
 HI = "highest"
@@ -140,7 +142,7 @@ def _layer_weights_j(seed31, tags, shapes, dtype, init_scale, fmt):
     for j, (k, shape) in enumerate(shapes):
         leaf = W.leaf_traced(seed31, tags[j], shape, dtype,
                              init_scale).astype(F32)
-        out[k] = stored_weight(leaf, fmt) if k in W.LAYER_MATS else leaf
+        out[k] = stored_weight(leaf, fmt) if k in family.LAYER_MATS else leaf
     return out
 
 
@@ -148,8 +150,8 @@ def layer_weights(cfg: dict, seed: int, i: int, fmt: str):
     """Layer i's leaves from the seed, matrices as ``fmt`` stores them
     (one compiled function makes every layer)."""
     m = cfg["model"]
-    all_shapes = dict(W.leaf_shapes(m))
-    keys = ("ln1", "ln2") + W.LAYER_MATS
+    all_shapes = dict(family.leaf_shapes(m))
+    keys = ("ln1", "ln2") + family.LAYER_MATS
     shapes = tuple((k, tuple(all_shapes[f"layers.{i}.{k}"])) for k in keys)
     tags = np.asarray([W.leaf_tag(f"layers.{i}.{k}") for k in keys],
                       np.int32)
@@ -158,9 +160,7 @@ def layer_weights(cfg: dict, seed: int, i: int, fmt: str):
 
 
 def top_leaf(cfg: dict, seed: int, name: str, fmt: str = None):
-    m = cfg["model"]
-    leaf = W.make_leaf(seed, name, dict(W.leaf_shapes(m))[name],
-                       m["torch_dtype"], cfg["init_scale"]).astype(F32)
+    leaf = W.Leaves(family, cfg, seed).make(name).astype(F32)
     return stored_weight(leaf, fmt) if fmt else leaf
 
 
@@ -222,13 +222,12 @@ def _head_logits(x, pos, norm, head, eps, act_fmt):
 
 def train_params(cfg: dict, seed: int, precision: str = "stated"):
     """Every leaf in float32, as the trainer's parameters start."""
-    m = cfg["model"]
     fmt = LOWER[cfg["precision"]["weights"]] if precision == "lower" \
         else None
+    seeded = W.Leaves(family, cfg, seed)
     out = {}
-    for name, shape in W.leaf_shapes(m):
-        leaf = W.make_leaf(seed, name, shape, m["torch_dtype"],
-                           cfg["init_scale"]).astype(F32)
+    for name, shape in seeded.shapes.items():
+        leaf = seeded.make(name).astype(F32)
         is_mat = len(shape) == 2 and name != "embed"
         out[name] = stored_weight(leaf, fmt) if (fmt and is_mat) else leaf
     return out
@@ -239,7 +238,7 @@ def row_loss_sum(params, ids, model, act_fmt=None):
     x = jnp.take(params["embed"], ids, axis=0)
     for i in range(model["num_hidden_layers"]):
         lw = {k: params[f"layers.{i}.{k}"]
-              for k in ("ln1", "ln2") + W.LAYER_MATS}
+              for k in ("ln1", "ln2") + family.LAYER_MATS}
         x = jax.checkpoint(functools.partial(
             layer, model=model, act_fmt=act_fmt))(x, lw)
     h = rms_norm(x, params["norm"], model["rms_norm_eps"])
@@ -251,10 +250,13 @@ def row_loss_sum(params, ids, model, act_fmt=None):
     return jax.checkpoint(ce)(h[:-1], ids[1:])
 
 
-def loss_and_grads(params, batch, model, act_fmt=None, rows=None):
+def loss_and_grads(params, batch, cfg: dict, precision: str = "stated",
+                   rows=None):
     """Mean shifted causal-LM loss over the batch and its gradients, one
     row at a time so that a row's activations are all that is held.
     ``rows`` limits the mean to those rows (a planted fault in tests)."""
+    model = cfg["model"]
+    act_fmt = "fp8" if precision == "lower" else None
     batch = np.asarray(batch, np.int32)
     rows = list(range(batch.shape[0])) if rows is None else list(rows)
     denom = len(rows) * (batch.shape[1] - 1)
@@ -273,36 +275,3 @@ def loss_and_grads(params, batch, model, act_fmt=None, rows=None):
                         donate_argnums=(0,))
         grads = scale(grads)
     return loss / denom, grads
-
-
-@functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(0, 1, 2, 3))
-def _adamw_leaf(p, g, m_, v_, hp, t):
-    lr, b1, b2, eps, wd = hp
-    m_ = b1 * m_ + (1 - b1) * g
-    v_ = b2 * v_ + (1 - b2) * g * g
-    upd = (m_ / (1 - b1 ** t)) / (jnp.sqrt(v_ / (1 - b2 ** t)) + eps)
-    return p * (1 - lr * wd) - lr * upd, m_, v_
-
-
-def adamw_step(params, grads, state, opt: dict, t: int):
-    """Decoupled-weight-decay Adam on every leaf. ``state`` is {"m": {},
-    "v": {}} of host arrays (empty before the first step): the moments
-    wait on the host between steps, so that the device holds the
-    parameters, one gradient tree and a row's activations, no more."""
-    hp = (opt["learning_rate"], opt["beta1"], opt["beta2"], opt["epsilon"],
-          opt["weight_decay"])
-    for name in list(params):
-        g = grads.pop(name)
-        m_ = jnp.asarray(state["m"][name]) if name in state["m"] \
-            else jnp.zeros_like(g)
-        v_ = jnp.asarray(state["v"][name]) if name in state["v"] \
-            else jnp.zeros_like(g)
-        params[name], m_, v_ = _adamw_leaf(
-            params[name], g, m_, v_, hp, jnp.asarray(float(t), F32))
-        state["m"][name], state["v"][name] = np.asarray(m_), np.asarray(v_)
-    return params, state
-
-
-def leaf_norms(tree: dict) -> dict:
-    return {k: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(F32)))))
-            for k, v in tree.items()}

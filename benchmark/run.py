@@ -14,7 +14,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse         # noqa: E402
-import importlib        # noqa: E402
 import json             # noqa: E402
 import os               # noqa: E402
 import shutil           # noqa: E402
@@ -90,8 +89,7 @@ class Hooks:
 
     @staticmethod
     def compiles(system) -> int:
-        """Compiles the system's own watch has counted (a serving
-        engine has one; the trainer has none)."""
+        """Compiles the system's own watch has counted."""
         watch = getattr(system, "compile_watch", None)
         return watch.compiles if watch is not None else 0
 
@@ -157,8 +155,7 @@ def read_per_layer(names, ctx):
     out = {}
     for name in names:
         spec = manifest.metric_file(name)
-        reader = importlib.import_module(
-            f"benchmark.readers.{spec['reader']}")
+        reader = manifest.module("readers", spec["reader"])
         value = reader.read(ctx, **spec.get("args", {}))
         if value is not None:
             out[name] = {"value": float(value), "unit": spec["unit"]}
@@ -176,6 +173,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from benchmark import manifest, traffic, check
+    from benchmark.readers import _program
     cell = manifest.workload(args.workload)
     cfg = manifest.config_of(cell)
     mix = traffic.load_mix(cell["traffic"])
@@ -189,7 +187,7 @@ def main(argv=None) -> int:
     log(f"device {device}; compile cache "
         f"{os.environ['JAX_COMPILATION_CACHE_DIR']}")
 
-    driver = importlib.import_module(f"benchmark.drivers.{mix['driver']}")
+    driver = manifest.module("drivers", mix["driver"])
     system, setup = driver.setup(cfg, mix, args.seed, log)
     log(f"set-up detail: {json.dumps(setup.get('programs', []))}")
 
@@ -198,6 +196,8 @@ def main(argv=None) -> int:
                      args.seconds, hooks)
     log(f"window closed: attempted {res['attempted']} failed "
         f"{res['failed']}; set-up {hooks.setup_s:.2f} s")
+    log("program counters: " + json.dumps(
+        _program.counters(("compile.", "loss."))))
     device["memory_peak_bytes"] = memory_peak_bytes(cell["chips"])
     in_window = hooks.compiles_close - hooks.compiles_open
     driver.release(system)
@@ -227,6 +227,7 @@ def main(argv=None) -> int:
         busy, window = trace_reduce.busy_and_window_s(trace)
         device["busy_s"], device["window_s"] = busy, window
         ctx = {"model": cfg["model"], "cfg": cfg, "mix": mix, "peak": peak,
+               "family": manifest.family_of(cfg),
                "stats": hooks.stats, "clock": res["clock"],
                "trace": trace, "res": res,
                "trace_clock": (hooks.trace_t0, hooks.trace_t1)}

@@ -1,36 +1,18 @@
 """The system under test, built the way a user builds it.
 
-The only module of the benchmark that imports the program. Serving:
-``PagedLlamaDecoder.from_weight_loader`` (the benchmark's seeded leaves,
-quantised by the program as they arrive) behind ``ServingEngine``.
-Training: ``LlamaForCausalLM`` + ``optimizer.AdamW`` + ``jit.TrainStep``
-with the same leaves assigned to its parameters.
+This module and the family modules (``benchmark/families``) are the only
+ones of the benchmark that import the program. Serving: the family's
+decoder, fed the benchmark's seeded leaves through the program's loader
+entry (quantised by the program as they arrive), behind
+``ServingEngine``. Training: the family's trainable model +
+``optimizer.AdamW`` + ``jit.TrainStep``, with the same leaves assigned
+to its parameters.
 """
 import time
 
 import numpy as np
 
-from . import weights as W
-
-
-def llama_config(cfg: dict, **extra):
-    from paddle_tpu.models import LlamaConfig
-    m = cfg["model"]
-    if m["hidden_size"] != m["num_attention_heads"] * m["head_dim"]:
-        raise ValueError("the program derives head_dim as hidden_size / "
-                         "num_attention_heads; this config disagrees")
-    if m.get("sliding_window") is not None:
-        raise ValueError("the program has no sliding-window attention")
-    return LlamaConfig(
-        vocab_size=m["vocab_size"], hidden_size=m["hidden_size"],
-        intermediate_size=m["intermediate_size"],
-        num_hidden_layers=m["num_hidden_layers"],
-        num_attention_heads=m["num_attention_heads"],
-        num_key_value_heads=m["num_key_value_heads"],
-        max_position_embeddings=m["max_position_embeddings"],
-        rms_norm_eps=m["rms_norm_eps"], rope_theta=m["rope_theta"],
-        tie_word_embeddings=m["tie_word_embeddings"],
-        dtype=m["torch_dtype"], **extra)
+from . import manifest, weights as W
 
 
 # -- serving ------------------------------------------------------------------
@@ -38,15 +20,9 @@ def llama_config(cfg: dict, **extra):
 def build_engine(cfg: dict, seed: int):
     """(engine, seconds to make and quantise the weights)."""
     from paddle_tpu.inference import ServingEngine
-    from paddle_tpu.inference.paged_decode import PagedLlamaDecoder
-    lcfg = llama_config(cfg)
-    dtype = cfg["model"]["torch_dtype"]
-
-    def load(name, shape):
-        return W.make_leaf(seed, name, shape, dtype, cfg["init_scale"])
-
+    family = manifest.family_of(cfg)
     t0 = time.perf_counter()
-    dec = PagedLlamaDecoder.from_weight_loader(lcfg, load, **cfg["decoder"])
+    dec = family.build_decoder(cfg, W.Leaves(family, cfg, seed).make)
     t_weights = time.perf_counter() - t0
     opts = dict(cfg["engine"])
     if "prompt_buckets" in opts:
@@ -120,25 +96,6 @@ def warm_ragged(eng, pairs, log=None):
 
 # -- training -----------------------------------------------------------------
 
-_TRAIN_NAMES = {"wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
-                "wv": "self_attn.v_proj", "wo": "self_attn.o_proj",
-                "wg": "mlp.gate_proj", "wu": "mlp.up_proj",
-                "wd": "mlp.down_proj", "ln1": "input_layernorm",
-                "ln2": "post_attention_layernorm"}
-
-
-def train_param_name(leaf: str) -> str:
-    """The benchmark's leaf name -> LlamaForCausalLM's parameter name."""
-    if leaf == "embed":
-        return "model.embed_tokens.weight"
-    if leaf == "norm":
-        return "model.norm.weight"
-    if leaf == "head":
-        return "lm_head.weight"
-    _, i, k = leaf.split(".")
-    return f"model.layers.{i}.{_TRAIN_NAMES[k]}.weight"
-
-
 class Trainer:
     """One compiled step with its state. ``leaves`` maps the benchmark's
     leaf names to the model's live parameters."""
@@ -146,21 +103,19 @@ class Trainer:
     def __init__(self, cfg: dict, seed: int):
         import paddle_tpu as paddle
         from paddle_tpu import optimizer
-        from paddle_tpu.models import LlamaForCausalLM
         self.paddle = paddle
         paddle.seed(W.model_seed(seed))
-        lcfg = llama_config(cfg, **cfg["trainer"])
-        model = LlamaForCausalLM(lcfg)
+        family = manifest.family_of(cfg)
+        model, param_of = family.build_trainable(cfg)
         named = dict(model.named_parameters())
-        dtype = cfg["model"]["torch_dtype"]
+        seeded = W.Leaves(family, cfg, seed)
         self.leaves = {}
-        for name, shape in W.leaf_shapes(cfg["model"]):
-            p = named.pop(train_param_name(name))
-            if tuple(p.shape) != tuple(shape):
+        for name, shape in seeded.shapes.items():
+            p = named.pop(param_of[name])
+            if tuple(p.shape) != shape:
                 raise ValueError(f"{name}: model has {tuple(p.shape)}, "
                                  f"config says {shape}")
-            p._replace(W.make_leaf(seed, name, shape, dtype,
-                                   cfg["init_scale"]))
+            p._replace(seeded.make(name))
             self.leaves[name] = p
         if named:
             raise ValueError(f"parameters without a seeded leaf: "
@@ -173,6 +128,12 @@ class Trainer:
         self.model = model
         self.step = paddle.jit.TrainStep(
             model, lambda out, lab: model.loss(out, lab), self.opt)
+
+    @property
+    def compile_watch(self):
+        """The step's own count of compiles, where ``Hooks.compiles``
+        looks for it."""
+        return self.step.compile_watch
 
     def __call__(self, ids):
         """One step on a [batch, seq] int32 array; returns the loss, not
@@ -198,20 +159,14 @@ def reseed_engine(eng, cfg: dict, seed: int):
     the benchmark builds its engine from its own seed."""
     import gc
     import jax.numpy as jnp
-    from paddle_tpu.inference.paged_decode import PagedLlamaDecoder
     cache = eng.dec.cache
     # the pool goes too while the weights are made: quantising the head
     # needs a gigabyte of float32 that the pool leaves no room for
     planes = [(a.shape, a.dtype) for a in cache.k]
     eng.dec.weights = cache.k = cache.v = None
     gc.collect()
-    dtype = cfg["model"]["torch_dtype"]
-
-    def load(name, shape):
-        return W.make_leaf(seed, name, shape, dtype, cfg["init_scale"])
-
-    small = dict(cfg["decoder"], num_blocks=2)
-    eng.dec.weights = PagedLlamaDecoder.from_weight_loader(
-        llama_config(cfg), load, **small).weights
+    family = manifest.family_of(cfg)
+    eng.dec.weights = family.build_decoder(
+        cfg, W.Leaves(family, cfg, seed).make, num_blocks=2).weights
     cache.k = [jnp.zeros(s, d) for s, d in planes]
     cache.v = [jnp.zeros(s, d) for s, d in planes]

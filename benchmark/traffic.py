@@ -1,10 +1,11 @@
 """One general traffic generator, driven by a mix's data file.
 
-A mix (``benchmark/traffic/<mix>.json``) names a ``driver`` and gives its
-parameters. Every seed draws the SAME multiset of sizes and arrival gaps
-(stratified quantiles of the stated distributions) in another order, with
-other token ids: runs differ by order and content, never by the amount of
-work, so a spread between seeds is the system's and not the sample's.
+A mix (``benchmark/traffic/<mix>.json``) names a ``driver``, a module
+``benchmark/drivers/<driver>.py``, and gives its parameters. Every seed
+draws the SAME multiset of sizes and arrival gaps (stratified quantiles of
+the stated distributions) in another order, with other token ids: runs
+differ by order and content, never by the amount of work, so a spread
+between seeds is the system's and not the sample's.
 """
 import json
 import math
@@ -19,9 +20,11 @@ from . import manifest
 def load_mix(name: str) -> dict:
     with open(os.path.join(manifest.DATA, "traffic", f"{name}.json")) as f:
         mix = json.load(f)
-    if mix.get("driver") not in ("open_loop", "closed_loop", "train_steps"):
-        raise ValueError(f"traffic mix {name}: unknown driver "
-                         f"{mix.get('driver')!r}")
+    drivers = manifest.module_names("drivers")
+    if mix.get("driver") not in drivers:
+        raise ValueError(f"traffic mix {name}: no driver "
+                         f"{mix.get('driver')!r}; there are: "
+                         f"{', '.join(drivers)}")
     return mix
 
 

@@ -272,3 +272,30 @@ def test_fault_half_of_the_batch_left_out(tmp_path, monkeypatch, capsys):
     assert rc == 0 and line["correct"] is False
     grad = line["checks"]["grad_norm_gap_worst_leaf"]
     assert grad["value"] > grad["limit"]
+
+
+def test_fault_a_compile_inside_the_training_window(tmp_path, monkeypatch,
+                                                    capsys):
+    """The window's second step arrives at another length, so the step
+    compiles again: the trainer's own watch counts it, ``correct`` comes
+    out false, and the log says what the registry counted."""
+    tiny_tree.point_at(monkeypatch, str(tmp_path))
+    tiny_tree.let_cpu_through(monkeypatch)
+    calls = []
+
+    def reshaped(self, ids, call):
+        calls.append(1)
+        # three steps of set-up, then the window
+        return call(self, ids[:, :32] if len(calls) == 5 else ids)
+    _break_trainer(monkeypatch, reshaped)
+    rc = run.main(["--workload", "t_train", "--seed", "7", "--seconds",
+                   "1.5", "--trace", "0"])
+    cap = capsys.readouterr()
+    line = json.loads(cap.out.strip().splitlines()[-1])
+    assert rc == 0 and line["failed"] == 0 and line["correct"] is False
+    assert line["checks"]["compiles_in_window"] == {"value": 1, "limit": 0}
+    counters = json.loads(
+        cap.err.split("program counters: ")[1].splitlines()[0])
+    assert counters["loss.cross_entropy.grad_in_forward"] >= 1
+    assert any(k.startswith("compile.") for k in counters)
+    assert all(k.startswith(("compile.", "loss.")) for k in counters)

@@ -113,15 +113,22 @@ def test_four_chip_cells_are_at_most_a_quarter(bench):
     assert four <= max(1, len(bench["workloads"]) // 4)
 
 
-def test_per_layer_metrics_match_their_files_and_cells(bench):
-    cells = {w["name"] for w in bench["workloads"]}
+def test_per_layer_metrics_match_their_files(bench):
     for m in bench["per_layer"]:
         spec = manifest.metric_file(m["name"])
-        for key in ("unit", "better", "source", "layer", "moves",
-                    "workloads"):
+        for key in ("unit", "better", "source", "layer", "moves"):
             assert spec[key] == m[key], (m["name"], key)
-        assert os.path.exists(os.path.join(
-            REPO, "benchmark", "readers", spec["reader"] + ".py"))
+        assert spec["reader"] in manifest.module_names("readers")
+
+
+def test_benchmark_json_alone_lists_a_metrics_cells(bench):
+    """No metric file repeats the list, so adding a cell to a metric is
+    an edit of BENCHMARK.json and of nothing else."""
+    metrics = os.path.join(REPO, "benchmark", "metrics")
+    for f in sorted(os.listdir(metrics)):
+        assert "workloads" not in manifest.load_json(metrics, f), f
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
         assert set(m["workloads"]) <= cells
         for cell in m["workloads"]:
             assert m["moves"] in manifest.metrics_for(cell, "end_to_end"), \
@@ -157,14 +164,32 @@ def test_a_cell_dropped_in_as_files_is_found(tmp_path, monkeypatch):
         ["host_ms_per_step.itl"]
     # a later PR's metric: one more file and one more entry, no code
     spec = dict(manifest.metric_file("host_ms_per_step.itl"),
-                workloads=["t_closed"], moves="serve_tokens_per_s")
+                moves="serve_tokens_per_s")
     with open(tmp_path / "benchmark" / "metrics" / "new.tps.json",
               "w") as f:
         json.dump(spec, f)
     bench["per_layer"].append(
-        {k: spec[k] for k in ("unit", "better", "source", "layer", "moves",
-                              "workloads")} | {"name": "new.tps"})
+        {k: spec[k] for k in ("unit", "better", "source", "layer", "moves")}
+        | {"name": "new.tps", "workloads": ["t_closed"]})
     with open(tmp_path / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
     assert manifest.metrics_for("t_closed", "per_layer") == \
         ["host_ms_per_step.itl", "new.tps"]
+    # a later PR's cell joins a metric that is there: BENCHMARK.json's
+    # entry gets one more name, the metric's file stays as it is
+    bench["per_layer"][0]["workloads"].append("t_train")
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    assert manifest.metrics_for("t_train", "per_layer") == \
+        ["host_ms_per_step.itl"]
+
+
+def test_a_configuration_names_its_family(bench):
+    for c in bench["configs"]:
+        cfg = manifest.load_json(REPO, c["file"])
+        assert manifest.family_of(cfg).__name__ == \
+            "benchmark.families." + cfg["family"]
+    with pytest.raises(ValueError, match="there are: llama"):
+        manifest.family_of({"family": "mamba"})
+    with pytest.raises(KeyError, match="there are: llama"):
+        manifest.family_of({"model": {}})

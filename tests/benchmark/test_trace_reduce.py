@@ -1,7 +1,7 @@
 """The reduction from a profiler trace to numbers, on a hand-made trace
 whose answers can be worked out on paper and on a small recorded one
-(``data/``, cut from a chip run of this benchmark); and the cost functions
-against hand-worked counts for the Mistral-7B shapes."""
+(``data/``, cut from a chip run of this benchmark); and the Llama
+family's work counts against hand-worked ones for the Mistral-7B shapes."""
 import json
 import os
 
@@ -9,6 +9,7 @@ import pytest
 
 import tiny_tree
 from benchmark import costs, manifest, trace_reduce as tr
+from benchmark.families import llama
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MS = 1_000_000
@@ -156,29 +157,29 @@ def test_mistral_parameter_counts(mistral):
     per_layer = 4096 * (4096 + 2 * 1024) + 4096 * 4096 + 3 * 4096 * 14336
     assert per_layer == 218_103_808
     head = 4096 * 32768
-    assert costs.matmul_params(mistral) == 32 * per_layer + head
-    assert costs.total_params(mistral) == \
+    assert llama.matmul_params(mistral) == 32 * per_layer + head
+    assert llama.total_params(mistral) == \
         32 * per_layer + 2 * head + 65 * 4096 == 7_248_023_552
 
 
 def test_mistral_kv_and_flops(mistral):
-    assert costs.kv_bytes_per_token(mistral) == 128 * 1024
+    assert llama.kv_bytes_per_token(mistral) == 128 * 1024
     assert costs.causal_pairs(4096) == 4096 * 4097 // 2
     # one decode token at context 1000: 2 FLOPs per matmul parameter and
     # 4 * 128 per head, layer and key
-    assert costs.forward_flops(mistral, 1, 1000) == \
-        2 * costs.matmul_params(mistral) + 4 * 128 * 32 * 32 * 1000
-    assert costs.kv_read_bytes(mistral, 1000) == 1000 * 131072
-    assert costs.weight_stream_bytes(mistral) == costs.matmul_params(mistral)
+    assert llama.forward_flops(mistral, 1, 1000) == \
+        2 * llama.matmul_params(mistral) + 4 * 128 * 32 * 32 * 1000
+    assert llama.kv_read_bytes(mistral, 1000) == 1000 * 131072
+    assert llama.weight_stream_bytes(mistral) == llama.matmul_params(mistral)
 
 
 def test_train_flops_of_the_two_layer_stage(mistral):
     two = dict(mistral, num_hidden_layers=2)
     params = 2 * 218_103_808 + 4096 * 32768
-    assert costs.matmul_params(two) == params == 570_425_344
-    assert costs.total_params(two) == 704_663_552
+    assert llama.matmul_params(two) == params == 570_425_344
+    assert llama.total_params(two) == 704_663_552
     pairs = 4 * (4096 * 4097 // 2)
     attn = 4 * 128 * 32 * 2 * pairs
-    assert costs.train_flops(two, 4, 4096) == \
+    assert llama.train_flops(two, 4, 4096) == \
         3 * (2 * params * 4 * 4096 + attn)
-    assert costs.flash_train_flops(two, 4, 4096) == 3 * attn
+    assert llama.flash_train_flops(two, 4, 4096) == 3 * attn

@@ -19,8 +19,9 @@ MODEL = {"hidden_act": "silu", "hidden_size": 256, "intermediate_size": 512,
          "max_position_embeddings": 2048, "sliding_window": None,
          "tie_word_embeddings": False, "torch_dtype": "bfloat16"}
 
-SERVE = {"source": "test", "kind": "serve", "model": MODEL, "reduced": [],
-         "assumed": [], "deployment": "test", "init_scale": 0.02,
+SERVE = {"source": "test", "kind": "serve", "family": "llama",
+         "model": MODEL, "reduced": [], "assumed": [],
+         "deployment": "test", "init_scale": 0.02,
          "precision": {"weights": "int8", "activations": "bfloat16",
                        "kv_pool": "bfloat16"},
          "decoder": {"weight_dtype": "int8", "block_size": 32,
@@ -31,8 +32,9 @@ SERVE = {"source": "test", "kind": "serve", "model": MODEL, "reduced": [],
                     "prompt_buckets": [512], "prefill_chunk": 32,
                     "ragged_idle_cap": 32}}
 
-TRAIN = {"source": "test", "kind": "train", "model": MODEL, "reduced": [],
-         "assumed": [], "deployment": "test", "init_scale": 0.02,
+TRAIN = {"source": "test", "kind": "train", "family": "llama",
+         "model": MODEL, "reduced": [], "assumed": [],
+         "deployment": "test", "init_scale": 0.02,
          "precision": {"weights": "bfloat16", "activations": "bfloat16"},
          "trainer": {"use_recompute": False,
                      "recompute_granularity": "full",
