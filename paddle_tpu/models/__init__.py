@@ -11,6 +11,9 @@ from .moe_lm import (  # noqa: F401
     MoEConfig, MoEForCausalLM, MoEModel, moe_tiny, deepseek_moe_16b_like,
     qwen2_moe_a14b_like,
 )
+from .keye_vl2 import (  # noqa: F401
+    KeyeVL2Config, KeyeVL2ForCausalLM, KeyeVL2Model, keye_vl2_tiny,
+)
 from .dit import (  # noqa: F401
     DiT, DiTConfig, dit_tiny, dit_s_2, dit_xl_2,
 )

@@ -12,7 +12,8 @@ from .layer.norm import *  # noqa: F401,F403
 from .layer.pooling import *  # noqa: F401,F403
 from .layer.loss import *  # noqa: F401,F403
 from .layer.transformer import *  # noqa: F401,F403
-from .layer.moe import MoELayer, SwitchGate, GShardGate  # noqa: F401
+from .layer.moe import (MoELayer, MoEShareLayer, SwitchGate,  # noqa: F401
+                        GShardGate)
 from .layer.rnn import *  # noqa: F401,F403
 from .layer.extras import *  # noqa: F401,F403
 from .decode import BeamSearchDecoder, dynamic_decode  # noqa: F401

@@ -16,7 +16,7 @@ from .. import functional as F
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["MoELayer", "SwitchGate", "GShardGate"]
+__all__ = ["MoELayer", "MoEShareLayer", "SwitchGate", "GShardGate"]
 
 
 class _GateBase:
@@ -33,11 +33,14 @@ class SwitchGate(_GateBase):
 
 
 class MoELayer(Layer):
-    """Token-routed expert FFN block.
+    """Token-routed expert FFN block that holds every expert.
 
-    Args mirror the reference MoELayer where sensible; experts are the
-    standard gated FFN (w1/w2), stored stacked [E, ...] so the expert dim
-    can shard over the mesh.
+    Args mirror the reference MoELayer where sensible. Each expert is the
+    plain two-matrix FFN ``w2(act(w1 x))`` (no gate matrix; ``activation``
+    is gelu, relu or silu), stored stacked [E, ...] so the expert dim can
+    shard over the mesh. The gated three-matrix expert
+    ``w_down(silu(w_gate x) * w_up x)``, and a layer that holds only its
+    share of the experts, is ``MoEShareLayer``.
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
@@ -115,7 +118,9 @@ class MoELayer(Layer):
             raise NotImplementedError(
                 "dispatch_mode='ragged' cannot shard over an expert-"
                 "parallel mesh axis (segment sizes are data-dependent); "
-                "use dispatch_mode='dense' under EP")
+                "use dispatch_mode='dense' under EP, or give each rank a "
+                "MoEShareLayer(share=(rank, ranks)), which is dropless "
+                "over the experts it is told it holds")
 
         def f(xa, gw, w1, w2):
             if ragged:
@@ -161,3 +166,92 @@ class MoELayer(Layer):
         None when the last forward ran inside a compiled program (run
         one eager forward to sample routing)."""
         return getattr(self, "_last_stats", None)
+
+
+class MoEShareLayer(Layer):
+    """One share of a gated-expert layer: it is told which experts it
+    holds, routes over all of them and computes its own experts' part.
+
+    ``share=(index, count)`` divides the ``num_experts`` experts evenly
+    over ``count`` holders; this layer has the parameters of experts
+    ``index * num_experts // count`` onward, ``num_experts // count`` of
+    them, each ``w_down(silu(w_gate x) * w_up x)``. The router
+    (``gate_weight`` [d_model, num_experts], softmax in float32, the
+    ``top_k`` largest, divided by their sum when ``norm_topk_prob``) is
+    whole on every share. ``forward`` returns the sum over a token's
+    chosen experts that are held here; what the other shares hold is
+    theirs to add, which under expert parallelism is the exchange and on
+    a single share is left out. ``share=(0, 1)`` is the whole layer. No
+    token is dropped (``ops.moe.moe_share_forward``).
+
+    ``rows`` is a buffer of ``num_held + 1`` counters that every forward
+    adds to: the rows each held expert computed, then all the (token,
+    choice) rows routed anywhere. A counter is two int32 words, the low
+    30 bits and the carries out of them (at 2 x 8192 tokens and top-8 one
+    word would wrap after 16,384 steps); a call adds fewer than 2**30
+    rows. ``jit.TrainStep`` threads the buffer through the compiled step
+    like any other; ``routing_counts()`` reads it.
+    """
+    _LOW_BITS = 30
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 top_k: int, share=(0, 1), norm_topk_prob: bool = True,
+                 dtype=None):
+        super().__init__(dtype=dtype)
+        index, count = share
+        if num_experts % count or not 0 <= index < count:
+            raise ValueError(f"share {share!r} does not divide "
+                             f"{num_experts} experts evenly")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.num_held = num_experts // count
+        self.first_expert = index * self.num_held
+        self.norm_topk_prob = norm_topk_prob
+        self.gate_weight = self.create_parameter((d_model, num_experts))
+        self.w_gate = self.create_parameter(
+            (self.num_held, d_model, d_hidden))
+        self.w_up = self.create_parameter((self.num_held, d_model, d_hidden))
+        self.w_down = self.create_parameter(
+            (self.num_held, d_hidden, d_model))
+        self.register_buffer(
+            "rows", Tensor(jnp.zeros((2, self.num_held + 1), jnp.int32)))
+
+    def compute(self, x):
+        """(out, the counters this call adds) with the buffer untouched:
+        for a caller that runs the layer inside a rematerialised region
+        and counts outside it (``count``)."""
+        from ...ops.moe import moe_share_forward
+        from ...utils import telemetry
+        telemetry.default_tracer().metrics.inc("moe.dispatch.share_ragged")
+        routed = x.shape[0] * x.shape[1] * self.top_k
+
+        def f(xa, gw, wg, wu, wd):
+            out, rows = moe_share_forward(
+                xa, gw, wg, wu, wd, self.top_k, self.first_expert,
+                self.norm_topk_prob)
+            return out, jnp.concatenate(
+                [rows, jnp.full((1,), routed, jnp.int32)])
+
+        return apply("moe_share", f, x, self.gate_weight, self.w_gate,
+                     self.w_up, self.w_down)
+
+    def count(self, seen):
+        low, high = self.rows._value
+        low = low + seen._value
+        self.rows._replace(jnp.stack(
+            [low & ((1 << self._LOW_BITS) - 1),
+             high + (low >> self._LOW_BITS)]))
+
+    def forward(self, x):
+        out, seen = self.compute(x)
+        self.count(seen)
+        return out
+
+    def routing_counts(self) -> dict:
+        """{"rows_held", "rows_max_expert", "rows_routed"} since the layer
+        was built (a read of the buffer: it waits for the device)."""
+        import numpy as np
+        low, high = np.asarray(self.rows._value).tolist()
+        rows = [lo + (hi << self._LOW_BITS) for lo, hi in zip(low, high)]
+        return {"rows_held": sum(rows[:-1]),
+                "rows_max_expert": max(rows[:-1]),
+                "rows_routed": rows[-1]}

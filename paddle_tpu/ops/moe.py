@@ -13,11 +13,14 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["topk_gating", "moe_dispatch_combine", "moe_mlp_forward",
-           "moe_ragged_forward"]
+           "moe_ragged_forward", "moe_share_forward"]
 
 
 def topk_gating(logits, top_k: int, capacity: int):
@@ -186,6 +189,147 @@ def moe_ragged_forward(x, gate_w, w1, w2, top_k: int,
              "assigned_per_expert": group_sizes.astype(jnp.float32),
              "dropped_fraction": jnp.float32(0.0)}
     return out.reshape(b, s, d).astype(x.dtype), aux_loss, stats
+
+
+def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
+                      first_expert: int = 0, norm_topk_prob: bool = True):
+    """One share's part of a gated-expert layer: x [B, S, D] ->
+    (out [B, S, D], rows [E_held] int32).
+
+    ``gate_w`` [D, E] routes over ALL E experts (softmax in float32, the
+    ``top_k`` largest, their weights divided by their sum when
+    ``norm_topk_prob``); ``w_gate`` / ``w_up`` [E_held, D, H] and
+    ``w_down`` [E_held, H, D] are the experts ``first_expert ..
+    first_expert + E_held - 1`` that live here, each computing
+    ``w_down(silu(w_gate x) * w_up x)``. ``out`` is the sum over a
+    token's chosen experts THAT ARE HELD of weight * expert(x): what the
+    other shares hold is theirs to add (expert parallelism's exchange,
+    or nothing on a single share). ``rows`` counts the rows each held
+    expert computed.
+
+    Dropless at static shapes: the token-to-expert assignments are
+    sorted with the held experts first, and the sorted rows are walked
+    in chunks of twice an even routing's share (rounded up to 16 rows: at
+    1.25 times the share a layer's held rows crossed the chunk's end from
+    step to step and a step's time with them). A chunk that holds no held
+    row is skipped by ``lax.cond``, so the work follows the rows that are
+    there while every row up to the worst case (all T * top_k) has its
+    chunk. Each chunk is three ``lax.ragged_dot`` over its rows, made
+    again in the backward pass (``_share_experts``): nothing of size
+    rows x width is kept.
+    """
+    b, s, d = x.shape
+    tokens = x.reshape(b * s, d)
+    t = tokens.shape[0]
+    e, n_held = gate_w.shape[1], w_gate.shape[0]
+    n_rows = t * top_k
+
+    logits = tokens.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
+    top_p, top_i = jax.lax.top_k(probs, top_k)                 # [T, k]
+    gates = top_p / jnp.sum(top_p, -1, keepdims=True) \
+        if norm_topk_prob else top_p
+
+    local = top_i.reshape(n_rows) - first_expert
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    # slot i of the flat layout is token i // k, choice i % k
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+
+    even = n_rows * n_held / e
+    row_chunk = min(n_rows, -(-int(2 * even) // 16) * 16)
+    n_chunks = -(-n_rows // row_chunk)
+    # rows past the last assignment never lie under a held expert
+    order = jnp.pad(order, (0, n_chunks * row_chunk - n_rows),
+                    constant_values=n_rows - 1)
+    out = _share_experts(tokens, gates.reshape(n_rows), w_gate, w_up,
+                         w_down, order, sizes, top_k, row_chunk)
+    return out.reshape(b, s, d).astype(x.dtype), sizes
+
+
+def _share_chunk(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
+                 lo, top_k, row_chunk):
+    """What the sorted rows lo .. lo + row_chunk add to every token's
+    output, [T, D]."""
+    t, d = tokens.shape
+    idx = jax.lax.dynamic_slice(order, (lo,), (row_chunk,))
+    tok = idx // top_k
+    ends = jnp.cumsum(sizes)
+    gs = jnp.clip(ends, lo, lo + row_chunk) \
+        - jnp.clip(ends - sizes, lo, lo + row_chunk)
+    cdt = tokens.dtype
+    # a row past the chunk's held rows lies under no expert: what
+    # ragged_dot leaves there is not defined on every backend (the TPU's
+    # leaves what the buffer held), so such rows are cut out of the
+    # gathered operand and of every product, forward and (where's vjp)
+    # backward
+    held = (jnp.arange(row_chunk) < ends[-1] - lo)[:, None]
+    rows = lambda a: jnp.where(held, a, jnp.zeros((), a.dtype))
+    dot = lambda a, w: rows(jax.lax.ragged_dot(a, w.astype(cdt), gs))
+    xs = rows(jnp.take(tokens, tok, axis=0))
+    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    ys = dot(h, w_down)
+    wts = jnp.take(flat_gates, idx).astype(ys.dtype)
+    return jnp.zeros((t, d), ys.dtype).at[tok].add(ys * wts[:, None])
+
+
+def _chunks(order, sizes, row_chunk):
+    """(chunk starts, rows under a held expert)."""
+    n = order.shape[0] // row_chunk
+    return jnp.arange(n, dtype=jnp.int32) * row_chunk, jnp.sum(sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
+                   top_k, row_chunk):
+    """The held experts' weighted outputs summed per token, a chunk of
+    sorted rows at a time; a chunk past the held rows is skipped. Its own
+    vjp walks the chunks again, each differentiated where it stands, so
+    no chunk's operands are kept for the backward pass."""
+    starts, held_rows = _chunks(order, sizes, row_chunk)
+
+    def step(out, lo):
+        return jax.lax.cond(
+            lo < held_rows,
+            lambda o: o + _share_chunk(tokens, flat_gates, w_gate, w_up,
+                                       w_down, order, sizes, lo, top_k,
+                                       row_chunk),
+            lambda o: o, out), None
+
+    out, _ = jax.lax.scan(step, jnp.zeros(tokens.shape, tokens.dtype),
+                          starts)
+    return out
+
+
+def _share_experts_fwd(tokens, flat_gates, w_gate, w_up, w_down, order,
+                       sizes, top_k, row_chunk):
+    out = _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order,
+                         sizes, top_k, row_chunk)
+    return out, (tokens, flat_gates, w_gate, w_up, w_down, order, sizes)
+
+
+def _share_experts_bwd(top_k, row_chunk, res, d_out):
+    tokens, flat_gates, w_gate, w_up, w_down, order, sizes = res
+    starts, held_rows = _chunks(order, sizes, row_chunk)
+    diff = (tokens, flat_gates, w_gate, w_up, w_down)
+
+    def step(acc, lo):
+        def run(acc):
+            _, vjp = jax.vjp(
+                lambda *a: _share_chunk(*a, order, sizes, lo, top_k,
+                                        row_chunk), *diff)
+            return tuple(a + g.astype(a.dtype)
+                         for a, g in zip(acc, vjp(d_out)))
+        return jax.lax.cond(lo < held_rows, run, lambda a: a, acc), None
+
+    acc0 = tuple(jnp.zeros(a.shape, jnp.float32) for a in diff)
+    acc, _ = jax.lax.scan(step, acc0, starts)
+    zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (*(g.astype(a.dtype) for g, a in zip(acc, diff)),
+            zero(order), zero(sizes))
+
+
+_share_experts.defvjp(_share_experts_fwd, _share_experts_bwd)
 
 
 moe_mlp_forward = moe_dispatch_combine

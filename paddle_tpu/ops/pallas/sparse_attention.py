@@ -1,0 +1,665 @@
+"""Learned sparse attention (the DeepSeek-Sparse-Attention scheme), Pallas
+TPU kernels and the differentiable entry point over them.
+
+A light *indexer* scores every causal (query, key) pair,
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        (s <= t)
+
+each query keeps the ``topk`` keys with the largest score (exact; ties go
+to the lower index), the main attention runs over the kept keys alone,
+and the indexer is trained by its own loss: the KL divergence from the
+main attention's probabilities over the kept keys (summed over the heads
+and normalised) to the softmax of the indexer's scores over the same
+keys. The selection is carried as a dense int8 mask [batch, seq, seq], so
+the attention kernels are flash kernels with one more operand: what they
+compute is exact, what they cost is the causal triangle.
+
+Kernels (each ``pallas_call`` is named; a device trace shows the name):
+
+``indexer_scores``      I as float32, bf16 operands, float32 accumulation
+``topk_select``         I -> mask: a radix select on the scores' bits, 32
+                        counting passes for the ``topk``-th largest value
+                        of a row and ``log2(seq)`` more for the ties
+``sparse_attn_fwd``     online-softmax attention under the mask
+``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv``
+``indexer_loss_rows``   the heads' summed probabilities under the mask (the
+                        indexer's target) folded with its scores into the
+                        loss's sums per row; ``indexer_loss_grad``: the same
+                        pass ending in the loss's gradient w.r.t. the scores
+
+``learned_sparse_attention`` ties them together under one ``custom_vjp``:
+it keeps q, k, v, the output, the row statistics, the mask and the
+indexer's operands, and makes the scores and the target again in the
+backward pass, so that no float32 [seq, seq] array outlives its pass
+(the target never leaves VMEM).
+The indexer's operands get their gradient from the indexer's loss alone
+and q, k, v from the output alone. Every piece has a plain ``jax.numpy``
+twin below (``*_reference``) that the tests hold it to.
+
+Layouts: q [b, h, s, d]; k, v [b, hk, s, d]; qI [b, hi, s, di];
+kI [b, s, di]; w [b, s, hi] float32 with the score's scales folded in.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret as _interpret
+
+_NEG = -1e30
+_INT_MIN = -2 ** 31
+F32 = jnp.float32
+
+
+def _block(seq: int, want: int) -> int:
+    b = min(want, seq)
+    if seq % b:
+        raise ValueError(f"sequence length {seq} is not a multiple of the "
+                         f"kernel's block {b}")
+    return b
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+# ---------------------------------------------------------------------------
+# indexer scores
+# ---------------------------------------------------------------------------
+
+def _scores_kernel(q_ref, k_ref, w_ref, o_ref, *, heads, bq, bk):
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    live = kj * bk <= qi * bq + bq - 1      # the tile holds a causal pair
+
+    @pl.when(live)
+    def _():
+        k = k_ref[0]                                    # [bk, di]
+        w = w_ref[0]                                    # [bq, hi] f32
+        acc = jnp.zeros((bq, bk), F32)
+        for j in range(heads):
+            s = jax.lax.dot_general(q_ref[0, j], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=F32)
+            acc = acc + w[:, j:j + 1] * jnp.maximum(s, 0.0)
+        o_ref[0] = acc
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[0] = jnp.zeros((bq, bk), F32)
+
+
+def indexer_scores(qi, ki, w, block_q=256, block_k=512):
+    """[b, s, s] float32 scores; tiles above the diagonal are zeros and
+    mean nothing (the selection applies the causal bound)."""
+    b, hi, s, di = qi.shape
+    bq, bk = _block(s, block_q), _block(s, block_k)
+
+    def last(q_i):
+        return (q_i * bq + bq - 1) // bk
+
+    return pl.pallas_call(
+        functools.partial(_scores_kernel, heads=hi, bq=bq, bk=bk),
+        grid=(b, s // bq, s // bk),
+        in_specs=[
+            pl.BlockSpec((1, hi, bq, di), lambda bi, q_i, kj: (bi, 0, q_i, 0)),
+            pl.BlockSpec((1, bk, di), lambda bi, q_i, kj:
+                         (bi, jnp.minimum(kj, last(q_i)), 0)),
+            pl.BlockSpec((1, bq, hi), lambda bi, q_i, kj: (bi, q_i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda bi, q_i, kj: (bi, q_i, kj)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), F32),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=_interpret(),
+        name="indexer_scores",
+    )(qi, ki, w)
+
+
+def indexer_scores_reference(qi, ki, w):
+    s = jnp.einsum("bjqd,bsd->bjqs", qi.astype(F32), ki.astype(F32),
+                   precision="highest")
+    return jnp.einsum("bjqs,bqj->bqs", jnp.maximum(s, 0.0), w.astype(F32),
+                      precision="highest")
+
+
+def _indexer_scores_bwd(qi, ki, w, d_scores, chunk=512):
+    """Gradients of the scores w.r.t. the indexer's operands from the
+    cotangent [b, s, s] (zero outside the selection), one block of
+    queries against its causal keys at a time. Left to XLA."""
+    b, hi, s, di = qi.shape
+    c = min(chunk, s)
+    dq, dw = [], []
+    dk = jnp.zeros((b, s, di), F32)
+    for q0 in range(0, s, c):
+        e = q0 + c                                      # causal extent
+        qc, kc, wc = qi[:, :, q0:e], ki[:, :e], w[:, q0:e]
+        sc = jnp.einsum("bjqd,bsd->bjqs", qc, kc,
+                        preferred_element_type=F32)
+        dc = d_scores[:, q0:e, :e]
+        dw.append(jnp.einsum("bjqs,bqs->bqj", jnp.maximum(sc, 0.0), dc))
+        gs = (jnp.where(sc > 0, dc[:, None], 0.0)
+              * jnp.swapaxes(wc, 1, 2)[..., None]).astype(qi.dtype)
+        dq.append(jnp.einsum("bjqs,bsd->bjqd", gs, kc,
+                             preferred_element_type=F32))
+        dk = dk.at[:, :e].add(jnp.einsum("bjqs,bjqd->bsd", gs, qc,
+                                         preferred_element_type=F32))
+    return (jnp.concatenate(dq, 2).astype(qi.dtype), dk.astype(ki.dtype),
+            jnp.concatenate(dw, 1).astype(w.dtype))
+
+
+# ---------------------------------------------------------------------------
+# selection
+# ---------------------------------------------------------------------------
+
+def _select_kernel(i_ref, m_ref, *, topk, rows, seq):
+    r0 = pl.program_id(1) * rows
+    x = i_ref[0]
+    x = jnp.where(x == 0.0, 0.0, x)          # -0.0 and 0.0 are one score
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    # a key whose signed order is the floats' order
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    row = r0 + jax.lax.broadcasted_iota(jnp.int32, (rows, seq), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, seq), 1)
+    causal = col <= row
+    key = jnp.where(causal, key, jnp.int32(_INT_MIN))
+
+    def count(pred):
+        return jnp.sum(pred.astype(F32), axis=1, keepdims=True)
+
+    # the largest thr with at least topk keys >= thr, bit by bit from the
+    # top; the first step's INT_MIN + INT_MIN wraps to 0, the middle
+    def value_bit(i, thr):
+        cand = thr + (jnp.int32(1) << (31 - i))
+        return jnp.where(count(key >= cand) >= topk, cand, thr)
+    thr = jax.lax.fori_loop(0, 32, value_bit,
+                            jnp.full((rows, 1), _INT_MIN, jnp.int32))
+    above, tie = key > thr, key == thr
+    need = topk - count(above)               # ties to take, lowest first
+    nbits = max(1, (seq - 1).bit_length())
+
+    # the largest m with fewer than `need` ties before column m: that
+    # column holds the last tie taken
+    def index_bit(i, m):
+        cand = m + (jnp.int32(1) << (nbits - 1 - i))
+        return jnp.where(count(tie & (col < cand)) < need, cand, m)
+    m = jax.lax.fori_loop(0, nbits, index_bit,
+                          jnp.zeros((rows, 1), jnp.int32))
+    keep = causal & (above | (tie & (col <= m)))
+    m_ref[0] = keep.astype(jnp.int8)
+
+
+def topk_select(scores, topk, block_rows=32):
+    """int8 mask [b, s, s]: 1 where key s is among the min(t + 1, topk)
+    best-scored keys s <= t of query t, ties to the lower index."""
+    b, s, _ = scores.shape
+    rows = _block(s, block_rows)
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=int(topk), rows=rows, seq=s),
+        grid=(b, s // rows),
+        in_specs=[pl.BlockSpec((1, rows, s), lambda bi, r: (bi, r, 0))],
+        out_specs=pl.BlockSpec((1, rows, s), lambda bi, r: (bi, r, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.int8),
+        compiler_params=_params("parallel", "parallel"),
+        interpret=_interpret(),
+        name="topk_select",
+    )(scores)
+
+
+def topk_select_reference(scores, topk):
+    """The same set through ``lax.top_k`` (which puts the lower index
+    first among equal values)."""
+    b, s, _ = scores.shape
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    x = jnp.where(causal, jnp.where(scores == 0.0, 0.0, scores), -jnp.inf)
+    _, idx = jax.lax.top_k(x, min(int(topk), s))
+    hit = jnp.zeros((b, s, s), bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        idx].set(True)
+    return (hit & causal).astype(jnp.int8)
+
+
+# ---------------------------------------------------------------------------
+# attention under the mask
+# ---------------------------------------------------------------------------
+
+def _probs(q, k, keep, scale, row_stat):
+    """(masked scores' probabilities given the rows' statistic) of one
+    tile: exp(q k^T * scale - row_stat) where kept, else 0."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * scale
+    return jnp.where(keep, jnp.exp(s - row_stat), 0.0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                m_sc, l_sc, acc_sc, *, scale, bq, bk, nk):
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, F32)
+        l_sc[...] = jnp.zeros(l_sc.shape, F32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
+
+    @pl.when(kj * bk <= qi * bq + bq - 1)
+    def _():
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32) * scale
+        s = jnp.where(keep, s, _NEG)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        m_sc[...] = m_new
+
+    @pl.when(kj == nk - 1)
+    def _():
+        l = jnp.maximum(l_sc[...], 1e-30)
+        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_sc[...] + jnp.log(l)
+
+
+def _tiles(s, block_q, block_k):
+    bq, bk = _block(s, block_q), _block(s, block_k)
+
+    def last_k(q_i):                     # last key tile a query tile needs
+        return (q_i * bq + bq - 1) // bk
+
+    def first_q(kj):                     # first query tile a key tile meets
+        return (kj * bk) // bq
+    return bq, bk, last_k, first_q
+
+
+def sparse_attn_fwd(q, k, v, mask, scale, block_q=512, block_k=512):
+    """(out [b, h, s, d], lse [b, h, s, 1] float32)."""
+    b, h, s, d = q.shape
+    g = h // k.shape[1]
+    bq, bk, last_k, _ = _tiles(s, block_q, block_k)
+    nk = s // bk
+
+    def kv_map(bi, hi, q_i, kj):
+        return (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)
+
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
+        grid=(b, h, s // bq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, q_i, kj:
+                         (bi, hi, q_i, 0)),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, bq, bk), lambda bi, hi, q_i, kj:
+                         (bi, q_i, jnp.minimum(kj, last_k(q_i)))),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, q_i, kj:
+                         (bi, hi, q_i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, q_i, kj:
+                         (bi, hi, q_i, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, 1), F32)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), F32), pltpu.VMEM((bq, 1), F32),
+                        pltpu.VMEM((bq, d), F32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        interpret=_interpret(),
+        name="sparse_attn_fwd",
+    )(q, k, v, mask)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   mask_ref, dq_ref, acc_sc, *, scale, bq, bk, nk):
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _():
+        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
+
+    @pl.when(kj * bk <= qi * bq + bq - 1)
+    def _():
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        k = k_ref[0, 0]
+        p = _probs(q_ref[0, 0], k, keep, scale, lse_ref[0, 0])
+        dp = jax.lax.dot_general(do_ref[0, 0], v_ref[0, 0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=F32)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        acc_sc[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+
+    @pl.when(kj == nk - 1)
+    def _():
+        dq_ref[0, 0] = acc_sc[...].astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    mask_ref, dk_ref, dv_ref, dk_sc, dv_sc,
+                    *, scale, bq, bk, nq, group):
+    kj, gi, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+
+    @pl.when((gi == 0) & (qi == 0))
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, F32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, F32)
+
+    @pl.when(kj * bk <= qi * bq + bq - 1)
+    def _():
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        p = _probs(q, k_ref[0, 0], keep, scale, lse_ref[0, 0])
+        dv_sc[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        dp = jax.lax.dot_general(do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=F32)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        dk_sc[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=F32)
+
+    @pl.when((gi == group - 1) & (qi == nq - 1))
+    def _():
+        dk_ref[0, 0] = dk_sc[...]
+        dv_ref[0, 0] = dv_sc[...]
+
+
+def sparse_attn_bwd(q, k, v, out, lse, do, mask, scale,
+                    block_q=512, block_k=512):
+    """(dq in q's dtype, dk, dv float32 summed over each key head's
+    query heads)."""
+    b, h, s, d = q.shape
+    hk = k.shape[1]
+    g = h // hk
+    bq, bk, last_k, first_q = _tiles(s, block_q, block_k)
+    nq, nk = s // bq, s // bk
+    delta = jnp.sum(out.astype(F32) * do.astype(F32), -1, keepdims=True)
+
+    def kv_map(bi, hi, q_i, kj):
+        return (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)
+
+    def row_map(bi, hi, q_i, kj):
+        return (bi, hi, q_i, 0)
+
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
+        grid=(b, h, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), row_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bq, d), row_map),
+            pl.BlockSpec((1, 1, bq, 1), row_map),
+            pl.BlockSpec((1, 1, bq, 1), row_map),
+            pl.BlockSpec((1, bq, bk), lambda bi, hi, q_i, kj:
+                         (bi, q_i, jnp.minimum(kj, last_k(q_i)))),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bq, d), row_map),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), F32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        interpret=_interpret(),
+        name="sparse_attn_bwd_dq",
+    )(q, k, v, do, lse, delta, mask)
+
+    def q_map(bi, hki, kj, gi, q_i):
+        return (bi, hki * g + gi, jnp.maximum(q_i, first_q(kj)), 0)
+
+    def k_map(bi, hki, kj, gi, q_i):
+        return (bi, hki, kj, 0)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
+                          nq=nq, group=g),
+        grid=(b, hk, nk, g, nq),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bk, d), k_map),
+            pl.BlockSpec((1, 1, bk, d), k_map),
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
+            pl.BlockSpec((1, bq, bk), lambda bi, hki, kj, gi, q_i:
+                         (bi, jnp.maximum(q_i, first_q(kj)), kj)),
+        ],
+        out_specs=[pl.BlockSpec((1, 1, bk, d), k_map),
+                   pl.BlockSpec((1, 1, bk, d), k_map)],
+        out_shape=[jax.ShapeDtypeStruct((b, hk, s, d), F32),
+                   jax.ShapeDtypeStruct((b, hk, s, d), F32)],
+        scratch_shapes=[pltpu.VMEM((bk, d), F32), pltpu.VMEM((bk, d), F32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary", "arbitrary"),
+        interpret=_interpret(),
+        name="sparse_attn_bwd_dkv",
+    )(q, k, v, do, lse, delta, mask)
+    return dq, dk, dv
+
+
+def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, sc_ref, *rest,
+                 scale, bq, bk, heads, grad):
+    """One (query tile, key tile) of the indexer's loss, the heads in the
+    innermost grid axis. The heads' mean probability under the mask (the
+    target, each head's row summing to 1) gathers in VMEM; after the last
+    head the tile is folded, together with the indexer's scores, either
+    into four sums per row (``grad`` false: what the loss needs) or into
+    the loss's gradient with respect to the scores (``grad`` true)."""
+    if grad:
+        z_ref, lsei_ref, out_ref, tgt = rest
+    else:
+        a_ref, z_ref, m_ref, l_ref, tgt = rest
+    qi, kj, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    live = kj * bk <= qi * bq + bq - 1
+
+    if not grad:
+        @pl.when((kj == 0) & (hi == 0))
+        def _():
+            a_ref[0] = jnp.zeros((bq, 1), F32)
+            z_ref[0] = jnp.zeros((bq, 1), F32)
+            l_ref[0] = jnp.zeros((bq, 1), F32)
+            m_ref[0] = jnp.full((bq, 1), _NEG, F32)
+
+    @pl.when(live & (hi == 0))
+    def _():
+        tgt[...] = jnp.zeros((bq, bk), F32)
+
+    @pl.when(live)
+    def _():
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        tgt[...] += _probs(q_ref[0, 0], k_ref[0, 0], keep, scale,
+                           lse_ref[0, 0]) * (1.0 / heads)
+
+    @pl.when(live & (hi == heads - 1))
+    def _():
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        t, sc = tgt[...], sc_ref[0]
+        if grad:
+            soft = jnp.where(keep, jnp.exp(sc - lsei_ref[0]), 0.0)
+            out_ref[0] = soft - t / z_ref[0]
+        else:
+            pos = keep & (t > 0.0)
+            a_ref[0] += jnp.sum(jnp.where(
+                pos, t * (jnp.log(jnp.where(pos, t, 1.0)) - sc), 0.0),
+                axis=1, keepdims=True)
+            z_ref[0] += jnp.sum(t, axis=1, keepdims=True)
+            scm = jnp.where(keep, sc, _NEG)
+            m_prev = m_ref[0]
+            m_new = jnp.maximum(m_prev, jnp.max(scm, axis=1, keepdims=True))
+            l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) + jnp.sum(
+                jnp.where(keep, jnp.exp(scm - m_new), 0.0), axis=1,
+                keepdims=True)
+            m_ref[0] = m_new
+
+    if grad:
+        @pl.when(jnp.logical_not(live) & (hi == heads - 1))
+        def _():
+            out_ref[0] = jnp.zeros((bq, bk), F32)
+
+
+def _loss_call(q, k, lse, mask, scores, rows, scale, block_q, block_k):
+    """The loss kernel's two forms: ``rows`` None gives the four sums per
+    row (a, z, m, l), each [b, s, 1]; ``rows`` = (z, lse_i) gives the
+    gradient [b, s, s]."""
+    b, h, s, d = q.shape
+    g = h // k.shape[1]
+    bq, bk, last_k, _ = _tiles(s, block_q, block_k)
+    grad = rows is not None
+
+    def head_map(bi, q_i, kj, hi):
+        return (bi, hi, q_i, 0)
+
+    def tile_map(bi, q_i, kj, hi):
+        return (bi, q_i, jnp.minimum(kj, last_k(q_i)))
+
+    row_spec = pl.BlockSpec((1, bq, 1), lambda bi, q_i, kj, hi: (bi, q_i, 0))
+    row_shape = jax.ShapeDtypeStruct((b, s, 1), F32)
+    in_specs = [
+        pl.BlockSpec((1, 1, bq, d), head_map),
+        pl.BlockSpec((1, 1, bk, d), lambda bi, q_i, kj, hi:
+                     (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)),
+        pl.BlockSpec((1, 1, bq, 1), head_map),
+        pl.BlockSpec((1, bq, bk), tile_map),
+        pl.BlockSpec((1, bq, bk), tile_map),
+    ]
+    if grad:
+        in_specs += [row_spec, row_spec]
+        out_specs = pl.BlockSpec((1, bq, bk),
+                                 lambda bi, q_i, kj, hi: (bi, q_i, kj))
+        out_shape = jax.ShapeDtypeStruct((b, s, s), F32)
+    else:
+        out_specs, out_shape = [row_spec] * 4, [row_shape] * 4
+    return pl.pallas_call(
+        functools.partial(_loss_kernel, scale=scale, bq=bq, bk=bk, heads=h,
+                          grad=grad),
+        grid=(b, s // bq, s // bk, h),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bq, bk), F32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
+        interpret=_interpret(),
+        name="indexer_loss_grad" if grad else "indexer_loss_rows",
+    )(q, k, lse, mask, scores, *(rows or ()))
+
+
+def indexer_loss(q, k, lse, mask, scores, scale, block_q=512, block_k=512):
+    """(the indexer's loss, (z, lse_i) per row for the backward pass):
+    the mean over the queries of KL(target || softmax of the scores over
+    the kept keys), the target being the heads' summed probabilities of
+    the main attention over the kept keys, normalised. With the target's
+    row sum z and the scores' row statistic lse_i, a row's term is
+    sum(t (log t - score)) / z - log z + lse_i."""
+    a, z, m, l = _loss_call(q, k, lse, mask, scores, None, scale,
+                            block_q, block_k)
+    lse_i = m + jnp.log(l)
+    return jnp.mean(a / z - jnp.log(z) + lse_i), (z, lse_i)
+
+
+def indexer_loss_grad(q, k, lse, mask, scores, rows, scale,
+                      block_q=512, block_k=512):
+    """The loss's gradient with respect to the scores, times the number
+    of queries: softmax(scores) - target / z over the kept keys."""
+    return _loss_call(q, k, lse, mask, scores, rows, scale, block_q,
+                      block_k)
+
+
+def sparse_attention_reference(q, k, v, mask, scale):
+    """(out, probabilities [b, h, s, s]) in float32 ``jax.numpy``."""
+    g = q.shape[1] // k.shape[1]
+    kf = jnp.repeat(k.astype(F32), g, axis=1)
+    vf = jnp.repeat(v.astype(F32), g, axis=1)
+    s = jnp.einsum("bhqd,bhsd->bhqs", q.astype(F32), kf,
+                   precision="highest") * scale
+    keep = (mask > 0)[:, None]
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    p = jnp.where(keep, p, 0.0)
+    return jnp.einsum("bhqs,bhsd->bhqd", p, vf, precision="highest"), p
+
+
+# ---------------------------------------------------------------------------
+# the indexer's loss and the differentiable whole
+# ---------------------------------------------------------------------------
+
+def _indexer_kl(scores, target, mask):
+    """The indexer's loss from whole arrays (``indexer_loss`` is the
+    kernels' form): the mean over the queries of KL(target, normalised ||
+    softmax of the scores over the kept keys)."""
+    keep = mask > 0
+    target = target / jnp.sum(target, -1, keepdims=True)
+    logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
+    live = keep & (target > 0)
+    kl = jnp.where(live, target * (jnp.log(jnp.where(live, target, 1.0))
+                                   - jnp.where(live, logq, 0.0)), 0.0)
+    return jnp.mean(jnp.sum(kl, -1))
+
+
+def _forward(q, k, v, qi, ki, w, topk, scale):
+    with jax.named_scope("indexer"):
+        scores = indexer_scores(qi, ki, w)
+    with jax.named_scope("select"):
+        mask = topk_select(scores, topk)
+    with jax.named_scope("sparse_attn"):
+        out, lse = sparse_attn_fwd(q, k, v, mask, scale)
+    with jax.named_scope("indexer"):
+        loss, rows = indexer_loss(q, k, lse, mask, scores, scale)
+    return out, loss, lse, mask, rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def learned_sparse_attention(q, k, v, qi, ki, w, topk, scale):
+    """(out [b, h, s, d], the indexer's loss) with the Pallas kernels."""
+    out, loss, _, _, _ = _forward(q, k, v, qi, ki, w, topk, scale)
+    return out, loss
+
+
+def _lsa_fwd(q, k, v, qi, ki, w, topk, scale):
+    out, loss, lse, mask, (z, lse_i) = _forward(q, k, v, qi, ki, w, topk,
+                                                scale)
+    # the kernels' [.., s, 1] statistics pad their last dimension to a
+    # lane tile in HBM: keep [.., s]
+    return (out, loss), (q, k, v, qi, ki, w, out, lse[..., 0], mask,
+                         z[..., 0], lse_i[..., 0])
+
+
+def _lsa_bwd(topk, scale, res, cts):
+    d_out, d_loss = cts
+    # the scores are made again from the same operands as in the forward
+    # pass: behind a barrier, or XLA merges the two calls and keeps the
+    # forward's float32 [seq, seq] array alive
+    q, k, v, qi, ki, w, out, lse, mask, z, lse_i, d_out = \
+        jax.lax.optimization_barrier((*res, d_out))
+    lse = lse[..., None]
+    with jax.named_scope("sparse_attn"):
+        dq, dk, dv = sparse_attn_bwd(q, k, v, out, lse,
+                                     d_out.astype(q.dtype), mask, scale)
+    with jax.named_scope("indexer"):
+        scores = indexer_scores(qi, ki, w)
+        d_scores = indexer_loss_grad(q, k, lse, mask, scores,
+                                     (z[..., None], lse_i[..., None]), scale)
+        coef = d_loss / (scores.shape[0] * scores.shape[1])
+        dqi, dki, dw = (g * coef.astype(g.dtype) for g in
+                        _indexer_scores_bwd(qi, ki, w, d_scores))
+    return (dq, dk.astype(k.dtype), dv.astype(v.dtype), dqi, dki, dw)
+
+
+learned_sparse_attention.defvjp(_lsa_fwd, _lsa_bwd)
+
+
+def learned_sparse_attention_reference(q, k, v, qi, ki, w, topk, scale):
+    """The whole function's twin for the tests, in plain ``jax.numpy`` and
+    differentiated by jax: dense [b, h, s, s] probabilities, so for small
+    sizes only. The target and what the indexer reads are under
+    ``stop_gradient`` as the kernels' rule has it."""
+    scores = indexer_scores_reference(qi, ki, w)
+    mask = topk_select_reference(jax.lax.stop_gradient(scores), topk)
+    out, p = sparse_attention_reference(q, k, v, mask, scale)
+    target = jax.lax.stop_gradient(jnp.mean(p, 1))
+    loss = _indexer_kl(scores, target, mask)
+    return out.astype(q.dtype), loss

@@ -132,6 +132,7 @@ import itertools
 import json
 import threading
 import time
+import warnings
 from bisect import bisect_right
 from collections import deque
 from dataclasses import dataclass
@@ -271,6 +272,43 @@ class MetricsRegistry:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self.histograms: Dict[str, _Histogram] = {}
+        self._sources: List[tuple] = []
+        self._warned: set = set()
+
+    def add_source(self, prefix: str, ref):
+        """A subsystem whose numbers live elsewhere (a layer's counters
+        on the device) and are asked for when the registry is exported:
+        ``ref`` is a weak reference (``weakref.ref`` / ``WeakMethod``) to
+        a callable returning a stats dict, which ``snapshot`` publishes
+        under ``prefix`` (``value`` reads what was last published and asks
+        nobody: a source may wait for the device). Of two live sources
+        with one prefix the later one's numbers stand. What was last
+        published stays once the subsystem is gone; a source that raises
+        is skipped, with one warning."""
+        with self._lock:
+            self._sources.append((prefix, ref))
+
+    def _pull(self):
+        with self._lock:
+            sources = list(self._sources)
+        for source in sources:
+            prefix, ref = source
+            fn = ref()
+            if fn is None:
+                with self._lock:
+                    if source in self._sources:
+                        self._sources.remove(source)
+                continue
+            try:
+                stats = fn()
+            except Exception as e:          # telemetry never fails the program
+                if prefix not in self._warned:
+                    self._warned.add(prefix)
+                    warnings.warn(f"metrics source {prefix!r} raised "
+                                  f"{e!r}; its last numbers stay",
+                                  RuntimeWarning)
+                continue
+            self.publish(prefix, stats)
 
     def inc(self, name: str, n: float = 1):
         with self._lock:
@@ -316,6 +354,7 @@ class MetricsRegistry:
             return self.gauges.get(name)
 
     def snapshot(self) -> dict:
+        self._pull()
         with self._lock:
             return {"counters": dict(self.counters),
                     "gauges": dict(self.gauges),

@@ -143,6 +143,52 @@ def test_head_and_dense_loss_at_the_training_cell_shape(one_chip, on_chip):
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------
 
+# Keye-VL-2.0's learned sparse attention at the benchmark cell's widths:
+# 32 q / 4 kv heads of 128, an indexer of 16 heads of 64, 2048 of 8192 keys
+KEYE = dict(b=2, h=32, hk=4, s=8192, d=128, hi=16, di=64, topk=2048)
+
+
+def _keye_args(sh):
+    k = KEYE
+    return dict(
+        q=_sds(sh, (k["b"], k["h"], k["s"], k["d"]), BF16),
+        kv=_sds(sh, (k["b"], k["hk"], k["s"], k["d"]), BF16),
+        qi=_sds(sh, (k["b"], k["hi"], k["s"], k["di"]), BF16),
+        ki=_sds(sh, (k["b"], k["s"], k["di"]), BF16),
+        w=_sds(sh, (k["b"], k["s"], k["hi"]), jnp.float32),
+        scores=_sds(sh, (k["b"], k["s"], k["s"]), jnp.float32),
+        mask=_sds(sh, (k["b"], k["s"], k["s"]), jnp.int8),
+        lse=_sds(sh, (k["b"], k["h"], k["s"], 1), jnp.float32),
+        row=_sds(sh, (k["b"], k["s"], 1), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel,operands,n", [
+    ("indexer_scores", ("qi", "ki", "w"), 1),
+    ("topk_select", ("scores",), 1),
+    ("sparse_attn_fwd", ("q", "kv", "kv", "mask"), 1),
+    ("indexer_loss_rows", ("q", "kv", "lse", "mask", "scores"), 1),
+    ("indexer_loss_grad", ("q", "kv", "lse", "mask", "scores", "row",
+                           "row"), 1),
+    ("sparse_attn_bwd", ("q", "kv", "kv", "q", "lse", "q", "mask"), 2),
+])
+def test_learned_sparse_attention_kernels_keye_widths(one_chip, on_chip,
+                                                      kernel, operands, n):
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    a = _keye_args(one_chip)
+    scale = KEYE["d"] ** -0.5
+    fn = {"indexer_scores": sa.indexer_scores,
+          "topk_select": lambda x: sa.topk_select(x, KEYE["topk"]),
+          "sparse_attn_fwd": lambda *x: sa.sparse_attn_fwd(*x, scale),
+          "indexer_loss_rows": lambda *x: sa.indexer_loss(*x, scale)[0],
+          "indexer_loss_grad": lambda q, kv, lse, mask, sc, z, li:
+          sa.indexer_loss_grad(q, kv, lse, mask, sc, (z, li), scale),
+          "sparse_attn_bwd": lambda *x: sa.sparse_attn_bwd(*x, scale)}[kernel]
+    text = jax.jit(fn).lower(*[a[o] for o in operands]).compile().as_text()
+    assert text.count("tpu_custom_call") == n
+    # the device trace tells the kernels apart by these names
+    assert f"%{kernel}" in text
+
+
 def test_gate_refuses_head_dim_64(on_chip):
     from paddle_tpu.ops.paged_attention import (_pallas_decode_ok,
                                                 paged_attention_impl)
