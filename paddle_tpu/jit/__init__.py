@@ -526,6 +526,15 @@ class TrainStep:
         step = TrainStep(model, loss_fn, optimizer)   # loss_fn(out, *labels)
         loss = step(x, y)                             # Tensors in, loss out
 
+    The program is two stages: every trainable leaf's gradient is
+    finished (``jax.lax.optimization_barrier`` over them all), then the
+    optimizer runs. Without the boundary XLA fuses the optimizer's update
+    into the weight-gradient matmuls and runs those after the whole
+    backward pass, which slows the matmuls and keeps their operands alive
+    to the program's end. Each trace of a step program adds the number
+    of leaves under its barrier to ``train_step.grad_barrier_leaves`` in
+    the default registry.
+
     The compiled program donates parameter/optimizer-state buffers, so
     updates are in-place in HBM (the analog of the reference interpreter's
     inplace pass + buffer GC, at zero runtime cost).
@@ -570,7 +579,9 @@ class TrainStep:
     def _make_loss_and_grads(self):
         """Closure computing (loss, new_buffers, per-param grads) — the
         shared forward+backward of both the plain and gradient-merge
-        compiled programs."""
+        compiled programs. The gradients leave it under one
+        optimization barrier: whatever consumes them (optimizer,
+        accumulator) starts after the last of them is finished."""
         model = self.model
         loss_fn = self.loss_fn
         trainable_mask = self._trainable_mask
@@ -596,6 +607,9 @@ class TrainStep:
 
             (loss, new_bufs), grads = jax.value_and_grad(
                 loss_f, has_aux=True)(train_params)
+            grads = jax.lax.optimization_barrier(grads)
+            telemetry.default_tracer().metrics.inc(
+                "train_step.grad_barrier_leaves", len(grads))
             # re-expand grads to the full param list (None for frozen)
             gi = iter(grads)
             full_grads = [next(gi) if m else None for m in trainable_mask]

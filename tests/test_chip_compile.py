@@ -141,6 +141,56 @@ def test_head_and_dense_loss_at_the_training_cell_shape(one_chip, on_chip):
     assert ".remat" not in compiled.as_text()
 
 
+def test_mistral_cell_whole_step_recomputes_nothing(one_chip, on_chip):
+    """The training cell's whole ``jit.TrainStep`` program (Mistral-7B
+    widths from the cell's configuration file, 2 layers, batch 4 x 4096,
+    bf16 parameters with float32 master, m and v as ``benchmark.systems.
+    Trainer`` holds them): with every gradient finished before AdamW
+    starts, XLA keeps no operand of the backward pass for the optimizer's
+    sake, so it recomputes nothing (56.16 TFLOP is the step's own work;
+    with AdamW fused into the weight-gradient matmuls the program ran the
+    head's forward matmul twice, 60.56 TFLOP at 16.19 GB) and fits with
+    room (14.99 GB of the chip's 16.9)."""
+    import json
+    import os
+
+    import paddle_tpu as paddle
+    from benchmark.families import llama as family
+    from paddle_tpu import optimizer
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            repo, "benchmark/configs/mistral_7b_v03_l2_train.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(repo, "benchmark/traffic/train_s4096.json")) as f:
+        mix = json.load(f)
+    model, _ = family.build_trainable(cfg)
+    for p in model.parameters():      # shapes only: nothing runs here
+        p._value = jax.ShapeDtypeStruct(tuple(p.shape), BF16)
+    opt = optimizer.AdamW(parameters=model.parameters(), **cfg["optimizer"])
+    step = paddle.jit.TrainStep(
+        model, lambda out, lab: model.loss(out, lab), opt)
+    opt._state = jax.eval_shape(
+        opt.init_state, [p._value for p in opt._parameter_list])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    ids = _sds(one_chip, (mix["batch"], mix["seq"]))
+    compiled = step._build().lower(
+        placed([p._value for p in step._p_tensors]),
+        placed([b._value for b in step._b_tensors]), placed(opt._state),
+        _sds(one_chip, (), jnp.float32), _sds(one_chip, (2,), jnp.uint32),
+        (ids,), (ids,)).compile()
+    assert ".remat" not in compiled.as_text()
+    assert compiled.cost_analysis()["flops"] == pytest.approx(56.16e12,
+                                                              rel=0.01)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) < 15.5e9
+
+
 # -- the gate: what the chip's compiler refuses never reaches it ------------
 
 # Keye-VL-2.0's learned sparse attention at the benchmark cell's widths:
