@@ -73,7 +73,7 @@ class PipelineSchedule:
     # costs what its *busiest stage* actually runs: fwd = 1; bwd = 2
     # from stored residuals, 3 under remat (remat-fwd 1 + bwd 2). The
     # lock-step barrier is the per-tick ppermute pair, hence max over
-    # stages. bench.py `pp` measures the real on-chip number.
+    # stages. A model in chunk units, not measured on the chip.
     CHUNK_COST_PER_TICK = 4.0          # full fwd+bwd tick, remat (back-compat)
 
     def chunk_cost_per_tick(self, remat: bool = True) -> float:

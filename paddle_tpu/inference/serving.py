@@ -5065,7 +5065,7 @@ class ServingEngine:
         is the production one): from here on, any compile observed by
         the watch increments stats()["unexpected_recompiles"] and
         fires an ``unexpected_recompile`` tracer event — the runtime
-        FC2xx. Chaos legs and bench.py serving_trace assert zero."""
+        FC2xx. The chaos legs assert zero."""
         self.compile_watch.seal()
 
     def clear_finished(self):

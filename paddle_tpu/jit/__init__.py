@@ -91,12 +91,12 @@ def _wrap_tree(x, stop_gradient=True):
     return x
 
 
-def functional_call(layer, param_arrays: Sequence[jax.Array],
-                    buffer_arrays: Sequence[jax.Array], args: tuple,
-                    kwargs: Optional[dict] = None):
-    """Run ``layer(*args)`` with parameters/buffers replaced by the given
-    arrays. args are raw arrays or Tensors. Returns
-    (output_pytree_of_arrays, new_buffer_arrays)."""
+def _call_swapped(layer, param_arrays, buffer_arrays, args, kwargs, then):
+    """``layer(*args)`` and then ``then(output_pytree_of_arrays)``, both
+    with the given arrays in place of the layer's parameters and buffers:
+    whatever ``then`` reads from the layer is what the caller passed in,
+    not the live value. The buffers are read back between the two.
+    Returns (what ``then`` returned, new_buffer_arrays)."""
     kwargs = kwargs or {}
     params, buffers = _collect(layer)
     p_tensors = [p for _, p in params]
@@ -107,7 +107,18 @@ def functional_call(layer, param_arrays: Sequence[jax.Array],
         with no_grad():
             out = layer(*targs, **kwargs)
         new_buffers = bguard.read_current()
-    return _unwrap_tree(out), new_buffers
+        result = then(_unwrap_tree(out))
+    return result, new_buffers
+
+
+def functional_call(layer, param_arrays: Sequence[jax.Array],
+                    buffer_arrays: Sequence[jax.Array], args: tuple,
+                    kwargs: Optional[dict] = None):
+    """Run ``layer(*args)`` with parameters/buffers replaced by the given
+    arrays. args are raw arrays or Tensors. Returns
+    (output_pytree_of_arrays, new_buffer_arrays)."""
+    return _call_swapped(layer, param_arrays, buffer_arrays, args, kwargs,
+                         lambda out: out)
 
 
 # ---------------------------------------------------------------------------
@@ -526,6 +537,11 @@ class TrainStep:
         step = TrainStep(model, loss_fn, optimizer)   # loss_fn(out, *labels)
         loss = step(x, y)                             # Tensors in, loss out
 
+    ``loss_fn`` runs while the step's traced arrays still stand in for
+    the model's parameters and buffers, as the forward pass does: a
+    weight it reads from the model (a chunked loss reads the head, a
+    regulariser any weight) gets its gradient.
+
     The program is two stages: every trainable leaf's gradient is
     finished (``jax.lax.optimization_barrier`` over them all), then the
     optimizer runs. Without the boundary XLA fuses the optimizer's update
@@ -596,14 +612,21 @@ class TrainStep:
                 it_t, it_f = iter(tp), iter(frozen)
                 full = [next(it_t) if m else next(it_f)
                         for m in trainable_mask]
+
+                # the loss runs under the forward pass's swap: a weight it
+                # reads from the model (a chunked loss reads the head) is
+                # the traced array and gets its gradient
+                def loss_of(out):
+                    with with_rng_key(jax.random.fold_in(key, 777)), \
+                            no_grad():
+                        out_t = _wrap_tree(out)
+                        label_t = tuple(_wrap_tree(l) for l in labels)
+                        loss_t = loss_fn(out_t, *label_t)
+                    return loss_t._value.astype(jnp.float32)
+
                 with with_rng_key(key):
-                    out, new_bufs = functional_call(
-                        model, full, buffer_arrays, inputs)
-                with with_rng_key(jax.random.fold_in(key, 777)), no_grad():
-                    out_t = _wrap_tree(out)
-                    label_t = tuple(_wrap_tree(l) for l in labels)
-                    loss_t = loss_fn(out_t, *label_t)
-                return loss_t._value.astype(jnp.float32), new_bufs
+                    return _call_swapped(model, full, buffer_arrays, inputs,
+                                         None, loss_of)
 
             (loss, new_bufs), grads = jax.value_and_grad(
                 loss_f, has_aux=True)(train_params)

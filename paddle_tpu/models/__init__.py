@@ -1,15 +1,13 @@
-"""paddle_tpu.models — flagship model zoo (BASELINE.json configs)."""
+"""paddle_tpu.models — the model zoo."""
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_tiny, llama_small,
-    llama_mid, llama_1b, llama_3_8b,
+    llama_mid, llama_3_8b,
 )
 from .gpt import (  # noqa: F401
     GPTConfig, GPTForCausalLM, GPTModel, gpt_tiny, gpt_345m,
-    ernie_45_dense_3b,
 )
 from .moe_lm import (  # noqa: F401
-    MoEConfig, MoEForCausalLM, MoEModel, moe_tiny, deepseek_moe_16b_like,
-    qwen2_moe_a14b_like,
+    MoEConfig, MoEForCausalLM, MoEModel, moe_tiny,
 )
 from .keye_vl2 import (  # noqa: F401
     KeyeVL2Config, KeyeVL2ForCausalLM, KeyeVL2Model, keye_vl2_tiny,

@@ -1,7 +1,7 @@
 """DiT — Diffusion Transformer (SD3/DiT family).
 
 Capability parity target: the diffusion-transformer configs the
-reference trains (BASELINE.json 'SD3/DiT (conv+attn)'); reference
+reference trains (SD3/DiT: conv + attention); reference
 framework pieces: conv/attention kernels + fused layers (SURVEY.md §2.1
 fused kernels). Architecture per the public DiT recipe: patchify conv →
 N transformer blocks with adaLN-Zero timestep/label conditioning →

@@ -18,10 +18,10 @@ import jax.numpy as jnp
 from ..framework.core import apply
 from .. import nn
 from ..nn import functional as F
-from ..nn.functional.loss import causal_lm_loss
+from .lm_head import head_output, make_lm_head, next_token_loss
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "gpt_tiny",
-           "gpt_345m", "ernie_45_dense_3b"]
+           "gpt_345m"]
 
 
 @dataclass
@@ -166,7 +166,6 @@ class GPTDecoderLayer(nn.Layer):
         return self._block(x)
 
 
-
 class GPTModel(nn.Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__(dtype=cfg.dtype)
@@ -203,31 +202,17 @@ class GPTForCausalLM(nn.Layer):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
         self.gpt = GPTModel(cfg)
-        if cfg.tie_word_embeddings:
-            self.lm_head = None
-        else:
-            self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
-                                     bias_attr=False)
+        self.lm_head = make_lm_head(cfg.hidden_size, cfg.vocab_size,
+                                    tied=cfg.tie_word_embeddings)
 
     def forward(self, input_ids):
-        h = self.gpt(input_ids)
-        if self.cfg.chunked_ce_tokens:
-            return h          # loss() owns the head matmul (chunked CE)
-        if self.lm_head is None:
-            from ..tensor.linalg import matmul
-            return matmul(h, self.gpt.embed_tokens.weight,
-                          transpose_y=True)
-        return self.lm_head(h)
+        return head_output(self.gpt(input_ids), self.lm_head,
+                           self.gpt.embed_tokens, self.cfg.chunked_ce_tokens)
 
-    def loss(self, logits, labels):
-        if self.cfg.chunked_ce_tokens:
-            from ..nn.functional.loss import chunked_causal_lm_loss
-            return chunked_causal_lm_loss(
-                logits, labels,
-                None if self.lm_head is None else self.lm_head.weight,
-                self.gpt.embed_tokens.weight,
-                int(self.cfg.chunked_ce_tokens))
-        return causal_lm_loss(logits, labels)
+    def loss(self, out, labels):
+        return next_token_loss(out, labels, self.lm_head,
+                               self.gpt.embed_tokens,
+                               self.cfg.chunked_ce_tokens)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())
@@ -246,11 +231,3 @@ def gpt_345m(**kw) -> GPTConfig:
                      num_attention_heads=16,
                      max_position_embeddings=1024, **kw)
 
-
-def ernie_45_dense_3b(**kw) -> GPTConfig:
-    """ERNIE-4.5-style dense config (BASELINE.json 'ERNIE (DP)' entry)."""
-    return GPTConfig(vocab_size=103424, hidden_size=2560,
-                     intermediate_size=12288, num_hidden_layers=28,
-                     num_attention_heads=20,
-                     max_position_embeddings=4096,
-                     tie_word_embeddings=False, **kw)

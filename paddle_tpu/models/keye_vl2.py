@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..framework.core import apply
-from ..nn.functional.loss import causal_lm_loss
+from .lm_head import head_output, make_lm_head, next_token_loss
 from ..ops.pallas import sparse_attention as sa
 from ..ops.rms_norm import rms_norm
 from ..ops.rope import build_rope_cache, rope_reference
@@ -211,8 +211,7 @@ class KeyeVL2ForCausalLM(nn.Layer):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
         self.model = KeyeVL2Model(cfg)
-        self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
-                                 bias_attr=False)
+        self.lm_head = make_lm_head(cfg.hidden_size, cfg.vocab_size)
         # the default registry's snapshot() asks for the experts' counters
         # (moe.rows_held, moe.rows_max_expert, moe.rows_routed)
         telemetry.default_tracer().metrics.add_source(
@@ -220,14 +219,13 @@ class KeyeVL2ForCausalLM(nn.Layer):
 
     def forward(self, input_ids):
         h, aux = self.model(input_ids)
-        with jax.named_scope("lm_head"):
-            return self.lm_head(h), aux
+        return head_output(h, self.lm_head, None), aux
 
     def loss(self, out, labels):
         """Mean next-token cross entropy plus the layers' indexer losses."""
         logits, aux = out
-        with jax.named_scope("loss"):
-            return causal_lm_loss(logits, labels) + aux.astype("float32")
+        return next_token_loss(logits, labels, self.lm_head, None) \
+            + aux.astype("float32")
 
     def indexer_parameters(self):
         return [p for layer in self.model.layers

@@ -26,11 +26,11 @@ import jax.numpy as jnp
 from ..framework.core import Tensor, apply
 from .. import nn
 from ..nn import functional as F
-from ..nn.functional.loss import causal_lm_loss
+from .lm_head import head_output, make_lm_head, next_token_loss
 from ..ops.rope import build_rope_cache, rope_reference
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "llama_tiny",
-           "llama_small", "llama_mid", "llama_1b", "llama_3_8b"]
+           "llama_small", "llama_mid", "llama_3_8b"]
 
 
 @dataclass
@@ -50,8 +50,7 @@ class LlamaConfig:
     # reference recompute_granularity (PaddleNLP llama configs):
     # "full"      — whole block rematerialized (max memory savings)
     # "full_attn" — only the attention sublayer (ln1 + attn)
-    #               rematerialized; MLP activations stored. The middle
-    #               ground that keeps most of the no-remat MFU
+    #               rematerialized; MLP activations stored
     # "core_attn" — only the attention inner (scores/softmax/context)
     #               recomputed. With the Pallas flash kernel this is the
     #               plain forward: flash backward already recomputes
@@ -384,57 +383,27 @@ class LlamaForCausalLM(nn.Layer):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
         self.model = LlamaModel(cfg)
-        if cfg.tie_word_embeddings:
-            self.lm_head = None
-        elif cfg.tensor_parallel and _mp_active():
-            from ..distributed.fleet import ColumnParallelLinear
-            self.lm_head = ColumnParallelLinear(
-                cfg.hidden_size, cfg.vocab_size, has_bias=False,
-                gather_output=True)
-        else:
-            self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
-                                     bias_attr=False)
+        self.lm_head = make_lm_head(
+            cfg.hidden_size, cfg.vocab_size, tied=cfg.tie_word_embeddings,
+            tensor_parallel=cfg.tensor_parallel and _mp_active())
 
     def forward(self, input_ids, caches=None, pos=None):
-        if caches is not None:
-            h, new_caches = self.model(input_ids, caches=caches, pos=pos)
-        else:
-            h = self.model(input_ids)
-        if self.cfg.chunked_ce_tokens and caches is None:
-            # chunked-CE training config: loss() owns the head matmul
-            return h
-        with jax.named_scope("lm_head"):
-            if self.lm_head is None:
-                from ..tensor.linalg import matmul
-                logits = matmul(h, self.model.embed_tokens.weight,
-                                transpose_y=True)
-            else:
-                logits = self.lm_head(h)
-        if caches is not None:
-            return logits, new_caches
-        return logits
+        if caches is None:
+            # with cfg.chunked_ce_tokens the hidden states: loss() owns
+            # the head's matmul
+            return head_output(self.model(input_ids), self.lm_head,
+                               self.model.embed_tokens,
+                               self.cfg.chunked_ce_tokens)
+        h, new_caches = self.model(input_ids, caches=caches, pos=pos)
+        return head_output(h, self.lm_head,
+                           self.model.embed_tokens), new_caches
 
-    def loss(self, logits, labels):
-        """Shifted causal-LM cross entropy. The dense path shifts the
-        LABELS and takes the logits whole (``causal_lm_loss``): no
-        sliced copy of them, and the cross entropy's only residual is
-        their gradient in their own dtype, made in the forward pass.
-        With cfg.chunked_ce_tokens > 0, forward() returns HIDDEN states
-        and this computes the head matmul + CE in sequence chunks under
-        jax.checkpoint — the [B, S, V] logits are never materialized;
-        the backward rematerializes one chunk's logits at a time."""
-        with jax.named_scope("loss"):
-            if self.cfg.chunked_ce_tokens:
-                return self._chunked_loss(logits, labels)
-            return causal_lm_loss(logits, labels)
-
-    def _chunked_loss(self, hidden, labels):
-        from ..nn.functional.loss import chunked_causal_lm_loss
-        return chunked_causal_lm_loss(
-            hidden, labels,
-            None if self.lm_head is None else self.lm_head.weight,
-            self.model.embed_tokens.weight,
-            int(self.cfg.chunked_ce_tokens))
+    def loss(self, out, labels):
+        """Shifted causal-LM cross entropy of ``out = forward(ids)``
+        (``lm_head.next_token_loss``)."""
+        return next_token_loss(out, labels, self.lm_head,
+                               self.model.embed_tokens,
+                               self.cfg.chunked_ce_tokens)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())
@@ -485,7 +454,7 @@ def llama_tiny(**kw) -> LlamaConfig:
 
 
 def llama_small(**kw) -> LlamaConfig:
-    """~0.5B bench config sized for a single v5e chip."""
+    """~0.5B: 8 layers at llama_mid's width."""
     base = dict(vocab_size=32000, hidden_size=2048,
                 intermediate_size=5632, num_hidden_layers=8,
                 num_attention_heads=16, num_key_value_heads=8,
@@ -494,26 +463,10 @@ def llama_small(**kw) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
-def llama_1b(**kw) -> LlamaConfig:
-    """~1.0B largest-fitting config for one 16GB v5e chip: llama_mid's
-    MXU-efficient width at 18 layers; trains with remat + chunked CE
-    (BASELINE.md protocol: record the largest fit, not just the sweet
-    spot)."""
-    base = dict(vocab_size=32000, hidden_size=2048,
-                intermediate_size=5632, num_hidden_layers=18,
-                num_attention_heads=16, num_key_value_heads=8,
-                max_position_embeddings=4096)
-    base.update(kw)
-    return LlamaConfig(**base)
-
-
 def llama_mid(**kw) -> LlamaConfig:
-    """~0.65B bench config — the largest AdamW(multi_precision) +
-    activations footprint that keeps >=70% MFU on one 16GB v5e chip
-    (BASELINE.md step toward the Llama-3-8B north star). Width matches
-    llama_small (MXU-efficient 2048x5632 matmuls); measured sweep: this
-    shape at batch 4, seq 2048 gives 70.3% MFU vs 62.4% for a
-    narrow-deep 24-layer 717M variant."""
+    """~0.65B: 11 layers of 2048 x 5632, which with
+    AdamW(multi_precision) and the activations of batch 4 x 2048 fits
+    one 16 GB v5e chip (``chip_smoke.py`` trains it)."""
     base = dict(vocab_size=32000, hidden_size=2048,
                 intermediate_size=5632, num_hidden_layers=11,
                 num_attention_heads=16, num_key_value_heads=8,

@@ -3,7 +3,7 @@
 Capability parity target: the reference's MoE stack
 (/root/reference/python/paddle/incubate/distributed/models/moe/
 moe_layer.py:263 + global_scatter/gather alltoall comm) as used by
-DeepSeek/Qwen MoE recipes (BASELINE.json EP config).
+DeepSeek/Qwen MoE recipes.
 
 TPU-native: Llama-style decoder blocks whose MLP is an nn.MoELayer
 (top-k gating, capacity-bounded dispatch expressed as one-hot matmuls —
@@ -17,11 +17,10 @@ from dataclasses import dataclass
 
 from .. import nn
 from ..nn import functional as F
-from ..nn.functional.loss import causal_lm_loss
+from .lm_head import head_output, make_lm_head, next_token_loss
 from .llama import LlamaAttention, LlamaConfig, _LayerFn
 
-__all__ = ["MoEConfig", "MoEForCausalLM", "MoEModel", "moe_tiny",
-           "deepseek_moe_16b_like", "qwen2_moe_a14b_like"]
+__all__ = ["MoEConfig", "MoEForCausalLM", "MoEModel", "moe_tiny"]
 
 
 @dataclass
@@ -203,24 +202,16 @@ class MoEForCausalLM(nn.Layer):
         super().__init__(dtype=cfg.dtype)
         self.cfg = cfg
         self.model = MoEModel(cfg)
-        self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
-                                 bias_attr=False)
+        self.lm_head = make_lm_head(cfg.hidden_size, cfg.vocab_size)
 
     def forward(self, input_ids):
-        h = self.model(input_ids)
-        if self.cfg.chunked_ce_tokens:
-            return h          # loss() owns the head matmul (chunked CE)
-        return self.lm_head(h)
+        return head_output(self.model(input_ids), self.lm_head, None,
+                           self.cfg.chunked_ce_tokens)
 
-    def loss(self, logits, labels):
+    def loss(self, out, labels):
         """Shifted CE + router load-balance auxiliary loss."""
-        if self.cfg.chunked_ce_tokens:
-            from ..nn.functional.loss import chunked_causal_lm_loss
-            ce = chunked_causal_lm_loss(
-                logits, labels, self.lm_head.weight, None,
-                int(self.cfg.chunked_ce_tokens))
-        else:
-            ce = causal_lm_loss(logits, labels)
+        ce = next_token_loss(out, labels, self.lm_head, None,
+                             self.cfg.chunked_ce_tokens)
         aux = self.model.aux_losses()
         if aux and self.cfg.aux_loss_weight:
             total_aux = aux[0]
@@ -255,22 +246,3 @@ def moe_tiny(**kw) -> MoEConfig:
     base.update(kw)          # callers may override any default
     return MoEConfig(**base)
 
-
-def deepseek_moe_16b_like(**kw) -> MoEConfig:
-    return MoEConfig(vocab_size=102400, hidden_size=2048,
-                     intermediate_size=10944, moe_intermediate_size=1408,
-                     num_hidden_layers=28, num_attention_heads=16,
-                     num_key_value_heads=16, num_experts=64,
-                     num_experts_per_tok=6, num_shared_experts=2,
-                     first_k_dense_replace=1,
-                     max_position_embeddings=4096, **kw)
-
-
-def qwen2_moe_a14b_like(**kw) -> MoEConfig:
-    return MoEConfig(vocab_size=151936, hidden_size=3584,
-                     intermediate_size=18944, moe_intermediate_size=2560,
-                     num_hidden_layers=28, num_attention_heads=28,
-                     num_key_value_heads=4, num_experts=64,
-                     num_experts_per_tok=8, num_shared_experts=1,
-                     first_k_dense_replace=0,
-                     max_position_embeddings=8192, **kw)

@@ -17,7 +17,7 @@ __all__ = [
     "cosine_embedding_loss", "label_smooth", "square_error_cost",
     "log_loss", "hinge_embedding_loss", "triplet_margin_loss",
     "sigmoid_focal_loss", "ctc_loss", "poisson_nll_loss",
-    "chunked_softmax_cross_entropy", "chunked_causal_lm_loss",
+    "chunked_softmax_cross_entropy",
 ]
 
 
@@ -455,26 +455,11 @@ def chunked_softmax_cross_entropy(hidden, labels, weight,
     return apply("chunked_ce", f, hidden, labels, weight)
 
 
-def chunked_causal_lm_loss(hidden, labels, lm_head_weight,
-                           embedding_weight, chunk_tokens: int,
-                           ignore_index: int = -100):
-    """The CausalLM adoption seam for chunked CE: pass the lm_head
-    weight (or None when embeddings are tied) and the embedding weight;
-    the tied case transposes. One call site per model — the weight-
-    selection logic lives here, not copied into every zoo model."""
-    if lm_head_weight is not None:
-        return chunked_softmax_cross_entropy(
-            hidden, labels, lm_head_weight, chunk_tokens,
-            ignore_index=ignore_index)
-    return chunked_softmax_cross_entropy(
-        hidden, labels, embedding_weight, chunk_tokens,
-        transpose_weight=True, ignore_index=ignore_index)
-
-
 def causal_lm_loss(logits, labels, ignore_index: int = -100):
     """Next-token cross entropy of dense [B, S, V] logits, the dense
-    counterpart of ``chunked_causal_lm_loss``. The causal shift is made
-    on the LABELS (``labels[:, 1:]`` with one ``ignore_index`` column
+    counterpart of ``chunked_softmax_cross_entropy``
+    (``models.lm_head.next_token_loss`` chooses between them). The causal
+    shift is made on the LABELS (``labels[:, 1:]`` with one ``ignore_index`` column
     appended), so the logits are only reshaped to [B*S, V], a bitcast:
     ``logits[:, :-1]`` would copy them into B*(S-1) rows, which no tile
     divides. Same sum over the same B*(S-1) positions, same denominator."""

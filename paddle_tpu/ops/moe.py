@@ -117,19 +117,18 @@ def _grouped_mm(lhs, rhs, group_sizes):
     lhs [R, K] x rhs [E, K, N] -> [R, N], rows partitioned into E
     segments by group_sizes.
 
-    lax.ragged_dot: measured r5 on the v5e at the bench geometry
-    ([16384, 1024] x [8, 1024, 1408]), XLA's native lowering runs at
-    121 TF/s (62% of peak) — faster than the Pallas megablox gmm
-    kernel on this backend (6.6 ms default tiling, 1.7 ms best tiling
-    vs 0.39 ms here), so the hand kernel is NOT used. The ragged MFU
-    gap lives in dispatch/combine, not the matmuls.
+    XLA's own lowering of lax.ragged_dot, not the Pallas megablox gmm
+    kernel. No cell of the benchmark runs this function; the same
+    lowering under ``moe_share_forward`` is the Keye cell's
+    ``ragged-dot-none`` (PERF.md section 5), where the expert layer's
+    time lies in dispatch and combine more than in the matmuls.
     """
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
 def moe_ragged_forward(x, gate_w, w1, w2, top_k: int,
                        activation=jax.nn.gelu, capacity_factor=None):
-    """Sort-based DROPLESS MoE FFN (the large-E path, VERDICT r3 #7):
+    """Sort-based DROPLESS MoE FFN (the large-E path):
     x [B, S, D] → (out [B, S, D], aux_loss, stats).
 
     The dense GShard dispatch materializes [T, E, C] one-hot tensors —
@@ -173,14 +172,12 @@ def moe_ragged_forward(x, gate_w, w1, w2, top_k: int,
 
     h = activation(_grouped_mm(xs, w1.astype(xs.dtype), group_sizes))
     ys = _grouped_mm(h, w2.astype(xs.dtype), group_sizes)
-    # combine: weighted scatter-ADD back to token rows. Measured r5 on
-    # the v5e (model-level A/B at the bench geometry): this XLA-fused
-    # form runs the whole ragged model at 66.2k tok/s vs 53.6k for a
+    # combine: weighted scatter-ADD back to token rows. XLA fuses the
+    # multiply into the scatter and transposes it to a gather; a
     # scatter-free rewrite (bijective-inverse Pallas permute + reshape
-    # reduce, custom vjps) and 58.1k for a hybrid — the fused
-    # multiply-into-scatter and its cheap gather transpose beat
-    # "faster" index plumbing that breaks XLA fusion at custom_vjp
-    # boundaries. Keep this form; don't re-learn the lesson.
+    # reduce, custom vjps) breaks that fusion at its custom_vjp
+    # boundaries. No cell of the benchmark runs this function: not
+    # measured on the chip.
     wsorted = gates.reshape(t * top_k)[order].astype(ys.dtype)
     out = jnp.zeros((t, d), ys.dtype).at[sorted_tok].add(
         ys * wsorted[:, None])

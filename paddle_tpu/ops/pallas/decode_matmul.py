@@ -1,10 +1,9 @@
 """Weight-streaming matmul for decode-shaped activations (few rows).
 
 Why: serving decode multiplies a tiny activation [b<=32, K] against
-huge weights [K, N] — the op is pure weight-bandwidth. Measured r5 on
-the v5e at the Llama-3-8B MLP shape ([8, 4096] x [4096, 14336]), XLA's
-stock lowering streams weights at only ~150-250 GB/s of the chip's
-~800 GB/s (it picks compute-shaped tilings for an M=8 problem). This
+huge weights [K, N] — the op is pure weight-bandwidth, and XLA's stock
+lowering picks compute-shaped tilings for an M=8 problem. (No cell of
+the benchmark reaches this kernel: not measured on the chip.) This
 kernel tiles N x K with the activation resident in VMEM, streams weight
 tiles through the automatic Pallas pipeline, accumulates in an f32
 VMEM scratch, and dequantizes int8 / nibble-packed int4 tiles on the

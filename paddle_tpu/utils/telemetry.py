@@ -113,9 +113,9 @@ guard, no PRNG key is drawn, no device call is made, no schedule array
 changes. Enabled, the hot path appends small dicts to a deque and
 never touches a traced array or forces a host sync (the tracer reads
 only host-side scheduler state — flightcheck's FC301 family stays at
-zero findings over this module and its call sites); the serving bench
-pins the enabled overhead < 5% tok/s on the ragged row
-(bench.py serving_trace).
+zero findings over this module and its call sites). What the enabled
+ring costs a training step is in PERF.md (PR 27); a serving step's cost
+is not measured on the chip.
 
 Export format: Chrome Trace Event JSON (the ``traceEvents`` array
 form), loadable by Perfetto (ui.perfetto.dev) and chrome://tracing.

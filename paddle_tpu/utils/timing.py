@@ -9,8 +9,8 @@ per call.
 
 Completion is forced with jax.block_until_ready on the last result.
 
-Shared by bench.py (pipeline microbench) and
-distributed.fleet.pipeline.PipelineParallel (store-vs-remat auto-pick).
+Used by distributed.fleet.pipeline.PipelineParallel (store-vs-remat
+auto-pick).
 """
 from __future__ import annotations
 

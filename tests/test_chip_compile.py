@@ -141,30 +141,28 @@ def test_head_and_dense_loss_at_the_training_cell_shape(one_chip, on_chip):
     assert ".remat" not in compiled.as_text()
 
 
-def test_mistral_cell_whole_step_recomputes_nothing(one_chip, on_chip):
-    """The training cell's whole ``jit.TrainStep`` program (Mistral-7B
-    widths from the cell's configuration file, 2 layers, batch 4 x 4096,
-    bf16 parameters with float32 master, m and v as ``benchmark.systems.
-    Trainer`` holds them): with every gradient finished before AdamW
-    starts, XLA keeps no operand of the backward pass for the optimizer's
-    sake, so it recomputes nothing (56.16 TFLOP is the step's own work;
-    with AdamW fused into the weight-gradient matmuls the program ran the
-    head's forward matmul twice, 60.56 TFLOP at 16.19 GB) and fits with
-    room (14.99 GB of the chip's 16.9)."""
+def _cell_step_compiled(sharding, family, config, traffic):
+    """A training cell's whole ``jit.TrainStep`` program compiled for the
+    described chip: the configuration file's model through its family's
+    builder, the traffic file's batch, bf16 parameters with float32
+    master, m and v as ``benchmark.systems.Trainer`` holds them. Returns
+    (the compiled program, its bytes by the compiler's analysis, the
+    number of gradients under the step's barrier)."""
+    import importlib
     import json
     import os
 
     import paddle_tpu as paddle
-    from benchmark.families import llama as family
     from paddle_tpu import optimizer
+    from paddle_tpu.utils import telemetry
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            repo, "benchmark/configs/mistral_7b_v03_l2_train.json")) as f:
+    with open(os.path.join(repo, f"benchmark/configs/{config}.json")) as f:
         cfg = json.load(f)
-    with open(os.path.join(repo, "benchmark/traffic/train_s4096.json")) as f:
+    with open(os.path.join(repo, f"benchmark/traffic/{traffic}.json")) as f:
         mix = json.load(f)
-    model, _ = family.build_trainable(cfg)
+    model, _ = importlib.import_module(
+        f"benchmark.families.{family}").build_trainable(cfg)
     for p in model.parameters():      # shapes only: nothing runs here
         p._value = jax.ShapeDtypeStruct(tuple(p.shape), BF16)
     opt = optimizer.AdamW(parameters=model.parameters(), **cfg["optimizer"])
@@ -175,20 +173,55 @@ def test_mistral_cell_whole_step_recomputes_nothing(one_chip, on_chip):
 
     def placed(tree):
         return jax.tree_util.tree_map(
-            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+            lambda a: _sds(sharding, a.shape, a.dtype), tree)
 
-    ids = _sds(one_chip, (mix["batch"], mix["seq"]))
+    metrics = telemetry.default_tracer().metrics
+    leaves = metrics.value("train_step.grad_barrier_leaves") or 0
+    ids = _sds(sharding, (mix["batch"], mix["seq"]))
     compiled = step._build().lower(
         placed([p._value for p in step._p_tensors]),
         placed([b._value for b in step._b_tensors]), placed(opt._state),
-        _sds(one_chip, (), jnp.float32), _sds(one_chip, (2,), jnp.uint32),
+        _sds(sharding, (), jnp.float32), _sds(sharding, (2,), jnp.uint32),
         (ids,), (ids,)).compile()
+    m = compiled.memory_analysis()
+    return (compiled,
+            m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes,
+            metrics.value("train_step.grad_barrier_leaves") - leaves)
+
+
+def test_mistral_cell_whole_step_recomputes_nothing(one_chip, on_chip):
+    """The training cell's whole ``jit.TrainStep`` program (Mistral-7B
+    widths from the cell's configuration file, 2 layers, batch 4 x 4096):
+    with every gradient finished before AdamW starts, XLA keeps no operand
+    of the backward pass for the optimizer's sake, so it recomputes
+    nothing (56.16 TFLOP is the step's own work; with AdamW fused into the
+    weight-gradient matmuls the program ran the head's forward matmul
+    twice, 60.56 TFLOP at 16.19 GB) and fits with room (14.99 GB of the
+    chip's 16.9)."""
+    compiled, nbytes, leaves = _cell_step_compiled(
+        one_chip, "llama", "mistral_7b_v03_l2_train", "train_s4096")
     assert ".remat" not in compiled.as_text()
     assert compiled.cost_analysis()["flops"] == pytest.approx(56.16e12,
                                                               rel=0.01)
-    m = compiled.memory_analysis()
-    assert (m.argument_size_in_bytes + m.output_size_in_bytes
-            + m.temp_size_in_bytes - m.alias_size_in_bytes) < 15.5e9
+    assert nbytes < 15.5e9
+    assert leaves == 21
+
+
+def test_keye_cell_whole_step(one_chip, on_chip):
+    """The Keye cell's whole step (4 layers, one chip's 16 of 128 experts,
+    batch 2 x 8192): 18.813 TFLOP of XLA's own operations (the twelve
+    Pallas kernels a layer count for nothing there), 14.11 GB of the
+    chip's 16.9, all 67 leaves' gradients under the barrier, nothing
+    recomputed."""
+    compiled, nbytes, leaves = _cell_step_compiled(
+        one_chip, "lm_keye_vl2", "keye_vl2_30b_a3b_ep8_l4_train",
+        "train_b2_s8192")
+    assert ".remat" not in compiled.as_text()
+    assert compiled.cost_analysis()["flops"] == pytest.approx(18.813e12,
+                                                              rel=0.01)
+    assert nbytes == pytest.approx(14.11e9, rel=0.02)
+    assert leaves == 67
 
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------

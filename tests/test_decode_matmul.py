@@ -1,8 +1,8 @@
 """Weight-streaming decode matmul kernel (ops/pallas/decode_matmul).
 
-The kernel is TPU-only (its value is HBM streaming; chip correctness
-and the 563->742 tok/s 8B int4 win are recorded by `bench.py 8b`);
-here: the tile chooser's invariants on the real model shapes, the
+The kernel is TPU-only (its value is HBM streaming; its speed is not
+measured on the chip by any cell of the benchmark; that it compiles for
+the chip is tests/test_chip_compile.py's); here: the tile chooser's invariants on the real model shapes, the
 support gate off-TPU, and a skip-on-CPU correctness check against the
 plain dequant matmul. Reference analog: the weight-only GEMV CUDA
 kernels behind the serving path (paddle/phi/kernels/fusion/).

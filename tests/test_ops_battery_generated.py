@@ -140,7 +140,6 @@ EXCLUDED.update({
     "F.ctc_loss": _R_DED + " (test_functional_extras grad battery)",
     "F.rnnt_loss": _R_DED + " (test_functional_extras)",
     "F.gather_tree": _R_DED + " (test_domain_libs beam decode)",
-    "F.chunked_causal_lm_loss": _R_DED + " (test_models chunked CE)",
     "F.chunked_softmax_cross_entropy": _R_DED + " (test_models)",
     "F.class_center_sample": "random sampler (distributed margin-loss aux)",
     "paddle.pca_lowrank": "randomized algorithm " + _R_DED,

@@ -411,8 +411,8 @@ class TestStoreActivationsMode:
         # more microbatches amortize the bubble
         assert eff[(4, 16, 1)] > eff[(4, 8, 1)]
         # store mode skips the remat forward: 3 vs 4 fwd-units per tick
-        # (bwd alone ~2 fwd) — model ratio 1.33x; bench.py pp measures
-        # the real on-chip overhead
+        # (bwd alone ~2 fwd) — model ratio 1.33x, not measured on the
+        # chip
         s = build_pipeline_schedule(4, 16, 1, "1F1B")
         assert s.chunk_cost_per_tick(remat=False) \
             == pytest.approx(s.chunk_cost_per_tick(remat=True) * 3 / 4)

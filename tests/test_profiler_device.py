@@ -1,8 +1,8 @@
-"""Device-trace (xprof) profiler coverage — VERDICT r4 #6.
+"""Device-trace (xprof) profiler coverage.
 
 The §5.1 profiler row delegates device timelines to jax.profiler; the
-hardware proof (real TPU kernel events in the artifact) runs in
-`bench.py profile` on the chip. Here: the summary against a real CPU
+hardware proof (real TPU kernel events in the artifact) is every
+`--trace 1` run of benchmark/run.py on the chip. Here: the summary against a real CPU
 capture (host-only -> zero device lanes, exercising the same code
 path), and a chip test that skips off-TPU; the summary over a trace cut
 from a chip run is in tests/test_tracing_spans.py. Reference analog:
@@ -19,8 +19,7 @@ from paddle_tpu import profiler
 
 requires_tpu = pytest.mark.skipif(
     jax.default_backend() != "tpu",
-    reason="device-lane capture needs the real chip (bench.py profile "
-           "records it there)")
+    reason="device-lane capture needs the real chip")
 
 
 def test_device_trace_summary_on_host_capture(tmp_path):

@@ -291,23 +291,33 @@ class TestTPEngineIdentity:
     def test_int8_comm_logits_tolerance_and_greedy_identity(self):
         """The accuracy A/B of the EQuARX-style compressed allreduce
         (tp_comm="int8"): per-step logits stay within a small relative
-        tolerance of the fp32-comm shard, and on this (deterministic,
-        seeded) workload the greedy streams are token-identical. A
-        greedy near-tie whose gap sits below the quantization error
-        can legitimately flip under compressed comms — that tradeoff
-        is the flag's contract, which is why the flag exists and fp32
-        is the default."""
+        tolerance (2%) of the fp32-comm shard. The comparison crosses a
+        quantisation, so the greedy streams are held to what that bound
+        leaves: a near-tie whose gap sits below the quantization error
+        can legitimately flip under compressed comms (the flag's
+        contract, which is why the flag exists and fp32 is the
+        default), and on these random weights one does: request 3 parts
+        at its 5th token, where the exact logits differ by 5.3e-4 of a
+        largest logit of 1.82. Up to such a position the streams are
+        equal. The same arithmetic (tp=2, fp32 comms) is held to token
+        identity."""
         import jax
         from jax.sharding import Mesh
+        from near_tie import assert_same_until_near_tie
         from paddle_tpu.inference import SamplingParams
         from paddle_tpu.inference.paged_decode import PagedLlamaDecoder
-        # 1) stream identity on the pinned workload
+        # 1) the streams on the pinned workload
         reqs = [(self._prompt(n), SamplingParams(max_new_tokens=m))
                 for n, m in ((5, 10), (12, 8), (30, 12), (9, 6),
                              (17, 10))]
         base, _ = self._run(self.model, reqs)
+        fp32, _ = self._run(self.model, reqs, tp=2)
+        assert fp32 == base
         int8, _ = self._run(self.model, reqs, tp=2, tp_comm="int8")
-        assert int8 == base
+        same = [assert_same_until_near_tie(self.model, p, want, got,
+                                           rel=0.02)
+                for (p, _), want, got in zip(reqs, base, int8)]
+        assert sum(same) >= len(reqs) - 1
         # 2) logits tolerance, measured shard-for-shard on one prefill
         mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
         ctx = reqs[2][0][None].astype(np.int32)
