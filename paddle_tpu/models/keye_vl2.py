@@ -15,7 +15,11 @@ Per layer, on ``x = RMSNorm(h)``:
   under ``stop_gradient``) to the softmax of its scores over them: the
   indexer's three matrices and its norm learn from that term alone, and
   nothing else learns from it
-  (``ops.pallas.sparse_attention.learned_sparse_attention``);
+  (``ops.pallas.sparse_attention.learned_sparse_attention``: seven
+  kernels a layer and step; the backward pass makes the scores again,
+  and the kernel of dk and dv gathers the indexer's target from the
+  probabilities it makes anyway and ends in the loss's gradient, so a
+  step makes the main attention's q.k^T four times);
 - experts: ``nn.MoEShareLayer`` - softmax routing over all
   ``num_experts`` in float32, top ``num_experts_per_tok`` renormalised,
   SwiGLU experts, of which this process holds the ``expert_share``.

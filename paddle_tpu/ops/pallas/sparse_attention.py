@@ -21,17 +21,23 @@ Kernels (each ``pallas_call`` is named; a device trace shows the name):
                         counting passes for the ``topk``-th largest value
                         of a row and ``log2(seq)`` more for the ties
 ``sparse_attn_fwd``     online-softmax attention under the mask
-``sparse_attn_bwd_dq``, ``sparse_attn_bwd_dkv``
 ``indexer_loss_rows``   the heads' summed probabilities under the mask (the
                         indexer's target) folded with its scores into the
-                        loss's sums per row; ``indexer_loss_grad``: the same
-                        pass ending in the loss's gradient w.r.t. the scores
+                        loss's sums per row
+``sparse_attn_bwd_dq``  dq, a query tile's keys innermost
+``sparse_attn_bwd_dkv`` dk and dv, a key tile's queries and, innermost,
+                        the heads; the same probabilities gather into the
+                        target once more and the tile ends in the loss's
+                        gradient with respect to the scores
 
 ``learned_sparse_attention`` ties them together under one ``custom_vjp``:
 it keeps q, k, v, the output, the row statistics, the mask and the
 indexer's operands, and makes the scores and the target again in the
 backward pass, so that no float32 [seq, seq] array outlives its pass
-(the target never leaves VMEM).
+(the target never leaves VMEM). A step makes the main attention's q.k^T
+and ``exp`` over the causal tiles four times: in the forward kernel, in
+``indexer_loss_rows`` (which needs every head's finished row statistic),
+in dq and in dkv.
 The indexer's operands get their gradient from the indexer's loss alone
 and q, k, v from the output alone. Every piece has a plain ``jax.numpy``
 twin below (``*_reference``) that the tests hold it to.
@@ -49,6 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
+from ...utils import telemetry
 
 _NEG = -1e30
 _INT_MIN = -2 ** 31
@@ -63,8 +70,8 @@ def _block(seq: int, want: int) -> int:
     return b
 
 
-def _params(*semantics):
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+def _params(*semantics, **more):
+    return pltpu.CompilerParams(dimension_semantics=semantics, **more)
 
 
 # ---------------------------------------------------------------------------
@@ -342,40 +349,66 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    mask_ref, dk_ref, dv_ref, dk_sc, dv_sc,
-                    *, scale, bq, bk, nq, group):
-    kj, gi, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+                    mask_ref, sc_ref, z_ref, lsei_ref,
+                    dk_ref, dv_ref, ds_ref, tgt,
+                    *, scale, bq, bk, heads, group):
+    """One (key tile, query tile) of dk and dv, the heads in the innermost
+    grid axis, and of the indexer's side of the same tile: every head's
+    probabilities are made once, feed dk / dv of the head's key head (the
+    output blocks hold all key heads and stay in VMEM over a key tile's
+    queries and heads) and gather, heads summed, in ``tgt``. After the
+    last head the tile's target meets the indexer's scores: the loss's
+    gradient with respect to the scores, softmax(scores) - target / z
+    over the kept keys, times the number of queries."""
+    kj, qi, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    live = kj * bk <= qi * bq + bq - 1
+    last = hi == heads - 1
 
-    @pl.when((gi == 0) & (qi == 0))
+    @pl.when((qi == 0) & (hi == 0))
     def _():
-        dk_sc[...] = jnp.zeros(dk_sc.shape, F32)
-        dv_sc[...] = jnp.zeros(dv_sc.shape, F32)
+        dk_ref[...] = jnp.zeros(dk_ref.shape, F32)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, F32)
 
-    @pl.when(kj * bk <= qi * bq + bq - 1)
+    @pl.when(live & (hi == 0))
+    def _():
+        tgt[...] = jnp.zeros((bq, bk), F32)
+
+    @pl.when(live)
     def _():
         keep = mask_ref[0].astype(jnp.int32) > 0
+        kh = hi // group
         q, do = q_ref[0, 0], do_ref[0, 0]
-        p = _probs(q, k_ref[0, 0], keep, scale, lse_ref[0, 0])
-        dv_sc[...] += jax.lax.dot_general(
+        p = _probs(q, k_ref[0, kh], keep, scale, lse_ref[0, 0])
+        tgt[...] += p
+        dv_ref[0, kh] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=F32)
-        dp = jax.lax.dot_general(do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, v_ref[0, kh], (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
         ds = p * (dp - delta_ref[0, 0]) * scale
-        dk_sc[...] += jax.lax.dot_general(
+        dk_ref[0, kh] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=F32)
 
-    @pl.when((gi == group - 1) & (qi == nq - 1))
+    @pl.when(live & last)
     def _():
-        dk_ref[0, 0] = dk_sc[...]
-        dv_ref[0, 0] = dv_sc[...]
+        keep = mask_ref[0].astype(jnp.int32) > 0
+        soft = jnp.where(keep, jnp.exp(sc_ref[0] - lsei_ref[0]), 0.0)
+        # the heads' mean: the division once a tile, not once a head
+        ds_ref[0] = soft - tgt[...] * (1.0 / heads) / z_ref[0]
+
+    @pl.when(jnp.logical_not(live) & last)
+    def _():
+        ds_ref[0] = jnp.zeros((bq, bk), F32)
 
 
-def sparse_attn_bwd(q, k, v, out, lse, do, mask, scale,
+def sparse_attn_bwd(q, k, v, out, lse, do, mask, scores, rows, scale,
                     block_q=512, block_k=512):
-    """(dq in q's dtype, dk, dv float32 summed over each key head's
-    query heads)."""
+    """(dq in q's dtype; dk, dv float32 summed over each key head's query
+    heads; the indexer's loss's gradient with respect to ``scores``
+    [b, s, s] float32, times the number of queries: softmax(scores) -
+    target / z over the kept keys, zeros elsewhere). ``rows`` = (z,
+    lse_i), each [b, s, 1], are ``indexer_loss``'s."""
     b, h, s, d = q.shape
     hk = k.shape[1]
     g = h // hk
@@ -411,61 +444,74 @@ def sparse_attn_bwd(q, k, v, out, lse, do, mask, scale,
         name="sparse_attn_bwd_dq",
     )(q, k, v, do, lse, delta, mask)
 
-    def q_map(bi, hki, kj, gi, q_i):
-        return (bi, hki * g + gi, jnp.maximum(q_i, first_q(kj)), 0)
+    # the heads innermost: a dead tile (above the diagonal) asks for the
+    # blocks of the key tile's first live step, so it fetches nothing
+    def live_q(kj, q_i):
+        return jnp.maximum(q_i, first_q(kj))
 
-    def k_map(bi, hki, kj, gi, q_i):
-        return (bi, hki, kj, 0)
+    def q_map(bi, kj, q_i, hi):
+        return (bi, jnp.where(q_i < first_q(kj), 0, hi), live_q(kj, q_i), 0)
 
-    dk, dv = pl.pallas_call(
+    def k_map(bi, kj, q_i, hi):
+        return (bi, 0, kj, 0)
+
+    def tile_map(bi, kj, q_i, hi):
+        return (bi, live_q(kj, q_i), kj)
+
+    def stat_map(bi, kj, q_i, hi):
+        return (bi, live_q(kj, q_i), 0)
+
+    dk, dv, d_scores = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          nq=nq, group=g),
-        grid=(b, hk, nk, g, nq),
+                          heads=h, group=g),
+        grid=(b, nk, nq, h),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_map),
-            pl.BlockSpec((1, 1, bk, d), k_map),
-            pl.BlockSpec((1, 1, bk, d), k_map),
+            pl.BlockSpec((1, hk, bk, d), k_map),
+            pl.BlockSpec((1, hk, bk, d), k_map),
             pl.BlockSpec((1, 1, bq, d), q_map),
             pl.BlockSpec((1, 1, bq, 1), q_map),
             pl.BlockSpec((1, 1, bq, 1), q_map),
-            pl.BlockSpec((1, bq, bk), lambda bi, hki, kj, gi, q_i:
-                         (bi, jnp.maximum(q_i, first_q(kj)), kj)),
+            pl.BlockSpec((1, bq, bk), tile_map),
+            pl.BlockSpec((1, bq, bk), tile_map),
+            pl.BlockSpec((1, bq, 1), stat_map),
+            pl.BlockSpec((1, bq, 1), stat_map),
         ],
-        out_specs=[pl.BlockSpec((1, 1, bk, d), k_map),
-                   pl.BlockSpec((1, 1, bk, d), k_map)],
+        out_specs=[pl.BlockSpec((1, hk, bk, d), k_map),
+                   pl.BlockSpec((1, hk, bk, d), k_map),
+                   pl.BlockSpec((1, bq, bk), lambda bi, kj, q_i, hi:
+                                (bi, q_i, kj))],
         out_shape=[jax.ShapeDtypeStruct((b, hk, s, d), F32),
-                   jax.ShapeDtypeStruct((b, hk, s, d), F32)],
-        scratch_shapes=[pltpu.VMEM((bk, d), F32), pltpu.VMEM((bk, d), F32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary", "arbitrary"),
+                   jax.ShapeDtypeStruct((b, hk, s, d), F32),
+                   jax.ShapeDtypeStruct((b, s, s), F32)],
+        scratch_shapes=[pltpu.VMEM((bq, bk), F32)],
+        # at 512 x 512 the float32 score and gradient tiles, the all-heads
+        # k / v / dk / dv blocks and the kernel's [bq, bk] temporaries
+        # pass the 16 MiB a kernel gets by default (the v5e has 128 MiB)
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary", vmem_limit_bytes=48 << 20),
         interpret=_interpret(),
         name="sparse_attn_bwd_dkv",
-    )(q, k, v, do, lse, delta, mask)
-    return dq, dk, dv
+    )(q, k, v, do, lse, delta, mask, scores, *rows)
+    return dq, dk, dv, d_scores
 
 
-def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, sc_ref, *rest,
-                 scale, bq, bk, heads, grad):
+def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, sc_ref,
+                 a_ref, z_ref, m_ref, l_ref, tgt, *, scale, bq, bk, heads):
     """One (query tile, key tile) of the indexer's loss, the heads in the
     innermost grid axis. The heads' mean probability under the mask (the
     target, each head's row summing to 1) gathers in VMEM; after the last
-    head the tile is folded, together with the indexer's scores, either
-    into four sums per row (``grad`` false: what the loss needs) or into
-    the loss's gradient with respect to the scores (``grad`` true)."""
-    if grad:
-        z_ref, lsei_ref, out_ref, tgt = rest
-    else:
-        a_ref, z_ref, m_ref, l_ref, tgt = rest
+    head the tile is folded, together with the indexer's scores, into
+    four sums per row: what the loss needs."""
     qi, kj, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     live = kj * bk <= qi * bq + bq - 1
 
-    if not grad:
-        @pl.when((kj == 0) & (hi == 0))
-        def _():
-            a_ref[0] = jnp.zeros((bq, 1), F32)
-            z_ref[0] = jnp.zeros((bq, 1), F32)
-            l_ref[0] = jnp.zeros((bq, 1), F32)
-            m_ref[0] = jnp.full((bq, 1), _NEG, F32)
+    @pl.when((kj == 0) & (hi == 0))
+    def _():
+        a_ref[0] = jnp.zeros((bq, 1), F32)
+        z_ref[0] = jnp.zeros((bq, 1), F32)
+        l_ref[0] = jnp.zeros((bq, 1), F32)
+        m_ref[0] = jnp.full((bq, 1), _NEG, F32)
 
     @pl.when(live & (hi == 0))
     def _():
@@ -481,37 +527,30 @@ def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, sc_ref, *rest,
     def _():
         keep = mask_ref[0].astype(jnp.int32) > 0
         t, sc = tgt[...], sc_ref[0]
-        if grad:
-            soft = jnp.where(keep, jnp.exp(sc - lsei_ref[0]), 0.0)
-            out_ref[0] = soft - t / z_ref[0]
-        else:
-            pos = keep & (t > 0.0)
-            a_ref[0] += jnp.sum(jnp.where(
-                pos, t * (jnp.log(jnp.where(pos, t, 1.0)) - sc), 0.0),
-                axis=1, keepdims=True)
-            z_ref[0] += jnp.sum(t, axis=1, keepdims=True)
-            scm = jnp.where(keep, sc, _NEG)
-            m_prev = m_ref[0]
-            m_new = jnp.maximum(m_prev, jnp.max(scm, axis=1, keepdims=True))
-            l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) + jnp.sum(
-                jnp.where(keep, jnp.exp(scm - m_new), 0.0), axis=1,
-                keepdims=True)
-            m_ref[0] = m_new
-
-    if grad:
-        @pl.when(jnp.logical_not(live) & (hi == heads - 1))
-        def _():
-            out_ref[0] = jnp.zeros((bq, bk), F32)
+        pos = keep & (t > 0.0)
+        a_ref[0] += jnp.sum(jnp.where(
+            pos, t * (jnp.log(jnp.where(pos, t, 1.0)) - sc), 0.0),
+            axis=1, keepdims=True)
+        z_ref[0] += jnp.sum(t, axis=1, keepdims=True)
+        scm = jnp.where(keep, sc, _NEG)
+        m_prev = m_ref[0]
+        m_new = jnp.maximum(m_prev, jnp.max(scm, axis=1, keepdims=True))
+        l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) + jnp.sum(
+            jnp.where(keep, jnp.exp(scm - m_new), 0.0), axis=1,
+            keepdims=True)
+        m_ref[0] = m_new
 
 
-def _loss_call(q, k, lse, mask, scores, rows, scale, block_q, block_k):
-    """The loss kernel's two forms: ``rows`` None gives the four sums per
-    row (a, z, m, l), each [b, s, 1]; ``rows`` = (z, lse_i) gives the
-    gradient [b, s, s]."""
+def indexer_loss(q, k, lse, mask, scores, scale, block_q=512, block_k=512):
+    """(the indexer's loss, (z, lse_i) per row, each [b, s, 1], for the
+    backward pass): the mean over the queries of KL(target || softmax of
+    the scores over the kept keys), the target being the heads' summed
+    probabilities of the main attention over the kept keys, normalised.
+    With the target's row sum z and the scores' row statistic lse_i, a
+    row's term is sum(t (log t - score)) / z - log z + lse_i."""
     b, h, s, d = q.shape
     g = h // k.shape[1]
     bq, bk, last_k, _ = _tiles(s, block_q, block_k)
-    grad = rows is not None
 
     def head_map(bi, q_i, kj, hi):
         return (bi, hi, q_i, 0)
@@ -520,54 +559,27 @@ def _loss_call(q, k, lse, mask, scores, rows, scale, block_q, block_k):
         return (bi, q_i, jnp.minimum(kj, last_k(q_i)))
 
     row_spec = pl.BlockSpec((1, bq, 1), lambda bi, q_i, kj, hi: (bi, q_i, 0))
-    row_shape = jax.ShapeDtypeStruct((b, s, 1), F32)
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, d), head_map),
-        pl.BlockSpec((1, 1, bk, d), lambda bi, q_i, kj, hi:
-                     (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)),
-        pl.BlockSpec((1, 1, bq, 1), head_map),
-        pl.BlockSpec((1, bq, bk), tile_map),
-        pl.BlockSpec((1, bq, bk), tile_map),
-    ]
-    if grad:
-        in_specs += [row_spec, row_spec]
-        out_specs = pl.BlockSpec((1, bq, bk),
-                                 lambda bi, q_i, kj, hi: (bi, q_i, kj))
-        out_shape = jax.ShapeDtypeStruct((b, s, s), F32)
-    else:
-        out_specs, out_shape = [row_spec] * 4, [row_shape] * 4
-    return pl.pallas_call(
-        functools.partial(_loss_kernel, scale=scale, bq=bq, bk=bk, heads=h,
-                          grad=grad),
+    a, z, m, l = pl.pallas_call(
+        functools.partial(_loss_kernel, scale=scale, bq=bq, bk=bk, heads=h),
         grid=(b, s // bq, s // bk, h),
-        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), head_map),
+            pl.BlockSpec((1, 1, bk, d), lambda bi, q_i, kj, hi:
+                         (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)),
+            pl.BlockSpec((1, 1, bq, 1), head_map),
+            pl.BlockSpec((1, bq, bk), tile_map),
+            pl.BlockSpec((1, bq, bk), tile_map),
+        ],
+        out_specs=[row_spec] * 4,
+        out_shape=[jax.ShapeDtypeStruct((b, s, 1), F32)] * 4,
         scratch_shapes=[pltpu.VMEM((bq, bk), F32)],
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         interpret=_interpret(),
-        name="indexer_loss_grad" if grad else "indexer_loss_rows",
-    )(q, k, lse, mask, scores, *(rows or ()))
-
-
-def indexer_loss(q, k, lse, mask, scores, scale, block_q=512, block_k=512):
-    """(the indexer's loss, (z, lse_i) per row for the backward pass):
-    the mean over the queries of KL(target || softmax of the scores over
-    the kept keys), the target being the heads' summed probabilities of
-    the main attention over the kept keys, normalised. With the target's
-    row sum z and the scores' row statistic lse_i, a row's term is
-    sum(t (log t - score)) / z - log z + lse_i."""
-    a, z, m, l = _loss_call(q, k, lse, mask, scores, None, scale,
-                            block_q, block_k)
+        name="indexer_loss_rows",
+    )(q, k, lse, mask, scores)
     lse_i = m + jnp.log(l)
     return jnp.mean(a / z - jnp.log(z) + lse_i), (z, lse_i)
-
-
-def indexer_loss_grad(q, k, lse, mask, scores, rows, scale,
-                      block_q=512, block_k=512):
-    """The loss's gradient with respect to the scores, times the number
-    of queries: softmax(scores) - target / z over the kept keys."""
-    return _loss_call(q, k, lse, mask, scores, rows, scale, block_q,
-                      block_k)
 
 
 def sparse_attention_reference(q, k, v, mask, scale):
@@ -630,19 +642,19 @@ def _lsa_fwd(q, k, v, qi, ki, w, topk, scale):
 
 def _lsa_bwd(topk, scale, res, cts):
     d_out, d_loss = cts
+    telemetry.default_tracer().metrics.inc("attn.sparse.target_in_backward")
     # the scores are made again from the same operands as in the forward
     # pass: behind a barrier, or XLA merges the two calls and keeps the
     # forward's float32 [seq, seq] array alive
     q, k, v, qi, ki, w, out, lse, mask, z, lse_i, d_out = \
         jax.lax.optimization_barrier((*res, d_out))
-    lse = lse[..., None]
-    with jax.named_scope("sparse_attn"):
-        dq, dk, dv = sparse_attn_bwd(q, k, v, out, lse,
-                                     d_out.astype(q.dtype), mask, scale)
     with jax.named_scope("indexer"):
         scores = indexer_scores(qi, ki, w)
-        d_scores = indexer_loss_grad(q, k, lse, mask, scores,
-                                     (z[..., None], lse_i[..., None]), scale)
+    with jax.named_scope("sparse_attn"):
+        dq, dk, dv, d_scores = sparse_attn_bwd(
+            q, k, v, out, lse[..., None], d_out.astype(q.dtype), mask,
+            scores, (z[..., None], lse_i[..., None]), scale)
+    with jax.named_scope("indexer"):
         coef = d_loss / (scores.shape[0] * scores.shape[1])
         dqi, dki, dw = (g * coef.astype(g.dtype) for g in
                         _indexer_scores_bwd(qi, ki, w, d_scores))

@@ -13,6 +13,8 @@ KV) off the kernels.
 All of it lives in this one file: the worker that runs it loads the TPU
 library and keeps it until it exits.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -210,17 +212,30 @@ def test_mistral_cell_whole_step_recomputes_nothing(one_chip, on_chip):
 
 def test_keye_cell_whole_step(one_chip, on_chip):
     """The Keye cell's whole step (4 layers, one chip's 16 of 128 experts,
-    batch 2 x 8192): 18.813 TFLOP of XLA's own operations (the twelve
-    Pallas kernels a layer count for nothing there), 14.11 GB of the
-    chip's 16.9, all 67 leaves' gradients under the barrier, nothing
-    recomputed."""
+    batch 2 x 8192): 18.813 TFLOP of XLA's own operations (the Pallas
+    kernels, seven a layer, count for nothing there), 13.18 GB of the
+    chip's 16.9 (14.11 while the loss's gradient with respect to the
+    scores had a kernel of its own, ``indexer_loss_grad``: the backward
+    pass of a layer now has one kernel fewer to keep operands for), all
+    67 leaves' gradients under the barrier, nothing recomputed."""
+    from paddle_tpu.utils import telemetry
+    metrics = telemetry.default_tracer().metrics
+    folded = metrics.value("attn.sparse.target_in_backward") or 0
     compiled, nbytes, leaves = _cell_step_compiled(
         one_chip, "lm_keye_vl2", "keye_vl2_30b_a3b_ep8_l4_train",
         "train_b2_s8192")
-    assert ".remat" not in compiled.as_text()
+    text = compiled.as_text()
+    assert ".remat" not in text
+    # every layer's backward pass gathers the indexer's target inside the
+    # dk / dv kernel: seven kernels a layer, none for the loss's gradient
+    assert metrics.value("attn.sparse.target_in_backward") == folded + 4
+    assert "%indexer_loss_grad" not in text
+    assert len(re.findall(
+        r"%(indexer_scores|topk_select|sparse_attn_fwd|indexer_loss_rows|"
+        r"sparse_attn_bwd_dq|sparse_attn_bwd_dkv)[.\d]* = ", text)) == 28
     assert compiled.cost_analysis()["flops"] == pytest.approx(18.813e12,
                                                               rel=0.01)
-    assert nbytes == pytest.approx(14.11e9, rel=0.02)
+    assert nbytes == pytest.approx(13.18e9, rel=0.02)
     assert leaves == 67
 
 
@@ -250,9 +265,10 @@ def _keye_args(sh):
     ("topk_select", ("scores",), 1),
     ("sparse_attn_fwd", ("q", "kv", "kv", "mask"), 1),
     ("indexer_loss_rows", ("q", "kv", "lse", "mask", "scores"), 1),
-    ("indexer_loss_grad", ("q", "kv", "lse", "mask", "scores", "row",
-                           "row"), 1),
-    ("sparse_attn_bwd", ("q", "kv", "kv", "q", "lse", "q", "mask"), 2),
+    # dq, and dkv with the indexer's target and the loss's gradient folded
+    # in: at 512 x 512 it needs more VMEM than a kernel gets by default
+    ("sparse_attn_bwd", ("q", "kv", "kv", "q", "lse", "q", "mask", "scores",
+                         "row", "row"), 2),
 ])
 def test_learned_sparse_attention_kernels_keye_widths(one_chip, on_chip,
                                                       kernel, operands, n):
@@ -263,13 +279,36 @@ def test_learned_sparse_attention_kernels_keye_widths(one_chip, on_chip,
           "topk_select": lambda x: sa.topk_select(x, KEYE["topk"]),
           "sparse_attn_fwd": lambda *x: sa.sparse_attn_fwd(*x, scale),
           "indexer_loss_rows": lambda *x: sa.indexer_loss(*x, scale)[0],
-          "indexer_loss_grad": lambda q, kv, lse, mask, sc, z, li:
-          sa.indexer_loss_grad(q, kv, lse, mask, sc, (z, li), scale),
-          "sparse_attn_bwd": lambda *x: sa.sparse_attn_bwd(*x, scale)}[kernel]
+          "sparse_attn_bwd": lambda *x: sa.sparse_attn_bwd(
+              *x[:-2], x[-2:], scale)}[kernel]
     text = jax.jit(fn).lower(*[a[o] for o in operands]).compile().as_text()
     assert text.count("tpu_custom_call") == n
     # the device trace tells the kernels apart by these names
-    assert f"%{kernel}" in text
+    names = ("sparse_attn_bwd_dq", "sparse_attn_bwd_dkv") \
+        if kernel == "sparse_attn_bwd" else (kernel,)
+    for name in names:
+        assert f"%{name}" in text
+
+
+def test_learned_sparse_attention_whole_vjp_keye_widths(one_chip, on_chip):
+    """The differentiable whole under ``jax.vjp``: seven kernels (scores,
+    selection, attention, the loss's rows; scores again, dq, dkv with the
+    target) and none for the loss's gradient alone."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    a = _keye_args(one_chip)
+    scale = KEYE["d"] ** -0.5
+
+    def both(q, k, v, qi, ki, w, d_out):
+        (out, loss), vjp = jax.vjp(
+            lambda *x: sa.learned_sparse_attention(*x, KEYE["topk"], scale),
+            q, k, v, qi, ki, w)
+        return loss, vjp((d_out, jnp.ones_like(loss)))
+
+    text = jax.jit(both).lower(*[a[o] for o in (
+        "q", "kv", "kv", "qi", "ki", "w", "q")]).compile().as_text()
+    assert text.count("tpu_custom_call") == 7
+    assert "%sparse_attn_bwd_dkv" in text
+    assert "%indexer_loss_grad" not in text
 
 
 def test_gate_refuses_head_dim_64(on_chip):

@@ -75,27 +75,70 @@ def test_selection_is_the_exact_topk_ties_to_the_lower_index(operands, ties):
     assert (kernel.sum(-1) == want[None]).all()
 
 
-def test_attention_kernels_forward_target_and_backward(operands):
-    q, k, v, qi, ki, w, do = operands
-    mask = sa.topk_select(sa.indexer_scores_reference(qi, ki, w), TOPK)
-    out, lse = sa.sparse_attn_fwd(q, k, v, mask, SCALE, 32, 32)
+@pytest.mark.parametrize("h,hk,bq,bk,topk", [
+    (4, 2, 32, 32, 16),         # two query heads a key head, square tiles
+    (4, 2, 16, 32, 16),         # a query block shorter than the key block
+    (4, 2, 32, 16, 16),         # and longer: two key tiles on the diagonal
+    (4, 4, 32, 32, 16),         # a group of 1
+    (8, 1, 32, 32, 16),         # a group of 8
+    (4, 2, 16, 16, 16),         # the first row of tiles keeps every causal
+                                # key (t < topk), the others do not
+    (3, 1, 32, 32, 24),         # heads that are no power of two
+])
+def test_attention_kernels_forward_target_and_backward(h, hk, bq, bk, topk):
+    ks = jax.random.split(jax.random.PRNGKey(h * 100 + hk), 7)
+    q, do = (jax.random.normal(k, (B, h, S, D)) for k in ks[:2])
+    k, v = (jax.random.normal(k, (B, hk, S, D)) for k in ks[2:4])
+    qi, ki, w = (jax.random.normal(k, s) for k, s in zip(
+        ks[4:], [(B, HI, S, DI), (B, S, DI), (B, S, HI)]))
+    scores = sa.indexer_scores_reference(qi, ki, w)
+    mask = sa.topk_select(scores, topk)
+    out, lse = sa.sparse_attn_fwd(q, k, v, mask, SCALE, bq, bk)
     want, p = sa.sparse_attention_reference(q, k, v, mask, SCALE)
     assert close(out, want)
-    # the indexer's loss and its gradient w.r.t. the scores, by the two
-    # forms of the loss kernel
-    scores = sa.indexer_scores_reference(qi, ki, w)
-    loss, rows = sa.indexer_loss(q, k, lse, mask, scores, SCALE, 32, 32)
+    # the indexer's loss by its kernel; its gradient w.r.t. the scores is
+    # the fused backward's fourth output
+    loss, rows = sa.indexer_loss(q, k, lse, mask, scores, SCALE, bq, bk)
     want_loss, want_grad = jax.value_and_grad(sa._indexer_kl)(
         scores, p.mean(1), mask)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
-    grad = sa.indexer_loss_grad(q, k, lse, mask, scores, rows, SCALE, 32, 32)
-    assert close(grad / (B * S), want_grad, 1e-5)
-    got = sa.sparse_attn_bwd(q, k, v, out, lse, do, mask, SCALE, 32, 32)
+    *got, d_scores = sa.sparse_attn_bwd(q, k, v, out, lse, do, mask, scores,
+                                        rows, SCALE, bq, bk)
+    assert close(d_scores / (B * S), want_grad, 1e-5)
+    # nothing outside the selection, the tiles above the diagonal included
+    assert float(jnp.abs(jnp.where(mask > 0, 0.0, d_scores)).max()) == 0.0
     grads = jax.grad(lambda *a: jnp.sum(
         sa.sparse_attention_reference(*a, mask, SCALE)[0] * do),
         argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, grads):
         assert close(a, b)
+
+
+def test_the_backward_pass_counts_the_target_it_folds_in(operands):
+    """One ``attn.sparse.target_in_backward`` a traced layer's backward
+    pass, none for a forward pass alone; and the backward pass is three
+    kernels (scores, dq, dkv with the target), no fourth for the loss's
+    gradient."""
+    from paddle_tpu.utils import telemetry
+    q, k, v, qi, ki, w, do = operands
+    reg = telemetry.default_tracer().metrics
+    count = lambda: reg.value("attn.sparse.target_in_backward") or 0
+
+    def loss(*a):
+        o, li = sa.learned_sparse_attention(*a, TOPK, SCALE)
+        return jnp.sum(o * do) + li
+    before = count()
+    jax.make_jaxpr(loss)(q, k, v, qi, ki, w)
+    assert count() == before
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(6))))(
+        q, k, v, qi, ki, w))
+    assert count() == before + 1
+    # forward: scores, selection, attention, rows; backward: the three
+    assert text.count("pallas_call[") == 7
+    for name in ("sparse_attn_bwd_dq", "sparse_attn_bwd_dkv",
+                 "indexer_loss_rows"):
+        assert text.count(f"name={name}") == 1
+    assert "indexer_loss_grad" not in text
 
 
 def _both(operands, topk):
