@@ -12,6 +12,9 @@ from .moe_lm import (  # noqa: F401
 from .keye_vl2 import (  # noqa: F401
     KeyeVL2Config, KeyeVL2ForCausalLM, KeyeVL2Model, keye_vl2_tiny,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel, lfm2_moe_tiny,
+)
 from .dit import (  # noqa: F401
     DiT, DiTConfig, dit_tiny, dit_s_2, dit_xl_2,
 )

@@ -238,10 +238,8 @@ class KeyeVL2ForCausalLM(nn.Layer):
     def routing_counts(self) -> dict:
         """The layers' expert counters summed (``rows_max_expert``: the
         busiest single expert of any layer)."""
-        counts = [layer.mlp.routing_counts() for layer in self.model.layers]
-        return {"rows_held": sum(c["rows_held"] for c in counts),
-                "rows_max_expert": max(c["rows_max_expert"] for c in counts),
-                "rows_routed": sum(c["rows_routed"] for c in counts)}
+        return nn.MoEShareLayer.summed_counts(
+            layer.mlp for layer in self.model.layers)
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

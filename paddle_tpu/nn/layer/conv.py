@@ -10,7 +10,7 @@ from .. import initializer as I
 from .layers import Layer
 
 __all__ = ["Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose", "Conv2DTranspose",
-           "Conv3DTranspose"]
+           "Conv3DTranspose", "GatedShortConv"]
 
 
 def _t(v, n):
@@ -144,3 +144,29 @@ class Conv3DTranspose(_ConvNd):
                                   self.padding, self.output_padding,
                                   self.groups, self.dilation, output_size,
                                   self.data_format)
+
+
+class GatedShortConv(Layer):
+    """The gated short convolution of the LFM2 models over x
+    [batch, seq, hidden]: ``[B, C, X] = in_proj(x)``, a depthwise causal
+    convolution of ``kernel_size`` taps over ``B * X`` along the sequence
+    (zeros before each sequence's first token), gated by ``C``, then
+    ``out_proj``; no biases (``ops.short_conv``). ``conv_weight`` is
+    [kernel_size, hidden]: tap j weighs the token ``kernel_size - 1 - j``
+    places back."""
+
+    def __init__(self, hidden_size: int, kernel_size: int = 3, dtype=None):
+        super().__init__(dtype=dtype)
+        from .common import Linear
+        self.in_proj = Linear(hidden_size, 3 * hidden_size, bias_attr=False)
+        self.conv_weight = self.create_parameter((kernel_size, hidden_size))
+        self.out_proj = Linear(hidden_size, hidden_size, bias_attr=False)
+
+    def forward(self, x):
+        from ...framework.core import apply
+        from ...ops.short_conv import gated_short_conv
+        from ...utils import telemetry
+        telemetry.default_tracer().metrics.inc("short_conv.layers")
+        return apply("gated_short_conv", gated_short_conv, x,
+                     self.in_proj.weight, self.conv_weight,
+                     self.out_proj.weight)

@@ -6,6 +6,7 @@ emits the token all-to-all the reference does manually with
 global_scatter/global_gather."""
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -176,9 +177,17 @@ class MoEShareLayer(Layer):
     over ``count`` holders; this layer has the parameters of experts
     ``index * num_experts // count`` onward, ``num_experts // count`` of
     them, each ``w_down(silu(w_gate x) * w_up x)``. The router
-    (``gate_weight`` [d_model, num_experts], softmax in float32, the
-    ``top_k`` largest, divided by their sum when ``norm_topk_prob``) is
-    whole on every share. ``forward`` returns the sum over a token's
+    (``gate_weight`` [d_model, num_experts]) is whole on every share and
+    follows one of two published rules. ``score_func="softmax"``
+    (Keye-VL-2.0, Qwen3-MoE): softmax in float32, the ``top_k`` largest,
+    divided by their sum when ``norm_topk_prob``. ``"sigmoid"`` (LFM2-MoE,
+    DeepSeek-V3): ``s = sigmoid(logits)`` in float32; with
+    ``expert_bias=True`` the ``top_k`` largest of ``s + expert_bias`` are
+    chosen, ``expert_bias`` [num_experts] a float32 buffer that starts at
+    zero and takes no gradient (what balances the load writes it; nothing
+    here does); the weights are ``s`` at the chosen experts over their sum
+    + 1e-6 when ``norm_topk_prob``, times ``routed_scaling_factor``.
+    ``forward`` returns the sum over a token's
     chosen experts that are held here; what the other shares hold is
     theirs to add, which under expert parallelism is the exchange and on
     a single share is left out. ``share=(0, 1)`` is the whole layer. No
@@ -196,12 +205,23 @@ class MoEShareLayer(Layer):
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  top_k: int, share=(0, 1), norm_topk_prob: bool = True,
-                 dtype=None):
+                 dtype=None, score_func: str = "softmax",
+                 expert_bias: bool = False,
+                 routed_scaling_factor: float = 1.0):
         super().__init__(dtype=dtype)
         index, count = share
         if num_experts % count or not 0 <= index < count:
             raise ValueError(f"share {share!r} does not divide "
                              f"{num_experts} experts evenly")
+        if score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"score_func {score_func!r}; there are: "
+                             "softmax, sigmoid")
+        if score_func == "softmax" and (expert_bias
+                                        or routed_scaling_factor != 1.0):
+            raise ValueError("the softmax rule has no selection bias and "
+                             "no scaling factor")
+        self.score_func = score_func
+        self.routed_scaling_factor = float(routed_scaling_factor)
         self.num_experts, self.top_k = num_experts, top_k
         self.num_held = num_experts // count
         self.first_expert = index * self.num_held
@@ -214,25 +234,38 @@ class MoEShareLayer(Layer):
             (self.num_held, d_hidden, d_model))
         self.register_buffer(
             "rows", Tensor(jnp.zeros((2, self.num_held + 1), jnp.int32)))
+        if expert_bias:
+            self.register_buffer(
+                "expert_bias", Tensor(jnp.zeros((num_experts,), jnp.float32)))
+        else:
+            self.expert_bias = None
 
     def compute(self, x):
         """(out, the counters this call adds) with the buffer untouched:
         for a caller that runs the layer inside a rematerialised region
         and counts outside it (``count``)."""
-        from ...ops.moe import moe_share_forward
+        from ...ops import moe
         from ...utils import telemetry
-        telemetry.default_tracer().metrics.inc("moe.dispatch.share_ragged")
+        metrics = telemetry.default_tracer().metrics
+        metrics.inc("moe.dispatch.share_ragged")
+        if self.expert_bias is not None:
+            metrics.inc("moe.route.sigmoid_bias")
         routed = x.shape[0] * x.shape[1] * self.top_k
 
-        def f(xa, gw, wg, wu, wd):
-            out, rows = moe_share_forward(
+        def f(xa, gw, wg, wu, wd, bias=None):
+            route = moe.route_softmax if self.score_func == "softmax" \
+                else functools.partial(
+                    moe.route_sigmoid, expert_bias=bias,
+                    scaling=self.routed_scaling_factor)
+            out, rows = moe.moe_share_forward(
                 xa, gw, wg, wu, wd, self.top_k, self.first_expert,
-                self.norm_topk_prob)
+                self.norm_topk_prob, route)
             return out, jnp.concatenate(
                 [rows, jnp.full((1,), routed, jnp.int32)])
 
+        bias = () if self.expert_bias is None else (self.expert_bias,)
         return apply("moe_share", f, x, self.gate_weight, self.w_gate,
-                     self.w_up, self.w_down)
+                     self.w_up, self.w_down, *bias)
 
     def count(self, seen):
         low, high = self.rows._value
@@ -255,3 +288,12 @@ class MoEShareLayer(Layer):
         return {"rows_held": sum(rows[:-1]),
                 "rows_max_expert": max(rows[:-1]),
                 "rows_routed": rows[-1]}
+
+    @staticmethod
+    def summed_counts(layers) -> dict:
+        """``routing_counts`` of a model's expert layers together
+        (``rows_max_expert``: the busiest single expert of any layer)."""
+        counts = [layer.routing_counts() for layer in layers]
+        return {"rows_held": sum(c["rows_held"] for c in counts),
+                "rows_max_expert": max(c["rows_max_expert"] for c in counts),
+                "rows_routed": sum(c["rows_routed"] for c in counts)}

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["topk_gating", "moe_dispatch_combine", "moe_mlp_forward",
-           "moe_ragged_forward", "moe_share_forward"]
+           "moe_ragged_forward", "moe_share_forward", "route_softmax",
+           "route_sigmoid"]
 
 
 def topk_gating(logits, top_k: int, capacity: int):
@@ -188,14 +189,47 @@ def moe_ragged_forward(x, gate_w, w1, w2, top_k: int,
     return out.reshape(b, s, d).astype(x.dtype), aux_loss, stats
 
 
+def route_softmax(logits, top_k: int, norm_topk_prob: bool = True):
+    """Keye-VL-2.0's (Qwen3-MoE's) routing rule: softmax over ALL the
+    float32 ``logits`` [T, E], the ``top_k`` largest, their weights
+    divided by their sum when ``norm_topk_prob``. -> (top_i [T, k],
+    gates [T, k])."""
+    probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
+    top_p, top_i = jax.lax.top_k(probs, top_k)                 # [T, k]
+    gates = top_p / jnp.sum(top_p, -1, keepdims=True) \
+        if norm_topk_prob else top_p
+    return top_i, gates
+
+
+def route_sigmoid(logits, top_k: int, norm_topk_prob: bool = True,
+                  expert_bias=None, scaling: float = 1.0):
+    """LFM2-MoE's (DeepSeek-V3's) routing rule: ``s = sigmoid(logits)``
+    in float32; the ``top_k`` largest of ``s + expert_bias`` are CHOSEN
+    (the bias [E] steers the selection and takes no gradient; ``None`` is
+    no bias); the weights are ``s`` at the chosen experts, divided by
+    their sum + 1e-6 when ``norm_topk_prob``, times ``scaling``. ->
+    (top_i [T, k], gates [T, k])."""
+    s = jax.nn.sigmoid(logits)                                 # [T, E]
+    pick = s if expert_bias is None else \
+        s + jax.lax.stop_gradient(expert_bias.astype(s.dtype))
+    _, top_i = jax.lax.top_k(pick, top_k)                      # [T, k]
+    top_s = jnp.take_along_axis(s, top_i, axis=-1)
+    if norm_topk_prob:
+        top_s = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-6)
+    return top_i, top_s * scaling
+
+
 def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
-                      first_expert: int = 0, norm_topk_prob: bool = True):
+                      first_expert: int = 0, norm_topk_prob: bool = True,
+                      route=route_softmax):
     """One share's part of a gated-expert layer: x [B, S, D] ->
     (out [B, S, D], rows [E_held] int32).
 
-    ``gate_w`` [D, E] routes over ALL E experts (softmax in float32, the
-    ``top_k`` largest, their weights divided by their sum when
-    ``norm_topk_prob``); ``w_gate`` / ``w_up`` [E_held, D, H] and
+    ``gate_w`` [D, E] gives every token its float32 logits over ALL E
+    experts; ``route(logits, top_k, norm_topk_prob)`` turns them into the
+    token's ``top_k`` experts and their weights (``route_softmax``, or
+    ``route_sigmoid`` with its bias and scaling bound by
+    ``functools.partial``); ``w_gate`` / ``w_up`` [E_held, D, H] and
     ``w_down`` [E_held, H, D] are the experts ``first_expert ..
     first_expert + E_held - 1`` that live here, each computing
     ``w_down(silu(w_gate x) * w_up x)``. ``out`` is the sum over a
@@ -222,10 +256,7 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
     n_rows = t * top_k
 
     logits = tokens.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
-    top_p, top_i = jax.lax.top_k(probs, top_k)                 # [T, k]
-    gates = top_p / jnp.sum(top_p, -1, keepdims=True) \
-        if norm_topk_prob else top_p
+    top_i, gates = route(logits, top_k, norm_topk_prob)
 
     local = top_i.reshape(n_rows) - first_expert
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)

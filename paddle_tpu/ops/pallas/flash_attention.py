@@ -19,8 +19,8 @@ mismatched ids are fully masked; fully-masked query rows produce zero
 output (guarded online softmax, not NaN).
 
 Layout contract (paddle convention at the API): q/k/v [batch, seq, heads,
-head_dim]; kernels internally run [batch, heads, seq, head_dim]. head_dim
-should be a multiple of 128 for MXU efficiency (64 works, half-utilized).
+head_dim]; kernels internally run [batch, heads, seq, head_dim]. On the
+v5e head_dim 64 compiles and reads 21.5% of peak, 128 reads 38.9% (PR 34).
 
 VMEM budget: K and V are held whole per (batch, kv-head) — fine up to
 seq*dim*2B*2 ≈ 8MB (seq 16k @ d=128 bf16). Longer sequences belong to ring

@@ -104,6 +104,31 @@ def test_flash_attention_fwd_bwd_llama_mid(one_chip, on_chip):
     assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
 
 
+def test_flash_attention_fwd_bwd_head_dim_64(one_chip, on_chip):
+    """LFM2-8B-A1B's attention at the benchmark cell's shape: 32 q / 8 kv
+    heads of 64, 2 x 8192. The chip's compiler takes all three kernels at
+    a head of 64 (the PAGED kernel's gate on 64 stands: below); the
+    backward pass walks the 8192 positions in 2048 x 2048 pairs, ten
+    under the diagonal, so dq and dk/dv are ten calls each."""
+    from paddle_tpu.ops.flash_attention import flash_attention
+    q = _sds(one_chip, (2, 8192, 32, 64), BF16)
+    kv = _sds(one_chip, (2, 8192, 8, 64), BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=64 ** -0.5) \
+            .astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    # alone under jax.grad the instructions are %jvp_flash_fwd_.1,
+    # %transpose_jvp_flash_bwd_dq__.10, ...
+    calls = {name: len(re.findall(rf"{name}[_.\d]* = ", text))
+             for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 10,
+                     "flash_bwd_dkv": 10}
+    assert text.count("tpu_custom_call") == 21
+
+
 @pytest.mark.parametrize("k,n", [(4096, 14336), (4096, 128256)])
 def test_decode_matmul_int4(one_chip, on_chip, k, n):
     from paddle_tpu.ops.pallas.decode_matmul import (decode_matmul,
@@ -237,6 +262,30 @@ def test_keye_cell_whole_step(one_chip, on_chip):
                                                               rel=0.01)
     assert nbytes == pytest.approx(13.18e9, rel=0.02)
     assert leaves == 67
+
+
+def test_lfm2_cell_whole_step(one_chip, on_chip):
+    """The LFM2 cell's whole step (published layers 1-5, one chip's 8 of
+    32 experts, batch 2 x 8192): every layer's kind in one program, the
+    flash kernels at a head of 64, 48 grouped matmuls of the four expert
+    layers (three a chunk forward, nine in its vjp), all 49 leaves'
+    gradients under the barrier, nothing recomputed. XLA's count takes a
+    ``ragged-dot`` for its whole 32,768-row chunk and has the vjp's second
+    forward pass: 26.90 TFLOP where the model's count is 21.2."""
+    compiled, nbytes, leaves = _cell_step_compiled(
+        one_chip, "lm_lfm2_moe", "lfm2_8b_a1b_ep4_l5_train",
+        "train_b2_s8192")
+    text = compiled.as_text()
+    assert ".remat" not in text
+    calls = {name: len(re.findall(rf"%{name}[.\d]* = ", text))
+             for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                          "ragged-dot-none")}
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 10,
+                     "flash_bwd_dkv": 10, "ragged-dot-none": 48}
+    assert compiled.cost_analysis()["flops"] == pytest.approx(26.90e12,
+                                                              rel=0.01)
+    assert nbytes == pytest.approx(15.13e9, rel=0.02) and nbytes < 15.5e9
+    assert leaves == 49
 
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------
