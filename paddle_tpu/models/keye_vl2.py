@@ -217,7 +217,8 @@ class KeyeVL2ForCausalLM(nn.Layer):
         self.model = KeyeVL2Model(cfg)
         self.lm_head = make_lm_head(cfg.hidden_size, cfg.vocab_size)
         # the default registry's snapshot() asks for the experts' counters
-        # (moe.rows_held, moe.rows_max_expert, moe.rows_routed)
+        # (moe.rows_held, moe.rows_max_expert, moe.rows_walked,
+        # moe.rows_routed)
         telemetry.default_tracer().metrics.add_source(
             "moe", weakref.WeakMethod(self.routing_counts))
 

@@ -237,7 +237,8 @@ class Lfm2MoeForCausalLM(nn.Layer):
         self.lm_head = make_lm_head(cfg.hidden_size, cfg.vocab_size,
                                     tied=True)
         # the default registry's snapshot() asks for the experts' counters
-        # (moe.rows_held, moe.rows_max_expert, moe.rows_routed)
+        # (moe.rows_held, moe.rows_max_expert, moe.rows_walked,
+        # moe.rows_routed)
         telemetry.default_tracer().metrics.add_source(
             "moe", weakref.WeakMethod(self.routing_counts))
 

@@ -193,9 +193,13 @@ class MoEShareLayer(Layer):
     a single share is left out. ``share=(0, 1)`` is the whole layer. No
     token is dropped (``ops.moe.moe_share_forward``).
 
-    ``rows`` is a buffer of ``num_held + 1`` counters that every forward
-    adds to: the rows each held expert computed, then all the (token,
-    choice) rows routed anywhere. A counter is two int32 words, the low
+    ``rows`` is a buffer of ``num_held + 2`` counters that every forward
+    adds to: the rows each held expert computed, then the rows the
+    layer's gathers and scatter-adds walked to reach them (whole blocks
+    of the sorted rows up to the last held one, so a little more than the
+    held rows and, in a chunk sized for twice an even share, about half
+    the chunk), then all the (token, choice) rows routed anywhere. A
+    counter is two int32 words, the low
     30 bits and the carries out of them (at 2 x 8192 tokens and top-8 one
     word would wrap after 16,384 steps); a call adds fewer than 2**30
     rows. ``jit.TrainStep`` threads the buffer through the compiled step
@@ -233,7 +237,7 @@ class MoEShareLayer(Layer):
         self.w_down = self.create_parameter(
             (self.num_held, d_hidden, d_model))
         self.register_buffer(
-            "rows", Tensor(jnp.zeros((2, self.num_held + 1), jnp.int32)))
+            "rows", Tensor(jnp.zeros((2, self.num_held + 2), jnp.int32)))
         if expert_bias:
             self.register_buffer(
                 "expert_bias", Tensor(jnp.zeros((num_experts,), jnp.float32)))
@@ -257,11 +261,11 @@ class MoEShareLayer(Layer):
                 else functools.partial(
                     moe.route_sigmoid, expert_bias=bias,
                     scaling=self.routed_scaling_factor)
-            out, rows = moe.moe_share_forward(
+            out, rows, walked = moe.moe_share_forward(
                 xa, gw, wg, wu, wd, self.top_k, self.first_expert,
                 self.norm_topk_prob, route)
             return out, jnp.concatenate(
-                [rows, jnp.full((1,), routed, jnp.int32)])
+                [rows, walked[None], jnp.full((1,), routed, jnp.int32)])
 
         bias = () if self.expert_bias is None else (self.expert_bias,)
         return apply("moe_share", f, x, self.gate_weight, self.w_gate,
@@ -280,20 +284,20 @@ class MoEShareLayer(Layer):
         return out
 
     def routing_counts(self) -> dict:
-        """{"rows_held", "rows_max_expert", "rows_routed"} since the layer
-        was built (a read of the buffer: it waits for the device)."""
+        """{"rows_held", "rows_max_expert", "rows_walked", "rows_routed"}
+        since the layer was built (a read of the buffer: it waits for the
+        device)."""
         import numpy as np
         low, high = np.asarray(self.rows._value).tolist()
-        rows = [lo + (hi << self._LOW_BITS) for lo, hi in zip(low, high)]
-        return {"rows_held": sum(rows[:-1]),
-                "rows_max_expert": max(rows[:-1]),
-                "rows_routed": rows[-1]}
+        *held, walked, routed = [lo + (hi << self._LOW_BITS)
+                                 for lo, hi in zip(low, high)]
+        return {"rows_held": sum(held), "rows_max_expert": max(held),
+                "rows_walked": walked, "rows_routed": routed}
 
     @staticmethod
     def summed_counts(layers) -> dict:
         """``routing_counts`` of a model's expert layers together
         (``rows_max_expert``: the busiest single expert of any layer)."""
         counts = [layer.routing_counts() for layer in layers]
-        return {"rows_held": sum(c["rows_held"] for c in counts),
-                "rows_max_expert": max(c["rows_max_expert"] for c in counts),
-                "rows_routed": sum(c["rows_routed"] for c in counts)}
+        return {name: (max if name == "rows_max_expert" else sum)(
+            c[name] for c in counts) for name in counts[0]}

@@ -223,7 +223,7 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
                       first_expert: int = 0, norm_topk_prob: bool = True,
                       route=route_softmax):
     """One share's part of a gated-expert layer: x [B, S, D] ->
-    (out [B, S, D], rows [E_held] int32).
+    (out [B, S, D], rows [E_held] int32, walked int32).
 
     ``gate_w`` [D, E] gives every token its float32 logits over ALL E
     experts; ``route(logits, top_k, norm_topk_prob)`` turns them into the
@@ -236,18 +236,25 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
     token's chosen experts THAT ARE HELD of weight * expert(x): what the
     other shares hold is theirs to add (expert parallelism's exchange,
     or nothing on a single share). ``rows`` counts the rows each held
-    expert computed.
+    expert computed, ``walked`` the rows the layer's row movement went
+    over to reach them (whole blocks: at least ``sum(rows)``).
 
     Dropless at static shapes: the token-to-expert assignments are
     sorted with the held experts first, and the sorted rows are walked
     in chunks of twice an even routing's share (rounded up to 16 rows: at
     1.25 times the share a layer's held rows crossed the chunk's end from
     step to step and a step's time with them). A chunk that holds no held
-    row is skipped by ``lax.cond``, so the work follows the rows that are
-    there while every row up to the worst case (all T * top_k) has its
-    chunk. Each chunk is three ``lax.ragged_dot`` over its rows, made
-    again in the backward pass (``_share_experts``): nothing of size
-    rows x width is kept.
+    row is skipped by ``lax.cond``, so every row up to the worst case
+    (all T * top_k) has its chunk while an even routing runs one, half
+    full by design. The chunk is what bounds the memory and what the
+    weight gradients' float32 accumulators are paid once for, so it stays
+    that large; inside it the work follows the rows that are there: the
+    three ``lax.ragged_dot`` by their group sizes, and the gather of the
+    tokens' rows and the scatter-add of the products by walking the chunk
+    in blocks of ``_ROW_BLOCK`` rows only as far as the held rows reach
+    (``_take_rows``, ``_add_rows``). Each chunk's products are made again
+    in the backward pass (``_share_experts``): nothing of size rows x
+    width is kept.
     """
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
@@ -272,60 +279,119 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
                     constant_values=n_rows - 1)
     out = _share_experts(tokens, gates.reshape(n_rows), w_gate, w_up,
                          w_down, order, sizes, top_k, row_chunk)
-    return out.reshape(b, s, d).astype(x.dtype), sizes
+    _, lives, block = _chunks(order, sizes, row_chunk)
+    walked = block * jnp.sum(_live_blocks(lives, block))
+    return out.reshape(b, s, d).astype(x.dtype), sizes, walked
 
 
-def _share_chunk(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
-                 lo, top_k, row_chunk):
-    """What the sorted rows lo .. lo + row_chunk add to every token's
-    output, [T, D]."""
-    t, d = tokens.shape
+# Rows a step of the share's row movement gathers or scatter-adds. On the
+# chip (one layer at [16384, 2048], a 32,768-row chunk, 16,500 rows held):
+# PERF.md section 6, PR 35.
+_ROW_BLOCK = 2048
+
+
+def _live_blocks(live, block):
+    """Blocks of ``block`` rows that hold one of the first ``live``."""
+    return (live + block - 1) // block
+
+
+def _take_rows(src, tok, live, block):
+    """``src[tok]`` [R, D] with the rows from ``live`` on zero. Starts
+    from zeros and gathers ``_live_blocks`` blocks of ``block`` rows: the
+    rows past the last live block are never touched. ``_add_rows`` is its
+    transpose."""
+    r = tok.shape[0]
+
+    def body(i, out):
+        # where block does not divide R the last block moves back over
+        # rows the one before it wrote, and writes them the same
+        lo = jnp.minimum(i * block, r - block)
+        at = jax.lax.dynamic_slice(tok, (lo,), (block,))
+        keep = (lo + jnp.arange(block) < live)[:, None]
+        got = jnp.where(keep, jnp.take(src, at, axis=0),
+                        jnp.zeros((), src.dtype))
+        return jax.lax.dynamic_update_slice(out, got, (lo, 0))
+
+    return jax.lax.fori_loop(0, _live_blocks(live, block), body,
+                             jnp.zeros((r, src.shape[1]), src.dtype))
+
+
+def _add_rows(dst, tok, rows, live, block):
+    """``dst`` [T, D] with ``rows[i]`` added at ``tok[i]`` for i < live,
+    ``_live_blocks`` blocks of ``block`` rows at a time into ``dst``
+    itself: what ``rows`` holds from ``live`` on is never read."""
+    r, d = rows.shape
+
+    def body(i, dst):
+        lo = jnp.minimum(i * block, r - block)
+        at = jax.lax.dynamic_slice(tok, (lo,), (block,))
+        row = lo + jnp.arange(block)
+        keep = ((row >= i * block) & (row < live))[:, None]
+        add = jnp.where(keep, jax.lax.dynamic_slice(rows, (lo, 0), (block, d)),
+                        jnp.zeros((), rows.dtype))
+        return dst.at[at].add(add.astype(dst.dtype))
+
+    return jax.lax.fori_loop(0, _live_blocks(live, block), body, dst)
+
+
+def _chunk_rows(order, sizes, lo, top_k, row_chunk):
+    """Of the sorted rows lo .. lo + row_chunk: (their slots, their
+    tokens, the rows of each held expert among them)."""
     idx = jax.lax.dynamic_slice(order, (lo,), (row_chunk,))
-    tok = idx // top_k
     ends = jnp.cumsum(sizes)
     gs = jnp.clip(ends, lo, lo + row_chunk) \
         - jnp.clip(ends - sizes, lo, lo + row_chunk)
-    cdt = tokens.dtype
+    return idx, idx // top_k, gs
+
+
+def _chunk_products(xs, flat_gates, w_gate, w_up, w_down, idx, gs, live):
+    """The held experts' weighted outputs for a chunk's gathered rows
+    ``xs`` [row_chunk, D], row by row."""
+    cdt = xs.dtype
     # a row past the chunk's held rows lies under no expert: what
     # ragged_dot leaves there is not defined on every backend (the TPU's
-    # leaves what the buffer held), so such rows are cut out of the
-    # gathered operand and of every product, forward and (where's vjp)
-    # backward
-    held = (jnp.arange(row_chunk) < ends[-1] - lo)[:, None]
-    rows = lambda a: jnp.where(held, a, jnp.zeros((), a.dtype))
-    dot = lambda a, w: rows(jax.lax.ragged_dot(a, w.astype(cdt), gs))
-    xs = rows(jnp.take(tokens, tok, axis=0))
+    # leaves what the buffer held), so such rows are cut out of every
+    # product, forward and (where's vjp) backward
+    held = (jnp.arange(xs.shape[0]) < live)[:, None]
+    dot = lambda a, w: jnp.where(
+        held, jax.lax.ragged_dot(a, w.astype(cdt), gs), jnp.zeros((), cdt))
     h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
     ys = dot(h, w_down)
-    wts = jnp.take(flat_gates, idx).astype(ys.dtype)
-    return jnp.zeros((t, d), ys.dtype).at[tok].add(ys * wts[:, None])
+    return ys * jnp.take(flat_gates, idx).astype(ys.dtype)[:, None]
 
 
 def _chunks(order, sizes, row_chunk):
-    """(chunk starts, rows under a held expert)."""
+    """(chunk starts, each chunk's live rows: those under a held expert,
+    which come first, the rows of a block of the row movement)."""
     n = order.shape[0] // row_chunk
-    return jnp.arange(n, dtype=jnp.int32) * row_chunk, jnp.sum(sizes)
+    starts = jnp.arange(n, dtype=jnp.int32) * row_chunk
+    return (starts, jnp.clip(jnp.sum(sizes) - starts, 0, row_chunk),
+            min(_ROW_BLOCK, row_chunk))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
                    top_k, row_chunk):
     """The held experts' weighted outputs summed per token, a chunk of
-    sorted rows at a time; a chunk past the held rows is skipped. Its own
-    vjp walks the chunks again, each differentiated where it stands, so
-    no chunk's operands are kept for the backward pass."""
-    starts, held_rows = _chunks(order, sizes, row_chunk)
+    sorted rows at a time; a chunk past the held rows is skipped, and a
+    chunk that runs gathers its tokens' rows and adds its products into
+    the carried sum block by block up to its last held row. Its own vjp
+    walks the chunks again, each differentiated where it stands, so no
+    chunk's operands are kept for the backward pass."""
+    starts, lives, block = _chunks(order, sizes, row_chunk)
 
-    def step(out, lo):
-        return jax.lax.cond(
-            lo < held_rows,
-            lambda o: o + _share_chunk(tokens, flat_gates, w_gate, w_up,
-                                       w_down, order, sizes, lo, top_k,
-                                       row_chunk),
-            lambda o: o, out), None
+    def run(out, lo, live):
+        idx, tok, gs = _chunk_rows(order, sizes, lo, top_k, row_chunk)
+        xs = _take_rows(tokens, tok, live, block)
+        return _add_rows(out, tok, _chunk_products(
+            xs, flat_gates, w_gate, w_up, w_down, idx, gs, live), live, block)
+
+    def step(out, chunk):
+        return jax.lax.cond(chunk[1] > 0, run, lambda o, *_: o,
+                            out, *chunk), None
 
     out, _ = jax.lax.scan(step, jnp.zeros(tokens.shape, tokens.dtype),
-                          starts)
+                          (starts, lives))
     return out
 
 
@@ -338,22 +404,30 @@ def _share_experts_fwd(tokens, flat_gates, w_gate, w_up, w_down, order,
 
 def _share_experts_bwd(top_k, row_chunk, res, d_out):
     tokens, flat_gates, w_gate, w_up, w_down, order, sizes = res
-    starts, held_rows = _chunks(order, sizes, row_chunk)
-    diff = (tokens, flat_gates, w_gate, w_up, w_down)
+    starts, lives, block = _chunks(order, sizes, row_chunk)
+    diff = (flat_gates, w_gate, w_up, w_down)
 
-    def step(acc, lo):
-        def run(acc):
-            _, vjp = jax.vjp(
-                lambda *a: _share_chunk(*a, order, sizes, lo, top_k,
-                                        row_chunk), *diff)
-            return tuple(a + g.astype(a.dtype)
-                         for a, g in zip(acc, vjp(d_out)))
-        return jax.lax.cond(lo < held_rows, run, lambda a: a, acc), None
+    def run(acc, lo, live):
+        # the gather's transpose is the scatter-add and the other way
+        # round: the products' cotangent is d_out's rows taken, and the
+        # tokens' is d xs added into their accumulator
+        idx, tok, gs = _chunk_rows(order, sizes, lo, top_k, row_chunk)
+        _, vjp = jax.vjp(
+            lambda xs, *a: _chunk_products(xs, *a, idx, gs, live),
+            _take_rows(tokens, tok, live, block), *diff)
+        d_xs, *grads = vjp(_take_rows(d_out, tok, live, block))
+        return (_add_rows(acc[0], tok, d_xs, live, block),
+                *(a + g.astype(a.dtype) for a, g in zip(acc[1:], grads)))
 
-    acc0 = tuple(jnp.zeros(a.shape, jnp.float32) for a in diff)
-    acc, _ = jax.lax.scan(step, acc0, starts)
+    def step(acc, chunk):
+        return jax.lax.cond(chunk[1] > 0, run, lambda a, *_: a,
+                            acc, *chunk), None
+
+    acc0 = (jnp.zeros(tokens.shape, tokens.dtype),
+            *(jnp.zeros(a.shape, jnp.float32) for a in diff))
+    acc, _ = jax.lax.scan(step, acc0, (starts, lives))
     zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
-    return (*(g.astype(a.dtype) for g, a in zip(acc, diff)),
+    return (acc[0], *(g.astype(a.dtype) for g, a in zip(acc[1:], diff)),
             zero(order), zero(sizes))
 
 

@@ -15,6 +15,7 @@ import paddle_tpu as paddle
 from paddle_tpu import nn, optimizer
 from paddle_tpu.jit import _wrap_tree, functional_call
 from paddle_tpu.models import KeyeVL2ForCausalLM, keye_vl2_tiny
+from paddle_tpu.ops import moe
 from paddle_tpu.ops.moe import moe_share_forward
 from paddle_tpu.ops.pallas import sparse_attention as sa
 
@@ -225,8 +226,8 @@ def test_the_shares_outputs_add_up_to_the_uncut_layer(experts, shares):
     n, total, rows = 8 // shares, 0, []
     for i in range(shares):
         lo = i * n
-        out, r = moe_share_forward(x, gw, wg[lo:lo + n], wu[lo:lo + n],
-                                   wd[lo:lo + n], 2, lo)
+        out, r, _ = moe_share_forward(x, gw, wg[lo:lo + n], wu[lo:lo + n],
+                                      wd[lo:lo + n], 2, lo)
         assert close(out, dense_experts(x, gw, wg, wu, wd, 2,
                                         range(lo, lo + n)))
         total, rows = total + out, rows + list(np.asarray(r))
@@ -243,7 +244,7 @@ def test_a_share_is_dropless_whatever_the_routing(experts, favoured):
     x, gw, wg, wu, wd = experts
     x = x.at[..., 0].set(1.0)
     gw = gw.at[0, :favoured].add(50.0)
-    out, rows = moe_share_forward(x, gw, wg[:2], wu[:2], wd[:2], 2, 0)
+    out, rows, _ = moe_share_forward(x, gw, wg[:2], wu[:2], wd[:2], 2, 0)
     if favoured == 2:
         assert list(np.asarray(rows)) == [64, 64]
     else:
@@ -267,6 +268,137 @@ def test_the_shares_gradients(experts):
         assert close(a, b, 1e-4)
 
 
+# -- the share's row movement: blocks up to the held rows ----------------------
+
+BLOCK, R = 8, 40
+LIVES = [0, 1, BLOCK - 1, BLOCK, BLOCK + 1, R]
+
+
+@pytest.fixture(scope="module")
+def movement():
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    return (jax.random.normal(ks[0], (12, 16)),             # src / dst
+            jax.random.randint(ks[1], (R,), 0, 12),         # tok, repeated
+            jax.random.normal(ks[2], (R, 16)))              # rows
+
+
+def whole_take(src, tok, live):
+    """The whole-chunk gather with its cut, as the share made it before
+    the walk."""
+    held = (jnp.arange(tok.shape[0]) < live)[:, None]
+    return jnp.where(held, jnp.take(src, tok, axis=0), 0.0)
+
+
+def whole_add(dst, tok, rows, live):
+    held = (jnp.arange(tok.shape[0]) < live)[:, None]
+    return dst + jnp.zeros_like(dst).at[tok].add(jnp.where(held, rows, 0.0))
+
+
+@pytest.mark.parametrize("r", [R, R - 4])       # 8 does not divide 36
+@pytest.mark.parametrize("live", LIVES)
+def test_take_and_add_walk_blocks_up_to_the_live_rows(movement, live, r):
+    src, tok, rows = movement
+    tok, rows, live = tok[:r], rows[:r], min(live, r)
+    got = jax.jit(moe._take_rows, static_argnums=3)(src, tok, live, BLOCK)
+    assert (np.asarray(got) == np.asarray(whole_take(src, tok, live))).all()
+    # what lies past the live rows is never read: not a NaN gets through
+    dead = rows.at[live:].set(jnp.nan)
+    got = jax.jit(moe._add_rows, static_argnums=4)(
+        src, tok, dead, live, BLOCK)
+    assert close(got, whole_add(src, tok, rows, live), 1e-6)
+
+
+@pytest.mark.parametrize("live", LIVES)
+def test_take_and_add_are_each_others_transpose(movement, live):
+    """The vjp of the whole-chunk gather is the walk's add into zeros and
+    the vjp of the whole-chunk scatter-add the walk's take: what
+    ``_share_experts_bwd`` relies on."""
+    src, tok, rows = movement
+    _, vjp = jax.vjp(lambda s: whole_take(s, tok, live), src)
+    assert close(vjp(rows)[0], moe._add_rows(
+        jnp.zeros_like(src), tok, rows, live, BLOCK), 1e-6)
+    _, vjp = jax.vjp(lambda d, a: whole_add(d, tok, a, live), src, rows)
+    d_dst, d_rows = vjp(src)
+    assert close(d_dst, src)
+    assert close(d_rows, moe._take_rows(src, tok, live, BLOCK))
+
+
+def whole_chunk_share(x, gate_w, w_gate, w_up, w_down, top_k, first_expert):
+    """``moe_share_forward`` as it was before its row movement walked
+    blocks: every chunk gathers all its rows, cuts the dead ones out and
+    scatter-adds them all into a fresh array; differentiated by jax."""
+    b, s, d = x.shape
+    tokens = x.reshape(b * s, d)
+    t, e, n_held = b * s, gate_w.shape[1], w_gate.shape[0]
+    n_rows = t * top_k
+    top_i, gates = moe.route_softmax(tokens @ gate_w, top_k)
+    local = top_i.reshape(n_rows) - first_expert
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    row_chunk = min(n_rows, -(-int(2 * n_rows * n_held / e) // 16) * 16)
+    n_chunks = -(-n_rows // row_chunk)
+    order = jnp.pad(order, (0, n_chunks * row_chunk - n_rows),
+                    constant_values=n_rows - 1)
+    flat_gates, ends = gates.reshape(n_rows), jnp.cumsum(sizes)
+
+    def chunk(lo):
+        idx = jax.lax.dynamic_slice(order, (lo,), (row_chunk,))
+        tok = idx // top_k
+        gs = jnp.clip(ends, lo, lo + row_chunk) \
+            - jnp.clip(ends - sizes, lo, lo + row_chunk)
+        held = (jnp.arange(row_chunk) < ends[-1] - lo)[:, None]
+        rows = lambda a: jnp.where(held, a, 0.0)
+        dot = lambda a, w: rows(jax.lax.ragged_dot(a, w, gs))
+        xs = rows(jnp.take(tokens, tok, axis=0))
+        ys = dot(jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up), w_down)
+        return jnp.zeros((t, d)).at[tok].add(
+            ys * jnp.take(flat_gates, idx)[:, None])
+
+    def step(out, lo):
+        return jax.lax.cond(lo < ends[-1], lambda o: o + chunk(lo),
+                            lambda o: o, out), None
+
+    starts = jnp.arange(n_chunks, dtype=jnp.int32) * row_chunk
+    out, _ = jax.lax.scan(step, jnp.zeros((t, d)), starts)
+    return out.reshape(b, s, d), jnp.clip(ends[-1] - starts, 0, row_chunk)
+
+
+@pytest.mark.parametrize("routing", ["none", "even", "nearly_full", "all"])
+def test_the_walk_is_the_whole_chunk_share_and_counts_its_blocks(
+        experts, routing, monkeypatch):
+    """Two of eight experts held and 128 routed rows: chunks of 64. No
+    held row at all, an even share (about 32), 60 (1.9 x the share: one
+    chunk nearly full) and every row (two full chunks)."""
+    monkeypatch.setattr(moe, "_ROW_BLOCK", BLOCK)
+    x, gw, wg, wu, wd = experts
+    if routing != "even":
+        # a token whose x[0] is 1 picks the two held experts, one whose
+        # x[1] is 1 never picks either
+        n = {"none": 0, "nearly_full": 30, "all": 64}[routing]
+        picks = (jnp.arange(64) < n).reshape(2, 32).astype(x.dtype)
+        x = x.at[..., 0].set(picks).at[..., 1].set(1.0 - picks)
+        gw = gw.at[0, :2].add(50.0).at[1, :2].add(-50.0)
+    args = (x, gw, wg[:2], wu[:2], wd[:2])
+    want, lives = whole_chunk_share(*args, 2, 0)
+    lives = np.asarray(lives)
+    got, rows, walked = moe_share_forward(*args, 2, 0)
+    assert int(rows.sum()) == lives.sum()
+    if routing == "even":
+        assert 16 < lives[0] < 48 and lives[1] == 0
+    else:
+        assert list(lives) == {"none": [0, 0], "nearly_full": [60, 0],
+                               "all": [64, 64]}[routing]
+    assert close(got, want)
+    assert int(walked) == BLOCK * sum(-(-int(n) // BLOCK) for n in lives)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a, 2, 0)[0] ** 2),
+                        argnums=tuple(range(5)))(*args)
+    for a, b in zip(grads(moe_share_forward), grads(whole_chunk_share)):
+        assert close(a, b, 1e-4)
+
+
 def test_the_layer_counts_its_rows_and_refuses_an_uneven_share():
     paddle.seed(3)
     layer = nn.MoEShareLayer(16, 24, 8, 2, share=(1, 4))
@@ -279,6 +411,8 @@ def test_the_layer_counts_its_rows_and_refuses_an_uneven_share():
     assert counts["rows_routed"] == 2 * 2 * 32 * 2
     assert 0 < counts["rows_max_expert"] <= counts["rows_held"] \
         < counts["rows_routed"]
+    # two calls' one chunk of 64 rows each, walked as the one block it is
+    assert counts["rows_held"] < counts["rows_walked"] == 2 * 64
     # a counter is two words: it carries out of the low one exactly
     low = layer.rows._value[0].at[-1].add(2 ** 30 - 100)
     layer.rows._replace(layer.rows._value.at[0].set(low))
@@ -316,6 +450,8 @@ def test_recompute_trains_the_same():
     reg = telemetry.default_tracer().metrics
     assert reg.snapshot()["counters"]["moe.rows_routed"] \
         == counts["rows_routed"] == reg.value("moe.rows_routed")
+    assert reg.value("moe.rows_walked") == counts["rows_walked"] \
+        >= counts["rows_held"]
     assert reg.value("attn.sparse.kernel") >= 2
 
 

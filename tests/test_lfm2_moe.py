@@ -204,7 +204,7 @@ def test_the_four_shares_add_up_to_the_uncut_references_whole_layer():
     total, rows = 0, []
     for share in range(4):
         lo, n = share * 2, 2
-        out, r = moe.moe_share_forward(
+        out, r, _ = moe.moe_share_forward(
             x, lw["wr"], lw["eg"][lo:lo + n], lw["eu"][lo:lo + n],
             lw["ed"][lo:lo + n], k, lo, True, route)
         # each share is the reference told the same share
@@ -279,6 +279,8 @@ def test_recompute_trains_the_same_and_every_kind_of_layer_counts():
     assert np.allclose(losses[True], losses[False], rtol=1e-5)
     assert reg.snapshot()["counters"]["moe.rows_routed"] \
         == counts["rows_routed"] == reg.value("moe.rows_routed")
+    assert reg.value("moe.rows_walked") == counts["rows_walked"] \
+        >= counts["rows_held"]
     assert reg.value("attn.flash.head_dim") == 16
     assert reg.value("moe.route.sigmoid_bias") >= 2
 
