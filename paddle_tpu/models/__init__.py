@@ -15,6 +15,10 @@ from .keye_vl2 import (  # noqa: F401
 from .lfm2_moe import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel, lfm2_moe_tiny,
 )
+from .smallthinker import (  # noqa: F401
+    SmallThinkerConfig, SmallThinkerForCausalLM, SmallThinkerModel,
+    smallthinker_tiny,
+)
 from .dit import (  # noqa: F401
     DiT, DiTConfig, dit_tiny, dit_s_2, dit_xl_2,
 )

@@ -176,10 +176,13 @@ class MoEShareLayer(Layer):
     ``share=(index, count)`` divides the ``num_experts`` experts evenly
     over ``count`` holders; this layer has the parameters of experts
     ``index * num_experts // count`` onward, ``num_experts // count`` of
-    them, each ``w_down(silu(w_gate x) * w_up x)``. The router
+    them, each ``w_down(act(w_gate x) * w_up x)``: ``activation`` is the
+    gate's, ``"silu"`` (SwiGLU: Keye-VL-2.0, LFM2) or ``"relu"`` (ReGLU:
+    SmallThinker). The router
     (``gate_weight`` [d_model, num_experts]) is whole on every share and
     follows one of two published rules. ``score_func="softmax"``
-    (Keye-VL-2.0, Qwen3-MoE): softmax in float32, the ``top_k`` largest,
+    (Keye-VL-2.0, SmallThinker, Qwen3-MoE): softmax in float32, the
+    ``top_k`` largest,
     divided by their sum when ``norm_topk_prob``. ``"sigmoid"`` (LFM2-MoE,
     DeepSeek-V3): ``s = sigmoid(logits)`` in float32; with
     ``expert_bias=True`` the ``top_k`` largest of ``s + expert_bias`` are
@@ -192,6 +195,12 @@ class MoEShareLayer(Layer):
     theirs to add, which under expert parallelism is the exchange and on
     a single share is left out. ``share=(0, 1)`` is the whole layer. No
     token is dropped (``ops.moe.moe_share_forward``).
+
+    ``forward(x, router_input=None)``: the experts always read ``x``; the
+    router's logits come from ``router_input`` [batch, seq, d_model] where
+    it is given (SmallThinker routes on the layer's input, before
+    attention, and feeds the experts the stream after it), else from
+    ``x``. The router's gradient flows to the tensor it read.
 
     ``rows`` is a buffer of ``num_held + 2`` counters that every forward
     adds to: the rows each held expert computed, then the rows the
@@ -211,7 +220,8 @@ class MoEShareLayer(Layer):
                  top_k: int, share=(0, 1), norm_topk_prob: bool = True,
                  dtype=None, score_func: str = "softmax",
                  expert_bias: bool = False,
-                 routed_scaling_factor: float = 1.0):
+                 routed_scaling_factor: float = 1.0,
+                 activation: str = "silu"):
         super().__init__(dtype=dtype)
         index, count = share
         if num_experts % count or not 0 <= index < count:
@@ -224,7 +234,12 @@ class MoEShareLayer(Layer):
                                         or routed_scaling_factor != 1.0):
             raise ValueError("the softmax rule has no selection bias and "
                              "no scaling factor")
+        from ...ops.moe import ACTIVATIONS
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}; there are: "
+                             f"{', '.join(ACTIVATIONS)}")
         self.score_func = score_func
+        self.activation = activation
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.num_experts, self.top_k = num_experts, top_k
         self.num_held = num_experts // count
@@ -244,7 +259,7 @@ class MoEShareLayer(Layer):
         else:
             self.expert_bias = None
 
-    def compute(self, x):
+    def compute(self, x, router_input=None):
         """(out, the counters this call adds) with the buffer untouched:
         for a caller that runs the layer inside a rematerialised region
         and counts outside it (``count``)."""
@@ -254,22 +269,29 @@ class MoEShareLayer(Layer):
         metrics.inc("moe.dispatch.share_ragged")
         if self.expert_bias is not None:
             metrics.inc("moe.route.sigmoid_bias")
+        if self.activation == "relu":
+            metrics.inc("moe.expert.relu")
         routed = x.shape[0] * x.shape[1] * self.top_k
+        has_bias = self.expert_bias is not None
 
-        def f(xa, gw, wg, wu, wd, bias=None):
+        def f(xa, gw, wg, wu, wd, *more):
+            more = list(more)
+            bias = more.pop(0) if has_bias else None
+            routed_on = more.pop(0) if more else None
             route = moe.route_softmax if self.score_func == "softmax" \
                 else functools.partial(
                     moe.route_sigmoid, expert_bias=bias,
                     scaling=self.routed_scaling_factor)
             out, rows, walked = moe.moe_share_forward(
                 xa, gw, wg, wu, wd, self.top_k, self.first_expert,
-                self.norm_topk_prob, route)
+                self.norm_topk_prob, route, self.activation, routed_on)
             return out, jnp.concatenate(
                 [rows, walked[None], jnp.full((1,), routed, jnp.int32)])
 
-        bias = () if self.expert_bias is None else (self.expert_bias,)
+        more = ((self.expert_bias,) if has_bias else ()) \
+            + (() if router_input is None else (router_input,))
         return apply("moe_share", f, x, self.gate_weight, self.w_gate,
-                     self.w_up, self.w_down, *bias)
+                     self.w_up, self.w_down, *more)
 
     def count(self, seen):
         low, high = self.rows._value
@@ -278,8 +300,8 @@ class MoEShareLayer(Layer):
             [low & ((1 << self._LOW_BITS) - 1),
              high + (low >> self._LOW_BITS)]))
 
-    def forward(self, x):
-        out, seen = self.compute(x)
+    def forward(self, x, router_input=None):
+        out, seen = self.compute(x, router_input)
         self.count(seen)
         return out
 

@@ -13,6 +13,14 @@ Two paths:
   long sequences.
 
 Layout is paddle's: q/k/v [batch, seq, num_heads, head_dim].
+
+``window`` (a static integer or ``None``; causal attention only) is the
+number of keys a query sees, ITS OWN COUNTED: the query at position t
+sees the keys s with ``t - window < s <= t``, which is
+``sliding_window`` of the public configs that count so (Mistral's,
+SmallThinker's ``sliding_window_size``). ``None`` is plain causal
+attention and today's program to the letter; with a window the Pallas
+kernels walk only the band's tiles and are named ``flash_win_*``.
 """
 from __future__ import annotations
 
@@ -27,10 +35,13 @@ _NEG_INF = -1e30
 
 
 def _sdpa_core(q, k, v, bias, causal, scale, dropout=0.0,
-               dropout_key=None):
+               dropout_key=None, window=None):
     """[b, s, h, d] reference attention with f32 softmax accumulation.
     dropout (with a key) is applied to the attention probabilities,
-    upscale-in-train — the reference flashattn semantics."""
+    upscale-in-train — the reference flashattn semantics. ``window``:
+    the module's docstring."""
+    if window is not None and not causal:
+        raise ValueError("a window bounds causal attention only")
     b, sq, h, d = q.shape
     sk = k.shape[1]
     kv_heads = k.shape[2]
@@ -45,6 +56,8 @@ def _sdpa_core(q, k, v, bias, causal, scale, dropout=0.0,
         qi = jnp.arange(sq)[:, None] + (sk - sq)
         ki = jnp.arange(sk)[None, :]
         mask = qi >= ki
+        if window is not None:
+            mask = mask & (ki > qi - window)
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     if dropout and dropout_key is not None:
@@ -56,10 +69,10 @@ def _sdpa_core(q, k, v, bias, causal, scale, dropout=0.0,
 
 
 def flash_attention_reference(q, k, v, attn_mask=None, causal=False,
-                              dropout=0.0, scale=None):
+                              dropout=0.0, scale=None, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _sdpa_core(q, k, v, attn_mask, causal, scale)
+    return _sdpa_core(q, k, v, attn_mask, causal, scale, window=window)
 
 
 def _pick_block(seq: int):
@@ -92,7 +105,8 @@ def pallas_attention_plan(q, k, min_seq: int = 512):
 
 
 def flash_attention(q, k, v, attn_mask=None, causal=False, dropout=0.0,
-                    scale=None, return_softmax=False, dropout_key=None):
+                    scale=None, return_softmax=False, dropout_key=None,
+                    window=None):
     """Differentiable flash attention on raw arrays.
 
     On TPU backends dispatches to the Pallas kernel (custom VJP) when
@@ -101,7 +115,7 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, dropout=0.0,
     well). Both paths match numerically up to f32 accumulation order.
     Attention dropout requires a dropout_key (the dense path applies it
     to the probs); dropout > 0 without a key is an error — never a
-    silent no-op.
+    silent no-op. ``window``: the module's docstring.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -112,11 +126,16 @@ def flash_attention(q, k, v, attn_mask=None, causal=False, dropout=0.0,
             "training)")
     plan = pallas_attention_plan(q, k) if (attn_mask is None
                                            and dropout == 0.0) else None
+    if window is not None:      # trace-time: that a call took a window
+        from ..utils import telemetry
+        metrics = telemetry.default_tracer().metrics
+        metrics.inc("attn.flash.window")
+        metrics.set_gauge("attn.flash.window_size", window)
     if plan is not None:
         from .pallas.flash_attention import flash_attention_pallas
-        return flash_attention_pallas(q, k, v, causal, scale, *plan)
+        return flash_attention_pallas(q, k, v, causal, scale, *plan, window)
     return _sdpa_core(q, k, v, attn_mask, causal, scale, dropout,
-                      dropout_key)
+                      dropout_key, window)
 
 
 # ---------------------------------------------------------------------------

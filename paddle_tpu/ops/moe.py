@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import contextlib
 import functools
 
 import jax
@@ -219,20 +220,29 @@ def route_sigmoid(logits, top_k: int, norm_topk_prob: bool = True,
     return top_i, top_s * scaling
 
 
+# the gate's activation of a gated expert: w_down(act(w_gate x) * w_up x)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
                       first_expert: int = 0, norm_topk_prob: bool = True,
-                      route=route_softmax):
+                      route=route_softmax, activation: str = "silu",
+                      router_input=None):
     """One share's part of a gated-expert layer: x [B, S, D] ->
     (out [B, S, D], rows [E_held] int32, walked int32).
 
     ``gate_w`` [D, E] gives every token its float32 logits over ALL E
-    experts; ``route(logits, top_k, norm_topk_prob)`` turns them into the
+    experts, from ``x`` or, where it is given, from ``router_input``
+    [B, S, D] (a router that reads another tensor than its experts do,
+    and takes its gradient there);
+    ``route(logits, top_k, norm_topk_prob)`` turns them into the
     token's ``top_k`` experts and their weights (``route_softmax``, or
     ``route_sigmoid`` with its bias and scaling bound by
     ``functools.partial``); ``w_gate`` / ``w_up`` [E_held, D, H] and
     ``w_down`` [E_held, H, D] are the experts ``first_expert ..
     first_expert + E_held - 1`` that live here, each computing
-    ``w_down(silu(w_gate x) * w_up x)``. ``out`` is the sum over a
+    ``w_down(act(w_gate x) * w_up x)``, ``act`` the static ``activation``
+    (``silu`` or ``relu``). ``out`` is the sum over a
     token's chosen experts THAT ARE HELD of weight * expert(x): what the
     other shares hold is theirs to add (expert parallelism's exchange,
     or nothing on a single share). ``rows`` counts the rows each held
@@ -262,8 +272,18 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
     e, n_held = gate_w.shape[1], w_gate.shape[0]
     n_rows = t * top_k
 
-    logits = tokens.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    top_i, gates = route(logits, top_k, norm_topk_prob)
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r}; there are: "
+                         f"{', '.join(ACTIVATIONS)}")
+    # a router with an input of its own is a step of its own in the trace:
+    # it belongs to another place of the layer than the experts it is
+    # computed beside
+    routed_on, scope = (tokens, contextlib.nullcontext()) \
+        if router_input is None \
+        else (router_input.reshape(t, d), jax.named_scope("router"))
+    with scope:
+        logits = routed_on.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        top_i, gates = route(logits, top_k, norm_topk_prob)
 
     local = top_i.reshape(n_rows) - first_expert
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
@@ -278,7 +298,7 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
     order = jnp.pad(order, (0, n_chunks * row_chunk - n_rows),
                     constant_values=n_rows - 1)
     out = _share_experts(tokens, gates.reshape(n_rows), w_gate, w_up,
-                         w_down, order, sizes, top_k, row_chunk)
+                         w_down, order, sizes, top_k, row_chunk, activation)
     _, lives, block = _chunks(order, sizes, row_chunk)
     walked = block * jnp.sum(_live_blocks(lives, block))
     return out.reshape(b, s, d).astype(x.dtype), sizes, walked
@@ -344,7 +364,8 @@ def _chunk_rows(order, sizes, lo, top_k, row_chunk):
     return idx, idx // top_k, gs
 
 
-def _chunk_products(xs, flat_gates, w_gate, w_up, w_down, idx, gs, live):
+def _chunk_products(xs, flat_gates, w_gate, w_up, w_down, idx, gs, live,
+                    activation="silu"):
     """The held experts' weighted outputs for a chunk's gathered rows
     ``xs`` [row_chunk, D], row by row."""
     cdt = xs.dtype
@@ -355,7 +376,7 @@ def _chunk_products(xs, flat_gates, w_gate, w_up, w_down, idx, gs, live):
     held = (jnp.arange(xs.shape[0]) < live)[:, None]
     dot = lambda a, w: jnp.where(
         held, jax.lax.ragged_dot(a, w.astype(cdt), gs), jnp.zeros((), cdt))
-    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    h = ACTIVATIONS[activation](dot(xs, w_gate)) * dot(xs, w_up)
     ys = dot(h, w_down)
     return ys * jnp.take(flat_gates, idx).astype(ys.dtype)[:, None]
 
@@ -369,9 +390,9 @@ def _chunks(order, sizes, row_chunk):
             min(_ROW_BLOCK, row_chunk))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
-                   top_k, row_chunk):
+                   top_k, row_chunk, activation="silu"):
     """The held experts' weighted outputs summed per token, a chunk of
     sorted rows at a time; a chunk past the held rows is skipped, and a
     chunk that runs gathers its tokens' rows and adds its products into
@@ -384,7 +405,8 @@ def _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
         idx, tok, gs = _chunk_rows(order, sizes, lo, top_k, row_chunk)
         xs = _take_rows(tokens, tok, live, block)
         return _add_rows(out, tok, _chunk_products(
-            xs, flat_gates, w_gate, w_up, w_down, idx, gs, live), live, block)
+            xs, flat_gates, w_gate, w_up, w_down, idx, gs, live, activation),
+            live, block)
 
     def step(out, chunk):
         return jax.lax.cond(chunk[1] > 0, run, lambda o, *_: o,
@@ -396,13 +418,13 @@ def _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
 
 
 def _share_experts_fwd(tokens, flat_gates, w_gate, w_up, w_down, order,
-                       sizes, top_k, row_chunk):
+                       sizes, top_k, row_chunk, activation="silu"):
     out = _share_experts(tokens, flat_gates, w_gate, w_up, w_down, order,
-                         sizes, top_k, row_chunk)
+                         sizes, top_k, row_chunk, activation)
     return out, (tokens, flat_gates, w_gate, w_up, w_down, order, sizes)
 
 
-def _share_experts_bwd(top_k, row_chunk, res, d_out):
+def _share_experts_bwd(top_k, row_chunk, activation, res, d_out):
     tokens, flat_gates, w_gate, w_up, w_down, order, sizes = res
     starts, lives, block = _chunks(order, sizes, row_chunk)
     diff = (flat_gates, w_gate, w_up, w_down)
@@ -413,7 +435,8 @@ def _share_experts_bwd(top_k, row_chunk, res, d_out):
         # tokens' is d xs added into their accumulator
         idx, tok, gs = _chunk_rows(order, sizes, lo, top_k, row_chunk)
         _, vjp = jax.vjp(
-            lambda xs, *a: _chunk_products(xs, *a, idx, gs, live),
+            lambda xs, *a: _chunk_products(xs, *a, idx, gs, live,
+                                           activation),
             _take_rows(tokens, tok, live, block), *diff)
         d_xs, *grads = vjp(_take_rows(d_out, tok, live, block))
         return (_add_rows(acc[0], tok, d_xs, live, block),

@@ -18,14 +18,25 @@ id — the packed-sequence ("varlen"/"unpadded") training path. Negative or
 mismatched ids are fully masked; fully-masked query rows produce zero
 output (guarded online softmax, not NaN).
 
+A window (``window`` keys, static; causal attention only) restricts a
+query at position t to the keys s with ``t - window < s <= t``: its own
+key and the ``window - 1`` before it. The three kernels then bound their
+inner loops to the band's blocks (the key loops from below, the query
+loop of dk/dv from above) and mask the band's lower edge beside the
+causal compare, so tiles outside the band are neither fetched nor
+computed; such calls are named ``flash_win_*``. ``window=None`` is the
+program without any of it.
+
 Layout contract (paddle convention at the API): q/k/v [batch, seq, heads,
 head_dim]; kernels internally run [batch, heads, seq, head_dim]. On the
 v5e head_dim 64 compiles and reads 21.5% of peak, 128 reads 38.9% (PR 34).
 
-VMEM budget: K and V are held whole per (batch, kv-head) — fine up to
-seq*dim*2B*2 ≈ 8MB (seq 16k @ d=128 bf16). Longer sequences belong to ring
-attention (paddle_tpu.distributed.ring_attention) which shards seq over
-the mesh.
+VMEM budget: the forward kernel holds K and V whole per (batch,
+kv-head), and the pipeline buffers each twice: at seq 16k, d=128, bf16
+that is 16 MB and past the 16 MB a kernel gets by default, so the call
+asks for what it holds (``_vmem_room``; shapes that fit ask for nothing).
+Longer sequences belong to ring attention
+(paddle_tpu.distributed.ring_attention) which shards seq over the mesh.
 """
 from __future__ import annotations
 
@@ -56,7 +67,42 @@ DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
 
 
-def _fwd_kernel(*refs, scale, causal, block_k, seq_q, seq_k, segmented):
+def _band_first_block(q_first, k_first, block_k, num_kv, window):
+    """First block of ``block_k`` keys (the blocks start at global
+    position ``k_first``) that holds a key the query at global position
+    ``q_first`` sees under ``window``: a key above ``q_first - window``."""
+    lo = jnp.maximum(q_first - window + 1 - k_first, 0)
+    return jnp.clip(jax.lax.div(lo, block_k), 0, num_kv)
+
+
+def _band_end_block(k_last, q_first, block_q, num_q, window):
+    """One past the last block of ``block_q`` queries (starting at global
+    position ``q_first``) that holds a query which sees the key at global
+    position ``k_last`` under ``window``: a query below ``k_last +
+    window``."""
+    hi = jnp.maximum(k_last + window - 1 - q_first + block_q, 0)
+    return jnp.clip(jax.lax.div(hi, block_q), 0, num_q)
+
+
+def _vmem_room(resident_bytes):
+    """``pallas_call`` options for a call that holds ``resident_bytes`` of
+    whole-sequence operands, which the pipeline buffers twice: nothing
+    while they fit the VMEM a kernel gets by default, else a limit that
+    holds them."""
+    need = 2 * resident_bytes + (8 << 20)
+    if need <= 16 << 20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=need)}
+
+
+def _names(window):
+    """The three calls' names in the compiled program and the trace."""
+    stem = "flash" if window is None else "flash_win"
+    return f"{stem}_fwd", f"{stem}_bwd_dq", f"{stem}_bwd_dkv"
+
+
+def _fwd_kernel(*refs, scale, causal, block_k, seq_q, seq_k, segmented,
+                window=None):
     if segmented:
         q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref = refs
     else:
@@ -77,6 +123,10 @@ def _fwd_kernel(*refs, scale, causal, block_k, seq_q, seq_k, segmented):
             jax.lax.div(q_offset + bq - 1 + off, block_k) + 1, 0)
     else:
         num_kv_run = num_kv
+    first_kv = 0
+    if window is not None:
+        first_kv = _band_first_block(q_offset + off, 0, block_k, num_kv,
+                                     window)
 
     def body(kj, carry):
         acc, m_prev, l_prev = carry
@@ -90,6 +140,8 @@ def _fwd_kernel(*refs, scale, causal, block_k, seq_q, seq_k, segmented):
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(rows >= cols, s, _NEG_INF)
+            if window is not None:
+                s = jnp.where(cols > rows - window, s, _NEG_INF)
         if segmented:
             kseg = kseg_ref[0, pl.ds(kj * block_k, block_k)]  # [bk]
             s = jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
@@ -109,16 +161,20 @@ def _fwd_kernel(*refs, scale, causal, block_k, seq_q, seq_k, segmented):
     acc0 = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, num_kv_run, body, (acc0, m0, l0))
+    acc, m, l = jax.lax.fori_loop(first_kv, num_kv_run, body,
+                                  (acc0, m0, l0))
 
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     lse_ref[0, 0, :, 0] = (m + jnp.log(l_safe)).astype(jnp.float32)
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k,
+               window=None):
     """q [b,h,sq,d]; k/v [b,hk,sk,d]; segs [b,s] or None
     → out [b,h,sq,d], lse [b,h,sq]."""
+    if window is not None and not causal:
+        raise ValueError("a window bounds causal attention only")
     b, h, sq, d = q.shape
     hk, sk = k.shape[1], k.shape[2]
     group = h // hk
@@ -129,7 +185,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k):
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_k=bk, seq_q=sq, seq_k=sk,
-                               segmented=segmented)
+                               segmented=segmented, window=window)
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, sk, d),
@@ -157,13 +213,14 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k):
             _sds((b, h, sq, 1), jnp.float32, q),
         ],
         interpret=_interpret(),
-        name="flash_fwd",
+        name=_names(window)[0],
+        **_vmem_room(2 * sk * d * k.dtype.itemsize),
     )(*args)
     return out, lse[..., 0]
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_k, seq_q, seq_k,
-                   segmented, q_base, k_base):
+                   segmented, q_base, k_base, window=None):
     if segmented:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
          kseg_ref, dq_ref) = refs
@@ -189,18 +246,25 @@ def _bwd_dq_kernel(*refs, scale, causal, block_k, seq_q, seq_k,
             + 1, 0, num_kv)
     else:
         num_kv_run = num_kv
+    first_kv = 0
+    if window is not None:
+        first_kv = _band_first_block(q_base + q_offset, k_base, block_k,
+                                     num_kv, window)
 
     def body(kj, dq):
         k_blk = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         v_blk = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
+        if causal or window is not None:
             rows = q_base + q_offset + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = k_base + kj * block_k + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            if causal:
+                s = jnp.where(rows >= cols, s, _NEG_INF)
+            if window is not None:
+                s = jnp.where(cols > rows - window, s, _NEG_INF)
         if segmented:
             kseg = kseg_ref[0, pl.ds(kj * block_k, block_k)]
             s = jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
@@ -213,12 +277,12 @@ def _bwd_dq_kernel(*refs, scale, causal, block_k, seq_q, seq_k,
                                         preferred_element_type=jnp.float32)
 
     dq0 = jnp.zeros_like(q)
-    dq = jax.lax.fori_loop(0, num_kv_run, body, dq0)
+    dq = jax.lax.fori_loop(first_kv, num_kv_run, body, dq0)
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
-                    segmented, q_base, k_base):
+                    segmented, q_base, k_base, window=None):
     """Grid (b, hk, n_kblocks, group): the innermost `group` dimension
     revisits the same dk/dv output block, accumulating the kv-head's query
     group in VMEM (GQA without expanding K/V or group-partial HBM writes)."""
@@ -244,6 +308,10 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
             jnp.maximum(k_base + k_offset - q_base, 0), block_q)
     else:
         first_q = 0
+    end_q = num_q
+    if window is not None:
+        end_q = _band_end_block(k_base + k_offset + bk - 1, q_base, block_q,
+                                num_q, window)
 
     def body(qi, carry):
         dk, dv = carry
@@ -253,12 +321,15 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
         delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q), 0]
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
+        if causal or window is not None:
             rows = q_base + qi * block_q + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = k_base + k_offset + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            if causal:
+                s = jnp.where(rows >= cols, s, _NEG_INF)
+            if window is not None:
+                s = jnp.where(cols > rows - window, s, _NEG_INF)
         if segmented:
             qseg = qseg_ref[0, pl.ds(qi * block_q, block_q)]
             s = jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
@@ -276,7 +347,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
     d = k_blk.shape[-1]
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_q, num_q, body, (dk0, dv0))
+    dk, dv = jax.lax.fori_loop(first_q, end_q, body, (dk0, dv0))
 
     @pl.when(gi == 0)
     def _init():
@@ -289,10 +360,22 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
         dv_ref[0, 0] += dv
 
 
-def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
-                   scale, bq, bk, group, q_base, k_base, dq_dtype):
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("causal", "scale", "bq", "bk", "group", "q_base",
+                     "k_base", "dq_dtype", "window", "names", "interpret"))
+def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, *, causal,
+                   scale, bq, bk, group, q_base, k_base, dq_dtype,
+                   window, names, interpret):
     """dq + dk/dv pallas calls for one (q-slice, k-slice) pair whose
-    first rows sit at GLOBAL positions q_base/k_base."""
+    first rows sit at GLOBAL positions q_base/k_base. ``window`` is None
+    for a pair the band's lower edge does not cut; ``names`` are the
+    whole attention's.
+
+    An inlined ``jit``: pairs of the same shapes and static arguments
+    are traced once (at 16,384 tokens a layer has 36 pairs, 28 of them
+    alike) and each call still lands in the caller's program as its own
+    two kernels, under the caller's scope."""
     b, h, sq, d = q.shape
     hk, sk = k.shape[1], k.shape[2]
     segmented = q_seg is not None
@@ -318,14 +401,14 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=bk, seq_q=sq, seq_k=sk,
                           segmented=segmented, q_base=q_base,
-                          k_base=k_base),
+                          k_base=k_base, window=window),
         grid=(b, h, pl.cdiv(sq, bq)),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=_sds((b, h, sq, d), dq_dtype, q),
-        interpret=_interpret(),
-        name="flash_bwd_dq",
+        interpret=interpret,
+        name=names[1],
     )(*dq_args)
 
     # dk/dv: grid (b, hk, kblocks, group); q-head = hk_index*group + g
@@ -351,7 +434,7 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, seq_q=sq, seq_k=sk, group=group,
                           segmented=segmented, q_base=q_base,
-                          k_base=k_base),
+                          k_base=k_base, window=window),
         grid=(b, hk, pl.cdiv(sk, bk), group),
         in_specs=dkv_specs,
         out_specs=[
@@ -364,8 +447,8 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
             _sds((b, hk, sk, d), jnp.float32, q),
             _sds((b, hk, sk, d), jnp.float32, q),
         ],
-        interpret=_interpret(),
-        name="flash_bwd_dkv",
+        interpret=interpret,
+        name=names[2],
     )(*dkv_args)
     return dq, dk, dv
 
@@ -379,8 +462,28 @@ def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, causal,
 BWD_SEQ_CHUNK = 2048
 
 
+def _bwd_pairs(sq, sk, causal, window):
+    """The [q-chunk, k-chunk] pairs the backward pass calls its kernels
+    on, in order: (q0, qe, k0, ke, whether the causal diagonal cuts the
+    pair, the window if the band's lower edge does, else None). A pair
+    that holds no visible (query, key) is left out."""
+    cs = BWD_SEQ_CHUNK
+    base = sk - sq     # causal aligns queries to the END of the keys
+    for q0 in range(0, sq, cs):
+        qe = min(q0 + cs, sq)
+        for k0 in range(0, sk, cs):
+            ke = min(k0 + cs, sk)
+            if causal and k0 > base + qe - 1:
+                continue                       # fully invisible pair
+            if window is not None and ke - 1 <= base + q0 - window:
+                continue                       # wholly below the band
+            yield (q0, qe, k0, ke, causal and (ke - 1 > base + q0),
+                   window if window is not None
+                   and k0 <= base + qe - 1 - window else None)
+
+
 def _flash_bwd(q, k, v, out, lse, do, q_seg, kv_seg, causal, scale,
-               block_q, block_k):
+               block_q, block_k, window=None):
     """q/do [b,h,sq,d]; k/v [b,hk,sk,d] (NOT expanded). Returns dq [b,h,..]
     and group-summed dk/dv [b,hk,sk,d] (float32)."""
     b, h, sq, d = q.shape
@@ -394,33 +497,34 @@ def _flash_bwd(q, k, v, out, lse, do, q_seg, kv_seg, causal, scale,
 
     cs = BWD_SEQ_CHUNK
     base = sk - sq     # causal aligns queries to the END of the keys
+    static = dict(scale=scale, group=group, names=_names(window),
+                  interpret=_interpret())
     if sq <= cs and sk <= cs:
         return _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg,
-                              causal, scale, bq, bk, group,
-                              q_base=base, k_base=0, dq_dtype=q.dtype)
+                              causal=causal, bq=bq, bk=bk, q_base=base,
+                              k_base=0, dq_dtype=q.dtype, window=window,
+                              **static)
 
     dq = jnp.zeros((b, h, sq, d), jnp.float32)
     dk = jnp.zeros((b, hk, sk, d), jnp.float32)
     dv = jnp.zeros((b, hk, sk, d), jnp.float32)
-    for q0 in range(0, sq, cs):
-        qe = min(q0 + cs, sq)
-        for k0 in range(0, sk, cs):
-            ke = min(k0 + cs, sk)
-            if causal and k0 > base + qe - 1:
-                continue                       # fully invisible pair
-            pair_causal = causal and (ke - 1 > base + q0)
-            dq_p, dk_p, dv_p = _bwd_pair_call(
-                q[:, :, q0:qe], k[:, :, k0:ke], v[:, :, k0:ke],
-                do[:, :, q0:qe], lse4[:, :, q0:qe],
-                delta[:, :, q0:qe],
-                None if q_seg is None else q_seg[:, q0:qe],
-                None if kv_seg is None else kv_seg[:, k0:ke],
-                pair_causal, scale, min(bq, qe - q0),
-                min(bk, ke - k0), group,
-                q_base=base + q0, k_base=k0, dq_dtype=jnp.float32)
-            dq = dq.at[:, :, q0:qe].add(dq_p)
-            dk = dk.at[:, :, k0:ke].add(dk_p)
-            dv = dv.at[:, :, k0:ke].add(dv_p)
+    for q0, qe, k0, ke, pair_causal, pair_window in _bwd_pairs(
+            sq, sk, causal, window):
+        # a pair that neither mask cuts never reads its positions: given
+        # as 0, all such pairs are one trace
+        cut = pair_causal or pair_window is not None
+        dq_p, dk_p, dv_p = _bwd_pair_call(
+            q[:, :, q0:qe], k[:, :, k0:ke], v[:, :, k0:ke],
+            do[:, :, q0:qe], lse4[:, :, q0:qe],
+            delta[:, :, q0:qe],
+            None if q_seg is None else q_seg[:, q0:qe],
+            None if kv_seg is None else kv_seg[:, k0:ke],
+            causal=pair_causal, bq=min(bq, qe - q0), bk=min(bk, ke - k0),
+            q_base=base + q0 if cut else 0, k_base=k0 if cut else 0,
+            dq_dtype=jnp.float32, window=pair_window, **static)
+        dq = dq.at[:, :, q0:qe].add(dq_p)
+        dk = dk.at[:, :, k0:ke].add(dk_p)
+        dv = dv.at[:, :, k0:ke].add(dv_p)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -428,27 +532,30 @@ def _flash_bwd(q, k, v, out, lse, do, q_seg, kv_seg, causal, scale,
 # public custom-vjp entry points
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_pallas(q, k, v, causal=False, scale=None,
-                           block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
-    """q/k/v: [batch, seq, heads, head_dim] (kv heads may be fewer: GQA)."""
-    out, _ = _fa_fwd(q, k, v, causal, scale, block_q, block_k)
+                           block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                           window=None):
+    """q/k/v: [batch, seq, heads, head_dim] (kv heads may be fewer: GQA);
+    ``window``: a static number of keys a causal query sees, its own
+    counted, or None."""
+    out, _ = _fa_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return out
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     qt = jnp.swapaxes(q, 1, 2)   # [b,h,s,d]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out_t, lse = _flash_fwd(qt, kt, vt, None, None, causal, scale,
-                            block_q, block_k)
+                            block_q, block_k, window)
     out = jnp.swapaxes(out_t, 1, 2)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, res, g):
+def _fa_bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -458,7 +565,7 @@ def _fa_bwd(causal, scale, block_q, block_k, res, g):
     out_t = jnp.swapaxes(out, 1, 2)
     do_t = jnp.swapaxes(g, 1, 2)
     dq_t, dk_t, dv_t = _flash_bwd(qt, kt, vt, out_t, lse, do_t, None, None,
-                                  causal, scale, block_q, block_k)
+                                  causal, scale, block_q, block_k, window)
     dq = jnp.swapaxes(dq_t, 1, 2).astype(q.dtype)
     dk = jnp.swapaxes(dk_t, 1, 2).astype(k.dtype)
     dv = jnp.swapaxes(dv_t, 1, 2).astype(v.dtype)

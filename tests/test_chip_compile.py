@@ -129,6 +129,37 @@ def test_flash_attention_fwd_bwd_head_dim_64(one_chip, on_chip):
     assert text.count("tpu_custom_call") == 21
 
 
+@pytest.mark.parametrize("window,stem,pairs", [(None, "flash", 36),
+                                               (4096, "flash_win", 21)])
+def test_flash_attention_fwd_bwd_smallthinker_heads(one_chip, on_chip,
+                                                    window, stem, pairs):
+    """SmallThinker-21BA3B's attention at the benchmark cell's shape: 28 q
+    / 4 kv heads of 128 (groups of seven through dk/dv's ``group``), 1 x
+    16,384, without a window (layer 0) and under 4,096 keys (layers 1-3).
+    The forward kernel holds K and V of all 16,384 positions, twice 8 MB
+    with the pipeline's second buffer, and asks for that VMEM
+    (``_vmem_room``: the default 16 MB refuses it by 0.75 MB). The
+    backward pass walks [2048, 2048] pairs: 36 under the diagonal, of
+    which the band keeps 21 (8 on the diagonal, 7 whole, 6 cut by the
+    band's lower edge), and the windowed calls carry their own names."""
+    from paddle_tpu.ops.flash_attention import flash_attention
+    q = _sds(one_chip, (1, 16384, 28, 128), BF16)
+    kv = _sds(one_chip, (1, 16384, 4, 128), BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window) \
+            .astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = {name: len(re.findall(rf"{stem}_{name}[_.\d]* = ", text))
+             for name in ("fwd", "bwd_dq", "bwd_dkv")}
+    assert calls == {"fwd": 1, "bwd_dq": pairs, "bwd_dkv": pairs}
+    assert text.count("tpu_custom_call") == 1 + 2 * pairs
+    other = "flash_win_fwd" if window is None else "flash_fwd"
+    assert not re.findall(rf"[(_%]{other}[_.\d]* = ", text)
+
+
 @pytest.mark.parametrize("k,n", [(4096, 14336), (4096, 128256)])
 def test_decode_matmul_int4(one_chip, on_chip, k, n):
     from paddle_tpu.ops.pallas.decode_matmul import (decode_matmul,
@@ -296,6 +327,40 @@ def test_lfm2_cell_whole_step(one_chip, on_chip):
                                                               rel=0.01)
     assert nbytes == pytest.approx(14.88e9, rel=0.01) and nbytes < 15.5e9
     assert leaves == 49
+
+
+def test_smallthinker_cell_whole_step(one_chip, on_chip):
+    """The SmallThinker cell's whole step (published layers 0-3, one
+    chip's 16 of 64 experts, batch 1 x 16,384, ``use_recompute`` on: each
+    layer's forward pass runs again in the backward pass, so every forward
+    kernel is there twice): layer 0's attention under the old names,
+    layers 1-3 under ``flash_win_*`` with 21 pairs of backward calls each
+    against the global layer's 36; 48 grouped matmuls forward, again under
+    recomputation, and in the vjp; all 43 leaves' gradients under the
+    barrier. 12.9 GB of the chip's 16.9 (without recomputation the
+    compiler's analysis reads 16.84 GB after rematerialising operations of
+    its own choice)."""
+    from paddle_tpu.utils import telemetry
+    metrics = telemetry.default_tracer().metrics
+    before = metrics.value("attn.flash.window") or 0
+    compiled, nbytes, leaves = _cell_step_compiled(
+        one_chip, "lm_smallthinker", "smallthinker_21b_a3b_ep4_l4_train",
+        "train_b1_s16384")
+    text = compiled.as_text()
+    assert ".remat" not in text
+    calls = {name: len(re.findall(rf"%{name}[.\d]* = ", text))
+             for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                          "flash_win_fwd", "flash_win_bwd_dq",
+                          "flash_win_bwd_dkv")}
+    assert calls == {"flash_fwd": 2, "flash_bwd_dq": 36, "flash_bwd_dkv": 36,
+                     "flash_win_fwd": 6, "flash_win_bwd_dq": 63,
+                     "flash_win_bwd_dkv": 63}
+    assert nbytes == pytest.approx(12.91e9, rel=0.02) and nbytes < 15.5e9
+    assert leaves == 43
+    # the three window layers' calls counted themselves (a layer is
+    # traced once, recomputation or not)
+    took = metrics.value("attn.flash.window") - before
+    assert took and took % 3 == 0
 
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------

@@ -52,14 +52,16 @@ def test_the_rows_walked_are_read_against_the_rows_held(read, monkeypatch):
     assert read() == pytest.approx(1.1171, abs=1e-4)
 
 
-def test_the_entry_is_the_files_and_lists_both_expert_cells():
+def test_the_entry_is_the_files_and_lists_the_expert_cells():
     spec = manifest.load_json(REPO, "benchmark", "metrics", NAME + ".json")
-    entry = manifest.load_json(REPO, "BENCHMARK.json")["per_layer"][-1]
+    [entry] = [m for m in manifest.load_json(REPO, "BENCHMARK.json")[
+        "per_layer"] if m["name"] == NAME]
     assert entry == {
         "name": NAME, **{k: spec[k] for k in (
             "unit", "better", "source", "layer", "moves")},
         "workloads": ["keye_vl2_ep8_l4_train_s8192",
-                      "lfm2_ep4_l5_train_s8192"]}
+                      "lfm2_ep4_l5_train_s8192",
+                      "smallthinker_ep4_l4_train_s16384"]}
     assert (spec["better"], spec["source"]) == ("lower", "program_counter")
 
 

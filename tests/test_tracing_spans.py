@@ -556,11 +556,18 @@ class TestDeviceNames:
         for c in calls:
             kw = {k.arg: k.value for k in c.keywords}
             assert "name" in kw, f"{path}:{c.lineno} has no name="
-            names.append(kw["name"].value)
+            names.append(ast.unparse(kw["name"]))
         assert len(set(names)) == len(names)
         if path.endswith("flash_attention.py"):
-            assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq",
-                                     "flash_fwd"]
+            # the three calls take theirs from _names: the old ones
+            # without a window, flash_win_* under one
+            from paddle_tpu.ops.pallas.flash_attention import _names
+            assert sorted(names) == ["_names(window)[0]", "names[1]",
+                                     "names[2]"]
+            assert _names(None) == ("flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv")
+            assert _names(4096) == ("flash_win_fwd", "flash_win_bwd_dq",
+                                    "flash_win_bwd_dkv")
 
     def test_the_kernel_name_reaches_the_lowered_program(self):
         from paddle_tpu.ops.pallas.flash_attention import \
