@@ -16,6 +16,7 @@ import jax
 
 from ...framework.core import Tensor, apply, no_grad
 from ...jit import _SwapGuard, _unwrap_tree
+from ...utils import telemetry
 
 __all__ = ["recompute", "recompute_sequential"]
 
@@ -41,9 +42,23 @@ class _SubFn:
         return getattr(self.layer, self.method)(x)
 
 
-def recompute(function, *args, use_reentrant: bool = True, **kwargs):
-    """Run function(*args) with activation rematerialization in backward."""
+def recompute(function, *args, keep=(), use_reentrant: bool = True,
+              **kwargs):
+    """Run function(*args) with activation rematerialization in backward.
+
+    ``keep``: names (``jax.ad_checkpoint.checkpoint_name``) of values made
+    inside ``function`` that are kept for the backward pass and not made
+    again; everything else is. The default keeps nothing but the region's
+    inputs, the most memory a region can save. A kernel whose output costs
+    more to remake than to hold names it (the flash kernels:
+    ``ops.pallas.flash_attention.FLASH_KEEP``) and the caller whose step
+    has the room asks for it; a name nothing inside carries keeps
+    nothing."""
     preserve = kwargs.pop("preserve_rng_state", True)
+    metrics = telemetry.default_tracer().metrics
+    metrics.inc("recompute.regions")
+    if keep:
+        metrics.inc("recompute.regions_keeping")
     layer_params = []
     if hasattr(function, "parameters"):
         layer_params = [p for p in function.parameters()]
@@ -66,7 +81,11 @@ def recompute(function, *args, use_reentrant: bool = True, **kwargs):
         treedef_holder["treedef"] = treedef
         return tuple(flat) if len(flat) > 1 else flat[0]
 
-    ckpt = jax.checkpoint(pure)
+    if keep:
+        ckpt = jax.checkpoint(
+            pure, policy=jax.checkpoint_policies.save_only_these_names(*keep))
+    else:
+        ckpt = jax.checkpoint(pure)
     result = apply("recompute", ckpt, *layer_params, *tensor_args)
     flat = list(result) if isinstance(result, tuple) else [result]
     return jax.tree_util.tree_unflatten(treedef_holder["treedef"], flat)

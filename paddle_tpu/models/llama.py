@@ -51,10 +51,10 @@ class LlamaConfig:
     # "full"      — whole block rematerialized (max memory savings)
     # "full_attn" — only the attention sublayer (ln1 + attn)
     #               rematerialized; MLP activations stored
-    # "core_attn" — only the attention inner (scores/softmax/context)
-    #               recomputed. With the Pallas flash kernel this is the
-    #               plain forward: flash backward already recomputes
-    #               from q/k/v instead of storing probabilities
+    # "core_attn" — only the attention inner recomputed: with the Pallas
+    #               flash kernel the plain forward (its backward already
+    #               recomputes from q/k/v and stores no probabilities)
+    # recompute(keep=) can keep kernels' outputs by name; Llama asks for none
     recompute_granularity: str = "full"
     # parallelism knobs (consumed when a fleet mesh is active)
     tensor_parallel: bool = False

@@ -166,8 +166,12 @@ class SmallThinkerDecoderLayer(nn.Layer):
     def forward(self, x):
         if self.use_recompute:
             from ..distributed.fleet import recompute
+            from ..ops.pallas.flash_attention import FLASH_KEEP
             from .llama import _LayerFn
-            h, seen = recompute(_LayerFn(self), x)
+            # at 16,384 tokens a flash forward kernel costs O(s x band) to
+            # make again and O(s) to hold: the region keeps its outputs
+            # and makes everything else again
+            h, seen = recompute(_LayerFn(self), x, keep=FLASH_KEEP)
         else:
             h, seen = self._block(x)
         self.block_sparse_moe.count(seen)

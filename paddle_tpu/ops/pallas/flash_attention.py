@@ -551,7 +551,7 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     vt = jnp.swapaxes(v, 1, 2)
     out_t, lse = _flash_fwd(qt, kt, vt, None, None, causal, scale,
                             block_q, block_k, window)
-    out = jnp.swapaxes(out_t, 1, 2)
+    out, lse = _kept(jnp.swapaxes(out_t, 1, 2), lse)
     return out, (q, k, v, out, lse)
 
 
@@ -598,7 +598,7 @@ def _fas_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k):
     vt = jnp.swapaxes(v, 1, 2)
     out_t, lse = _flash_fwd(qt, kt, vt, q_seg, kv_seg, causal, scale,
                             block_q, block_k)
-    out = jnp.swapaxes(out_t, 1, 2)
+    out, lse = _kept(jnp.swapaxes(out_t, 1, 2), lse)
     return out, (q, k, v, q_seg, kv_seg, out, lse)
 
 
@@ -621,6 +621,22 @@ def _fas_bwd(causal, scale, block_q, block_k, res, g):
 
 
 flash_attention_pallas_segmented.defvjp(_fas_fwd, _fas_bwd)
+
+
+# The names under which ``_fa_fwd`` and ``_fas_fwd`` hand out the forward
+# kernel's output and its log-sum-exp. They are the residuals the backward
+# rules read, so a rematerialised region that keeps them
+# (``distributed.fleet.recompute(keep=FLASH_KEEP)``) does not run the
+# forward kernel again. Outside such a region a name lowers to nothing.
+# Below the rules, so that no kernel call site above moves a line (source
+# locations are in the kernels' compile-cache key).
+FLASH_KEEP = ("flash_out", "flash_lse")
+
+
+def _kept(out, lse):
+    from jax.ad_checkpoint import checkpoint_name
+    return (checkpoint_name(out, FLASH_KEEP[0]),
+            checkpoint_name(lse, FLASH_KEEP[1]))
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,

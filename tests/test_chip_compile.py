@@ -331,18 +331,23 @@ def test_lfm2_cell_whole_step(one_chip, on_chip):
 
 def test_smallthinker_cell_whole_step(one_chip, on_chip):
     """The SmallThinker cell's whole step (published layers 0-3, one
-    chip's 16 of 64 experts, batch 1 x 16,384, ``use_recompute`` on: each
-    layer's forward pass runs again in the backward pass, so every forward
-    kernel is there twice): layer 0's attention under the old names,
-    layers 1-3 under ``flash_win_*`` with 21 pairs of backward calls each
-    against the global layer's 36; 48 grouped matmuls forward, again under
-    recomputation, and in the vjp; all 43 leaves' gradients under the
-    barrier. 12.9 GB of the chip's 16.9 (without recomputation the
+    chip's 16 of 64 experts, batch 1 x 16,384, ``use_recompute`` on): each
+    layer is a rematerialised region that keeps its flash kernel's output
+    and log-sum-exp by name (``recompute(keep=FLASH_KEEP)``), so every
+    forward kernel is there ONCE (under a bare ``jax.checkpoint`` it was
+    there twice: 2 and 6) while the norms, projections, rotary embedding,
+    router and experts are made again in the backward pass: layer 0's
+    attention under the old names, layers 1-3 under ``flash_win_*`` with
+    21 pairs of backward calls each against the global layer's 36; all 43
+    leaves' gradients under the barrier. The kept 0.48 GB do not raise the
+    peak: 12.9 GB of the chip's 16.9 as before (without recomputation the
     compiler's analysis reads 16.84 GB after rematerialising operations of
     its own choice)."""
     from paddle_tpu.utils import telemetry
     metrics = telemetry.default_tracer().metrics
-    before = metrics.value("attn.flash.window") or 0
+    names = ("attn.flash.window", "recompute.regions",
+             "recompute.regions_keeping")
+    before = {name: metrics.value(name) or 0 for name in names}
     compiled, nbytes, leaves = _cell_step_compiled(
         one_chip, "lm_smallthinker", "smallthinker_21b_a3b_ep4_l4_train",
         "train_b1_s16384")
@@ -352,15 +357,18 @@ def test_smallthinker_cell_whole_step(one_chip, on_chip):
              for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                           "flash_win_fwd", "flash_win_bwd_dq",
                           "flash_win_bwd_dkv")}
-    assert calls == {"flash_fwd": 2, "flash_bwd_dq": 36, "flash_bwd_dkv": 36,
-                     "flash_win_fwd": 6, "flash_win_bwd_dq": 63,
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 36, "flash_bwd_dkv": 36,
+                     "flash_win_fwd": 3, "flash_win_bwd_dq": 63,
                      "flash_win_bwd_dkv": 63}
-    assert nbytes == pytest.approx(12.91e9, rel=0.02) and nbytes < 15.5e9
+    assert nbytes == pytest.approx(12.9e9, rel=0.02) and nbytes < 13.5e9
     assert leaves == 43
+    took = {name: metrics.value(name) - before[name] for name in names}
     # the three window layers' calls counted themselves (a layer is
-    # traced once, recomputation or not)
-    took = metrics.value("attn.flash.window") - before
-    assert took and took % 3 == 0
+    # traced once, recomputation or not), and each of the four layers is
+    # a region that keeps names
+    assert took["attn.flash.window"] and took["attn.flash.window"] % 3 == 0
+    assert took["recompute.regions"] == took["recompute.regions_keeping"] \
+        == took["attn.flash.window"] // 3 * 4
 
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------

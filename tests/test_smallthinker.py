@@ -376,7 +376,8 @@ def test_recompute_trains_the_same_and_the_counters_say_what_ran():
         assert model.lm_head is not None        # untied
         seen = {name: reg.value(name) or 0 for name in (
             "attn.flash.window", "moe.route.pre_attention",
-            "moe.expert.relu")}
+            "moe.expert.relu", "recompute.regions",
+            "recompute.regions_keeping")}
         opt = optimizer.AdamW(learning_rate=1e-3,
                               parameters=model.parameters())
         step = paddle.jit.TrainStep(model, lambda o, l: model.loss(o, l), opt)
@@ -394,6 +395,11 @@ def test_recompute_trains_the_same_and_the_counters_say_what_ran():
         assert reg.value("moe.route.pre_attention") \
             >= seen["moe.route.pre_attention"] + 4
         assert reg.value("moe.expert.relu") >= seen["moe.expert.relu"] + 4
+        # and under recomputation each layer is a region that keeps the
+        # flash kernels' outputs by name; without it there is no region
+        for name in ("recompute.regions", "recompute.regions_keeping"):
+            grew = (reg.value(name) or 0) - seen[name]
+            assert (grew >= 4 and grew % 4 == 0) if rc else grew == 0
     assert np.allclose(losses[True], losses[False], rtol=1e-5)
     assert reg.snapshot()["counters"]["moe.rows_routed"] \
         == counts["rows_routed"]
