@@ -9,9 +9,9 @@ masking with early loop exit.
 
 GQA is handled WITHOUT expanding K/V in HBM: forward and dq kernels read
 the shared kv-head block via index maps (hi // group), and the dk/dv
-kernel accumulates the query-head group in-place by revisiting the same
-output block across the innermost grid dimension — no jnp.repeat, no
-group-expanded HBM traffic.
+kernel walks the kv head's query group in its innermost grid dimension,
+gathering the group's sum in VMEM — no jnp.repeat, no group-expanded HBM
+traffic.
 
 Segment ids (int32, [batch, seq]) restrict attention to tokens of equal
 id — the packed-sequence ("varlen"/"unpadded") training path. Negative or
@@ -20,12 +20,12 @@ output (guarded online softmax, not NaN).
 
 A window (``window`` keys, static; causal attention only) restricts a
 query at position t to the keys s with ``t - window < s <= t``: its own
-key and the ``window - 1`` before it. The three kernels then bound their
-inner loops to the band's blocks (the key loops from below, the query
-loop of dk/dv from above) and mask the band's lower edge beside the
-causal compare, so tiles outside the band are neither fetched nor
-computed; such calls are named ``flash_win_*``. ``window=None`` is the
-program without any of it.
+key and the ``window - 1`` before it. The forward kernel then starts its
+key loop at the band's first block, the backward kernels list only the
+band's blocks among their steps, and a tile the band's lower edge cuts is
+masked beside the causal compare, so tiles outside the band are neither
+fetched nor computed; such calls are named ``flash_win_*``.
+``window=None`` is the program without any of it.
 
 Layout contract (paddle convention at the API): q/k/v [batch, seq, heads,
 head_dim]; kernels internally run [batch, heads, seq, head_dim]. On the
@@ -35,8 +35,12 @@ VMEM budget: the forward kernel holds K and V whole per (batch,
 kv-head), and the pipeline buffers each twice: at seq 16k, d=128, bf16
 that is 16 MB and past the 16 MB a kernel gets by default, so the call
 asks for what it holds (``_vmem_room``; shapes that fit ask for nothing).
-Longer sequences belong to ring attention
-(paddle_tpu.distributed.ring_attention) which shards seq over the mesh.
+The backward pass is one dq call and one dk/dv call over the whole
+sequence: each holds one block of its own side with its float32 gradient
+and streams the other side in chunks of ``_STREAM_BLOCKS`` blocks, about
+9 MB at d=128 whatever the length (``_flash_bwd``). Longer sequences
+belong to ring attention (paddle_tpu.distributed.ring_attention) which
+shards seq over the mesh.
 """
 from __future__ import annotations
 
@@ -50,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
+from ...utils import telemetry
 
 
 def _sds(shape, dtype, like):
@@ -75,24 +80,17 @@ def _band_first_block(q_first, k_first, block_k, num_kv, window):
     return jnp.clip(jax.lax.div(lo, block_k), 0, num_kv)
 
 
-def _band_end_block(k_last, q_first, block_q, num_q, window):
-    """One past the last block of ``block_q`` queries (starting at global
-    position ``q_first``) that holds a query which sees the key at global
-    position ``k_last`` under ``window``: a query below ``k_last +
-    window``."""
-    hi = jnp.maximum(k_last + window - 1 - q_first + block_q, 0)
-    return jnp.clip(jax.lax.div(hi, block_q), 0, num_q)
-
-
-def _vmem_room(resident_bytes):
-    """``pallas_call`` options for a call that holds ``resident_bytes`` of
-    whole-sequence operands, which the pipeline buffers twice: nothing
-    while they fit the VMEM a kernel gets by default, else a limit that
-    holds them."""
+def _vmem_room(resident_bytes, semantics=None):
+    """``pallas_call`` options for a call whose grid has ``semantics``
+    (None: Pallas's default) and that holds ``resident_bytes`` of operand
+    blocks, which the pipeline buffers twice: no VMEM limit while they
+    fit what a kernel gets by default, else one that holds them."""
     need = 2 * resident_bytes + (8 << 20)
-    if need <= 16 << 20:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=need)}
+    params = {} if semantics is None else {"dimension_semantics": semantics}
+    if need > 16 << 20:
+        params["vmem_limit_bytes"] = need
+    return {"compiler_params": pltpu.CompilerParams(**params)} if params \
+        else {}
 
 
 def _names(window):
@@ -219,313 +217,363 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, causal, scale, block_q, block_k,
     return out, lse[..., 0]
 
 
-def _bwd_dq_kernel(*refs, scale, causal, block_k, seq_q, seq_k,
-                   segmented, q_base, k_base, window=None):
+# The backward pass is one dq call and one dk/dv call over the whole
+# sequence. Each keeps one block of its own side in VMEM (dq a block of
+# queries, dk/dv a block of keys) and streams the other side through its
+# grid, this many blocks a step (8,192 rows at the blocks of 512). On the
+# v5e a step costs about a third of a microsecond besides its work, so
+# fewer, longer steps win: the SmallThinker layer's backward pass read
+# 52.4 / 49.0 / 46.7 ms at 4 / 8 / 16 blocks a step, a window layer's
+# 27.4 / 26.0 / 24.8 (1 x 16,384, 28 q / 4 kv heads of 128, bf16).
+_STREAM_BLOCKS = 16
+
+# A grid step's fields in the table ``_bwd_steps`` makes: the kept block
+# (its row) and the chunk of the streamed side it meets; the chunk's
+# blocks [lo, hi) to walk, of which no mask cuts those in [plain_lo,
+# plain_hi); whether the step is its row's first and its last.
+_ROW, _CHUNK, _LO, _PLAIN_LO, _PLAIN_HI, _HI, _FIRST, _LAST = range(8)
+_FIELDS = 8
+
+
+def _chunk_blocks(n):
+    """Blocks a backward step streams in: the most, up to
+    ``_STREAM_BLOCKS``, that divide the side's ``n`` blocks."""
+    return next(c for c in range(min(_STREAM_BLOCKS, n), 0, -1)
+                if n % c == 0)
+
+
+def _dq_spans(nq, nk, bq, bk, base, causal, window):
+    """For each block of queries, (lo, hi, a, b): it sees keys in the key
+    blocks [lo, hi), and no mask cuts the tiles of those in [a, b)."""
+    spans = []
+    for i in range(nq):
+        first = base + i * bq          # the block's global positions
+        last = first + bq - 1
+        lo, hi, a, b = 0, nk, 0, nk
+        if causal:
+            hi = last // bk + 1        # up to the key at `last`
+            b = (first + 1) // bk      # keys at or before `first`
+        if window is not None:
+            lo = (first - window + 1) // bk      # the first key `first` sees
+            a = -(-(last - window + 1) // bk)    # keys `last` sees
+        spans.append(tuple(min(max(x, 0), nk) for x in (lo, hi, a, b)))
+    return spans
+
+
+def _dkv_spans(nk, nq, bk, bq, base, causal, window):
+    """For each block of keys, (lo, hi, a, b): queries in the query blocks
+    [lo, hi) see it, and no mask cuts the tiles of those in [a, b)."""
+    spans = []
+    for j in range(nk):
+        first = j * bk - base          # the block's keys as query positions
+        last = first + bk - 1
+        lo, hi, a, b = 0, nq, 0, nq
+        if causal:
+            lo = first // bq           # from the query at `first`
+            a = -(-last // bq)         # queries at or after `last`
+        if window is not None:
+            hi = (last + window - 1) // bq + 1   # the last query `last` has
+            b = (first + window - bq) // bq + 1  # queries that see `first`
+        spans.append(tuple(min(max(x, 0), nq) for x in (lo, hi, a, b)))
+    return spans
+
+
+def _bwd_steps(spans, cb):
+    """The grid steps of one backward call as a flat int32 table,
+    ``_FIELDS`` a step: each row walks the chunks of ``cb`` blocks that
+    meet its span, in order, and nothing else; a row that sees nothing is
+    one step that walks no block, so that its gradient is still written
+    (zeros)."""
+    table = []
+    for row, (lo, hi, a, b) in enumerate(spans):
+        chunks = range(lo // cb, (hi - 1) // cb + 1) if hi > lo else [0]
+        for n, c in enumerate(chunks):
+            c_lo, c_hi = (max(lo, c * cb), min(hi, c * cb + cb)) \
+                if hi > lo else (0, 0)
+            p_lo = min(max(a, c_lo), c_hi)
+            p_hi = min(max(b, p_lo), c_hi)
+            table += [row, c, c_lo, p_lo, p_hi, c_hi, n == 0,
+                      n == len(chunks) - 1]
+    return np.asarray(table, np.int32)
+
+
+def _mask(s, queries, keys, causal, window):
+    """Scores ``s`` of query positions ``queries`` against key positions
+    ``keys`` under the causal compare and the band's lower edge."""
+    if causal:
+        s = jnp.where(queries >= keys, s, _NEG_INF)
+    if window is not None:
+        s = jnp.where(keys > queries - window, s, _NEG_INF)
+    return s
+
+
+def _walk(steps, t, tile, carry, causal, window):
+    """``tile(block, carry, mask)`` over the blocks step ``t`` walks, in
+    order: ``mask`` applies ``_mask`` to a tile the causal diagonal or the
+    band's lower edge cuts, and is None for the others."""
+    at = lambda f: steps[_FIELDS * t + f]
+    if not causal and window is None:
+        return jax.lax.fori_loop(at(_LO), at(_HI),
+                                 functools.partial(tile, mask=None), carry)
+    mask = functools.partial(_mask, causal=causal, window=window)
+    for lo, hi, cut in ((_LO, _PLAIN_LO, mask), (_PLAIN_LO, _PLAIN_HI, None),
+                        (_PLAIN_HI, _HI, mask)):
+        carry = jax.lax.fori_loop(at(lo), at(hi),
+                                  functools.partial(tile, mask=cut), carry)
+    return carry
+
+
+def _bwd_dq_kernel(steps, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   *refs, scale, causal, bq, bk, cb, base, segmented,
+                   window=None):
+    """Grid (b, h, steps): a block of queries against a chunk of ``cb``
+    key blocks. The block's dq gathers in a float32 scratch over its
+    steps and is written once, at its last."""
     if segmented:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dq_ref) = refs
+        qseg_ref, kseg_ref, dq_ref, acc = refs
     else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+        dq_ref, acc = refs
+    t = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)                     # [bq, d]
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, 0]                               # [bq]
-    delta = delta_ref[0, 0, :, 0]                           # [bq]
-    bq = q.shape[0]
-    qi = pl.program_id(2)
-    q_offset = qi * bq
+    lse = lse_ref[0, 0, 0][:, None]                         # [bq, 1]
+    delta = delta_ref[0, 0, 0][:, None]
+    q_first = base + steps[_FIELDS * t + _ROW] * bq        # global
+    k_chunk = steps[_FIELDS * t + _CHUNK] * cb
     if segmented:
         qseg = qseg_ref[0]
 
-    # q_base/k_base: GLOBAL sequence positions of this call's first
-    # query/key row — the wrapper may be feeding a [q-chunk, k-chunk]
-    # slice of a longer sequence (VMEM-bounded long-seq backward)
-    num_kv = pl.cdiv(seq_k, block_k)
-    if causal:
-        num_kv_run = jnp.clip(
-            jax.lax.div(q_base + q_offset + bq - 1 - k_base, block_k)
-            + 1, 0, num_kv)
-    else:
-        num_kv_run = num_kv
-    first_kv = 0
-    if window is not None:
-        first_kv = _band_first_block(q_base + q_offset, k_base, block_k,
-                                     num_kv, window)
-
-    def body(kj, dq):
-        k_blk = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
+    def tile(kj, dq, mask):
+        off = (kj - k_chunk) * bk
+        k_blk = k_ref[0, 0, pl.ds(off, bk), :].astype(jnp.float32)
+        v_blk = v_ref[0, 0, pl.ds(off, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal or window is not None:
-            rows = q_base + q_offset + \
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_base + kj * block_k + \
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if causal:
-                s = jnp.where(rows >= cols, s, _NEG_INF)
-            if window is not None:
-                s = jnp.where(cols > rows - window, s, _NEG_INF)
+        if mask is not None:
+            s = mask(s, q_first + jax.lax.broadcasted_iota(jnp.int32,
+                                                          s.shape, 0),
+                     kj * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                        s.shape, 1))
         if segmented:
-            kseg = kseg_ref[0, pl.ds(kj * block_k, block_k)]
+            kseg = kseg_ref[0, pl.ds(off, bk)]
             s = jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
         p = jnp.where(s > _NEG_INF * 0.5,
-                      jnp.exp(s - lse[:, None]), 0.0)        # [bq, bk]
+                      jnp.exp(s - lse), 0.0)                 # [bq, bk]
         dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale               # [bq, bk]
+        ds = p * (dp - delta) * scale                        # [bq, bk]
         return dq + jax.lax.dot_general(ds, k_blk, (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
 
-    dq0 = jnp.zeros_like(q)
-    dq = jax.lax.fori_loop(first_kv, num_kv_run, body, dq0)
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    @pl.when(steps[_FIELDS * t + _FIRST] == 1)
+    def _init():
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    acc[...] = _walk(steps, t, tile, acc[...], causal, window)
+
+    @pl.when(steps[_FIELDS * t + _LAST] == 1)
+    def _write():
+        dq_ref[0, 0] = acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, block_q, seq_q, seq_k, group,
-                    segmented, q_base, k_base, window=None):
-    """Grid (b, hk, n_kblocks, group): the innermost `group` dimension
-    revisits the same dk/dv output block, accumulating the kv-head's query
-    group in VMEM (GQA without expanding K/V or group-partial HBM writes)."""
+def _bwd_dkv_kernel(steps, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    *refs, scale, causal, bq, bk, cb, base, group,
+                    segmented, window=None):
+    """Grid (b, hk, steps, group): a block of keys against a chunk of
+    ``cb`` query blocks of one query head of its group (GQA without
+    expanding K/V), so the block's dk and dv gather over its steps and
+    its group's heads in float32 scratch and are written once, at the
+    last. A tile is computed transposed, keys down and queries across:
+    lse and delta are then rows as they are stored, and dv, dk plain
+    products."""
     if segmented:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dk_ref, dv_ref) = refs
+        qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref) = refs
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
+    t, gi = pl.program_id(2), pl.program_id(3)
     k_blk = k_ref[0, 0].astype(jnp.float32)                  # [bk, d]
     v_blk = v_ref[0, 0].astype(jnp.float32)
-    bk = k_blk.shape[0]
-    kj = pl.program_id(2)
-    gi = pl.program_id(3)
-    k_offset = kj * bk
+    k_first = steps[_FIELDS * t + _ROW] * bk                 # global
+    q_chunk = steps[_FIELDS * t + _CHUNK] * cb
     if segmented:
-        kseg = kseg_ref[0, pl.ds(k_offset, bk)]
+        kseg = kseg_ref[0][:, None]                          # [bk, 1]
 
-    num_q = pl.cdiv(seq_q, block_q)
-    if causal:
-        # first q block whose END global position can see this k block
-        first_q = jax.lax.div(
-            jnp.maximum(k_base + k_offset - q_base, 0), block_q)
-    else:
-        first_q = 0
-    end_q = num_q
-    if window is not None:
-        end_q = _band_end_block(k_base + k_offset + bk - 1, q_base, block_q,
-                                num_q, window)
-
-    def body(qi, carry):
+    def tile(qi, carry, mask):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q), 0]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+        off = (qi - q_chunk) * bq
+        q = q_ref[0, 0, pl.ds(off, bq), :].astype(jnp.float32)
+        do = do_ref[0, 0, pl.ds(off, bq), :].astype(jnp.float32)
+        lse = lse_ref[0, 0, :, pl.ds(off, bq)]               # [1, bq]
+        delta = delta_ref[0, 0, :, pl.ds(off, bq)]
+        s = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal or window is not None:
-            rows = q_base + qi * block_q + \
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_base + k_offset + \
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if causal:
-                s = jnp.where(rows >= cols, s, _NEG_INF)
-            if window is not None:
-                s = jnp.where(cols > rows - window, s, _NEG_INF)
+        if mask is not None:
+            s = mask(s, base + qi * bq + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1),
+                k_first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
         if segmented:
-            qseg = qseg_ref[0, pl.ds(qi * block_q, block_q)]
-            s = jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
+            qseg = qseg_ref[:, pl.ds(off, bq)]               # [1, bq]
+            s = jnp.where(kseg == qseg, s, _NEG_INF)
         p = jnp.where(s > _NEG_INF * 0.5,
-                      jnp.exp(s - lse[:, None]), 0.0)        # [bq, bk]
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                      jnp.exp(s - lse), 0.0)                 # [bk, bq]
+        dv = dv + jax.lax.dot_general(p, do, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+        ds = p * (dp - delta) * scale
+        dk = dk + jax.lax.dot_general(ds, q, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         return dk, dv
 
-    d = k_blk.shape[-1]
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_q, end_q, body, (dk0, dv0))
-
-    @pl.when(gi == 0)
+    @pl.when((steps[_FIELDS * t + _FIRST] == 1) & (gi == 0))
     def _init():
-        dk_ref[0, 0] = dk
-        dv_ref[0, 0] = dv
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    @pl.when(gi > 0)
-    def _accum():
-        dk_ref[0, 0] += dk
-        dv_ref[0, 0] += dv
+    dk, dv = _walk(steps, t, tile, (dk_acc[...], dv_acc[...]), causal,
+                   window)
+    dk_acc[...] = dk
+    dv_acc[...] = dv
 
-
-@functools.partial(
-    jax.jit, inline=True,
-    static_argnames=("causal", "scale", "bq", "bk", "group", "q_base",
-                     "k_base", "dq_dtype", "window", "names", "interpret"))
-def _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg, *, causal,
-                   scale, bq, bk, group, q_base, k_base, dq_dtype,
-                   window, names, interpret):
-    """dq + dk/dv pallas calls for one (q-slice, k-slice) pair whose
-    first rows sit at GLOBAL positions q_base/k_base. ``window`` is None
-    for a pair the band's lower edge does not cut; ``names`` are the
-    whole attention's.
-
-    An inlined ``jit``: pairs of the same shapes and static arguments
-    are traced once (at 16,384 tokens a layer has 36 pairs, 28 of them
-    alike) and each call still lands in the caller's program as its own
-    two kernels, under the caller's scope."""
-    b, h, sq, d = q.shape
-    hk, sk = k.shape[1], k.shape[2]
-    segmented = q_seg is not None
-
-    dq_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, sk, d),
-                     lambda bi, hi, qi, _g=group: (bi, hi // _g, 0, 0)),
-        pl.BlockSpec((1, 1, sk, d),
-                     lambda bi, hi, qi, _g=group: (bi, hi // _g, 0, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-    ]
-    dq_args = [q, k, v, do, lse4, delta]
-    if segmented:
-        dq_specs += [
-            pl.BlockSpec((1, bq), lambda bi, hi, qi: (bi, qi)),
-            pl.BlockSpec((1, sk), lambda bi, hi, qi: (bi, 0)),
-        ]
-        dq_args += [q_seg, kv_seg]
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=bk, seq_q=sq, seq_k=sk,
-                          segmented=segmented, q_base=q_base,
-                          k_base=k_base, window=window),
-        grid=(b, h, pl.cdiv(sq, bq)),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bi, hi, qi: (bi, hi, qi, 0)),
-        out_shape=_sds((b, h, sq, d), dq_dtype, q),
-        interpret=interpret,
-        name=names[1],
-    )(*dq_args)
-
-    # dk/dv: grid (b, hk, kblocks, group); q-head = hk_index*group + g
-    def qmap(bi, hki, kj, g, _g=group):
-        return (bi, hki * _g + g, 0, 0)
-
-    dkv_specs = [
-        pl.BlockSpec((1, 1, sq, d), qmap),
-        pl.BlockSpec((1, 1, bk, d), lambda bi, hki, kj, g: (bi, hki, kj, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda bi, hki, kj, g: (bi, hki, kj, 0)),
-        pl.BlockSpec((1, 1, sq, d), qmap),
-        pl.BlockSpec((1, 1, sq, 1), qmap),
-        pl.BlockSpec((1, 1, sq, 1), qmap),
-    ]
-    dkv_args = [q, k, v, do, lse4, delta]
-    if segmented:
-        dkv_specs += [
-            pl.BlockSpec((1, sq), lambda bi, hki, kj, g: (bi, 0)),
-            pl.BlockSpec((1, sk), lambda bi, hki, kj, g: (bi, 0)),
-        ]
-        dkv_args += [q_seg, kv_seg]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, seq_q=sq, seq_k=sk, group=group,
-                          segmented=segmented, q_base=q_base,
-                          k_base=k_base, window=window),
-        grid=(b, hk, pl.cdiv(sk, bk), group),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bi, hki, kj, g: (bi, hki, kj, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bi, hki, kj, g: (bi, hki, kj, 0)),
-        ],
-        out_shape=[
-            _sds((b, hk, sk, d), jnp.float32, q),
-            _sds((b, hk, sk, d), jnp.float32, q),
-        ],
-        interpret=interpret,
-        name=names[2],
-    )(*dkv_args)
-    return dq, dk, dv
-
-
-# backward VMEM story: each dq call holds its k-slice (and each dkv call
-# its q-slice) whole in VMEM, so slices past ~2k at d=128 blow the
-# ~16MB scoped-vmem budget (measured: a 4096 slice needs 16.6MB).
-# Above this length the wrapper tiles the backward into
-# [q-chunk, k-chunk] pair calls (global offsets keep the causal mask
-# exact; fully-invisible pairs are skipped outright).
-BWD_SEQ_CHUNK = 2048
-
-
-def _bwd_pairs(sq, sk, causal, window):
-    """The [q-chunk, k-chunk] pairs the backward pass calls its kernels
-    on, in order: (q0, qe, k0, ke, whether the causal diagonal cuts the
-    pair, the window if the band's lower edge does, else None). A pair
-    that holds no visible (query, key) is left out."""
-    cs = BWD_SEQ_CHUNK
-    base = sk - sq     # causal aligns queries to the END of the keys
-    for q0 in range(0, sq, cs):
-        qe = min(q0 + cs, sq)
-        for k0 in range(0, sk, cs):
-            ke = min(k0 + cs, sk)
-            if causal and k0 > base + qe - 1:
-                continue                       # fully invisible pair
-            if window is not None and ke - 1 <= base + q0 - window:
-                continue                       # wholly below the band
-            yield (q0, qe, k0, ke, causal and (ke - 1 > base + q0),
-                   window if window is not None
-                   and k0 <= base + qe - 1 - window else None)
+    @pl.when((steps[_FIELDS * t + _LAST] == 1) & (gi == group - 1))
+    def _write():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, do, q_seg, kv_seg, causal, scale,
-               block_q, block_k, window=None):
-    """q/do [b,h,sq,d]; k/v [b,hk,sk,d] (NOT expanded). Returns dq [b,h,..]
-    and group-summed dk/dv [b,hk,sk,d] (float32)."""
+               block_q, block_k, window=None, dkv_dtype=jnp.float32):
+    """q/do [b,h,sq,d]; k/v [b,hk,sk,d] (NOT expanded); lse [b,h,sq].
+    Returns dq [b,h,sq,d] in q's dtype and dk/dv [b,hk,sk,d], summed over
+    each kv head's query group, in ``dkv_dtype``.
+
+    One dq call and one dk/dv call, each over the whole sequence. dq keeps
+    a block of queries with its do, lse and delta and streams K and V;
+    dk/dv keeps a block of keys with its V and streams q, do, lse and
+    delta of each query head of the group. A step brings in a chunk of
+    ``_STREAM_BLOCKS`` blocks; the steps are listed at trace time
+    (``_bwd_steps``) and reach the index maps as a prefetched table, so
+    only chunks that hold a visible pair are fetched and, of those, only
+    such blocks are walked. VMEM holds the kept block, its float32
+    gradient and two buffers of each streamed chunk: about 9 MB at d=128
+    and blocks of 512, whatever the length."""
     b, h, sq, d = q.shape
     hk, sk = k.shape[1], k.shape[2]
     group = h // hk
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1)[..., None]                      # [b,h,sq,1]
-    lse4 = lse[..., None]                                    # [b,h,sq,1]
-
-    cs = BWD_SEQ_CHUNK
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    nq, nk = pl.cdiv(sq, bq), pl.cdiv(sk, bk)
     base = sk - sq     # causal aligns queries to the END of the keys
-    static = dict(scale=scale, group=group, names=_names(window),
-                  interpret=_interpret())
-    if sq <= cs and sk <= cs:
-        return _bwd_pair_call(q, k, v, do, lse4, delta, q_seg, kv_seg,
-                              causal=causal, bq=bq, bk=bk, q_base=base,
-                              k_base=0, dq_dtype=q.dtype, window=window,
-                              **static)
+    segmented = q_seg is not None
+    # lse and delta as rows [b, h, 1, sq]: a block of either is lane-dense
+    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1)[:, :, None]
+    lse = lse[:, :, None]
+    names = _names(window)
+    calls = telemetry.default_tracer().metrics
 
-    dq = jnp.zeros((b, h, sq, d), jnp.float32)
-    dk = jnp.zeros((b, hk, sk, d), jnp.float32)
-    dv = jnp.zeros((b, hk, sk, d), jnp.float32)
-    for q0, qe, k0, ke, pair_causal, pair_window in _bwd_pairs(
-            sq, sk, causal, window):
-        # a pair that neither mask cuts never reads its positions: given
-        # as 0, all such pairs are one trace
-        cut = pair_causal or pair_window is not None
-        dq_p, dk_p, dv_p = _bwd_pair_call(
-            q[:, :, q0:qe], k[:, :, k0:ke], v[:, :, k0:ke],
-            do[:, :, q0:qe], lse4[:, :, q0:qe],
-            delta[:, :, q0:qe],
-            None if q_seg is None else q_seg[:, q0:qe],
-            None if kv_seg is None else kv_seg[:, k0:ke],
-            causal=pair_causal, bq=min(bq, qe - q0), bk=min(bk, ke - k0),
-            q_base=base + q0 if cut else 0, k_base=k0 if cut else 0,
-            dq_dtype=jnp.float32, window=pair_window, **static)
-        dq = dq.at[:, :, q0:qe].add(dq_p)
-        dk = dk.at[:, :, k0:ke].add(dk_p)
-        dv = dv.at[:, :, k0:ke].add(dv_p)
-    return dq.astype(q.dtype), dk, dv
+    # dq: grid (b, h, steps); a step's row is a query block, its chunk
+    # ck keys
+    cb = _chunk_blocks(nk)
+    ck = cb * bk
+    steps = _bwd_steps(_dq_spans(nq, nk, bq, bk, base, causal, window), cb)
+
+    def q_rows(bi, hi, t, st):
+        return (bi, hi, st[_FIELDS * t + _ROW], 0)
+
+    def kv_chunk(bi, hi, t, st):
+        return (bi, hi // group, st[_FIELDS * t + _CHUNK], 0)
+
+    def q_stats(bi, hi, t, st):
+        return (bi, hi, 0, st[_FIELDS * t + _ROW])
+
+    in_specs = [
+        pl.BlockSpec((1, 1, bq, d), q_rows),
+        pl.BlockSpec((1, 1, ck, d), kv_chunk),
+        pl.BlockSpec((1, 1, ck, d), kv_chunk),
+        pl.BlockSpec((1, 1, bq, d), q_rows),
+        pl.BlockSpec((1, 1, 1, bq), q_stats),
+        pl.BlockSpec((1, 1, 1, bq), q_stats),
+    ]
+    args = [q, k, v, do, lse, delta]
+    if segmented:
+        in_specs += [
+            pl.BlockSpec((1, bq), lambda bi, hi, t, st:
+                         (bi, st[_FIELDS * t + _ROW])),
+            pl.BlockSpec((1, ck), lambda bi, hi, t, st:
+                         (bi, st[_FIELDS * t + _CHUNK])),
+        ]
+        args += [q_seg, kv_seg]
+    calls.inc("attn.flash.bwd_calls")
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq,
+                          bk=bk, cb=cb, base=base, segmented=segmented,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, len(steps) // _FIELDS),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, d), q_rows),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=_sds((b, h, sq, d), q.dtype, q),
+        **_vmem_room(2 * (ck + bq) * d * q.dtype.itemsize,
+                     ("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name=names[1],
+    )(jnp.asarray(steps), *args)
+
+    # dk/dv: grid (b, hk, steps, group); a step's row is a key block, its
+    # chunk cq queries of query head hk_index * group + g
+    cb = _chunk_blocks(nq)
+    cq = cb * bq
+    steps = _bwd_steps(_dkv_spans(nk, nq, bk, bq, base, causal, window), cb)
+
+    def q_chunk(bi, hki, t, g, st):
+        return (bi, hki * group + g, st[_FIELDS * t + _CHUNK], 0)
+
+    def k_rows(bi, hki, t, g, st):
+        return (bi, hki, st[_FIELDS * t + _ROW], 0)
+
+    def q_chunk_stats(bi, hki, t, g, st):
+        return (bi, hki * group + g, 0, st[_FIELDS * t + _CHUNK])
+
+    in_specs = [
+        pl.BlockSpec((1, 1, cq, d), q_chunk),
+        pl.BlockSpec((1, 1, bk, d), k_rows),
+        pl.BlockSpec((1, 1, bk, d), k_rows),
+        pl.BlockSpec((1, 1, cq, d), q_chunk),
+        pl.BlockSpec((1, 1, 1, cq), q_chunk_stats),
+        pl.BlockSpec((1, 1, 1, cq), q_chunk_stats),
+    ]
+    args = [q, k, v, do, lse, delta]
+    if segmented:
+        in_specs += [
+            pl.BlockSpec((1, cq), lambda bi, hki, t, g, st:
+                         (bi, st[_FIELDS * t + _CHUNK])),
+            pl.BlockSpec((1, bk), lambda bi, hki, t, g, st:
+                         (bi, st[_FIELDS * t + _ROW])),
+        ]
+        args += [q_seg, kv_seg]
+    calls.inc("attn.flash.bwd_calls")
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                          bq=bq, bk=bk, cb=cb, base=base, group=group,
+                          segmented=segmented, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hk, len(steps) // _FIELDS, group),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, bk, d), k_rows),
+                       pl.BlockSpec((1, 1, bk, d), k_rows)],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)]),
+        out_shape=[_sds((b, hk, sk, d), dkv_dtype, q),
+                   _sds((b, hk, sk, d), dkv_dtype, q)],
+        **_vmem_room(2 * (cq + bk) * d * q.dtype.itemsize,
+                     ("parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name=names[2],
+    )(jnp.asarray(steps), *args)
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -565,9 +613,10 @@ def _fa_bwd(causal, scale, block_q, block_k, window, res, g):
     out_t = jnp.swapaxes(out, 1, 2)
     do_t = jnp.swapaxes(g, 1, 2)
     dq_t, dk_t, dv_t = _flash_bwd(qt, kt, vt, out_t, lse, do_t, None, None,
-                                  causal, scale, block_q, block_k, window)
-    dq = jnp.swapaxes(dq_t, 1, 2).astype(q.dtype)
-    dk = jnp.swapaxes(dk_t, 1, 2).astype(k.dtype)
+                                  causal, scale, block_q, block_k, window,
+                                  dkv_dtype=k.dtype)
+    dq = jnp.swapaxes(dq_t, 1, 2)
+    dk = jnp.swapaxes(dk_t, 1, 2)
     dv = jnp.swapaxes(dv_t, 1, 2).astype(v.dtype)
     return dq, dk, dv
 
@@ -612,9 +661,10 @@ def _fas_bwd(causal, scale, block_q, block_k, res, g):
     out_t = jnp.swapaxes(out, 1, 2)
     do_t = jnp.swapaxes(g, 1, 2)
     dq_t, dk_t, dv_t = _flash_bwd(qt, kt, vt, out_t, lse, do_t, q_seg,
-                                  kv_seg, causal, scale, block_q, block_k)
-    dq = jnp.swapaxes(dq_t, 1, 2).astype(q.dtype)
-    dk = jnp.swapaxes(dk_t, 1, 2).astype(k.dtype)
+                                  kv_seg, causal, scale, block_q, block_k,
+                                  dkv_dtype=k.dtype)
+    dq = jnp.swapaxes(dq_t, 1, 2)
+    dk = jnp.swapaxes(dk_t, 1, 2)
     dv = jnp.swapaxes(dv_t, 1, 2).astype(v.dtype)
     zseg = lambda s: np.zeros(s.shape, jax.dtypes.float0)
     return dq, dk, dv, zseg(q_seg), zseg(kv_seg)

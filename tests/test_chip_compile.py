@@ -57,6 +57,14 @@ def on_chip(monkeypatch):
     compilation_cache.reset_cache()
 
 
+def _calls(text, stem, names):
+    """Instructions of the compiled ``text`` by kernel name (alone under
+    jax.grad they are %jvp_flash_fwd_.1, %transpose_jvp_flash_bwd_dq__.10,
+    ...)."""
+    return {name: len(re.findall(rf"{stem}{name}[_.\d]* = ", text))
+            for name in names}
+
+
 def _kernels_in(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text().count(
         "tpu_custom_call")
@@ -100,16 +108,23 @@ def test_flash_attention_fwd_bwd_llama_mid(one_chip, on_chip):
         return flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
-    # forward, dq, dk/dv
-    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+    # forward, one dq and one dk/dv call, and no partial gradient added
+    # up outside them
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert _calls(text, "flash_", ("fwd", "bwd_dq", "bwd_dkv")) == {
+        "fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert "scatter" not in text
 
 
 def test_flash_attention_fwd_bwd_head_dim_64(one_chip, on_chip):
     """LFM2-8B-A1B's attention at the benchmark cell's shape: 32 q / 8 kv
     heads of 64, 2 x 8192. The chip's compiler takes all three kernels at
     a head of 64 (the PAGED kernel's gate on 64 stands: below); the
-    backward pass walks the 8192 positions in 2048 x 2048 pairs, ten
-    under the diagonal, so dq and dk/dv are ten calls each."""
+    backward pass is one dq call and one dk/dv call over the 8192
+    positions (ten of each, and a scatter-add of their partial sums into
+    whole-sequence float32 buffers, while it walked 2048 x 2048 pairs)."""
     from paddle_tpu.ops.flash_attention import flash_attention
     q = _sds(one_chip, (2, 8192, 32, 64), BF16)
     kv = _sds(one_chip, (2, 8192, 8, 64), BF16)
@@ -120,28 +135,26 @@ def test_flash_attention_fwd_bwd_head_dim_64(one_chip, on_chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    # alone under jax.grad the instructions are %jvp_flash_fwd_.1,
-    # %transpose_jvp_flash_bwd_dq__.10, ...
-    calls = {name: len(re.findall(rf"{name}[_.\d]* = ", text))
-             for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
-    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 10,
-                     "flash_bwd_dkv": 10}
-    assert text.count("tpu_custom_call") == 21
+    assert _calls(text, "flash_", ("fwd", "bwd_dq", "bwd_dkv")) == {
+        "fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert text.count("tpu_custom_call") == 3
+    assert "scatter" not in text
 
 
-@pytest.mark.parametrize("window,stem,pairs", [(None, "flash", 36),
-                                               (4096, "flash_win", 21)])
+@pytest.mark.parametrize("window,stem", [(None, "flash"),
+                                         (4096, "flash_win")])
 def test_flash_attention_fwd_bwd_smallthinker_heads(one_chip, on_chip,
-                                                    window, stem, pairs):
+                                                    window, stem):
     """SmallThinker-21BA3B's attention at the benchmark cell's shape: 28 q
     / 4 kv heads of 128 (groups of seven through dk/dv's ``group``), 1 x
     16,384, without a window (layer 0) and under 4,096 keys (layers 1-3).
     The forward kernel holds K and V of all 16,384 positions, twice 8 MB
     with the pipeline's second buffer, and asks for that VMEM
     (``_vmem_room``: the default 16 MB refuses it by 0.75 MB). The
-    backward pass walks [2048, 2048] pairs: 36 under the diagonal, of
-    which the band keeps 21 (8 on the diagonal, 7 whole, 6 cut by the
-    band's lower edge), and the windowed calls carry their own names."""
+    backward pass is one dq call and one dk/dv call, each streaming the
+    other side four blocks of 512 a step through the default VMEM (36
+    and 21 calls of each while it walked [2048, 2048] pairs), and the
+    windowed calls carry their own names."""
     from paddle_tpu.ops.flash_attention import flash_attention
     q = _sds(one_chip, (1, 16384, 28, 128), BF16)
     kv = _sds(one_chip, (1, 16384, 4, 128), BF16)
@@ -152,10 +165,10 @@ def test_flash_attention_fwd_bwd_smallthinker_heads(one_chip, on_chip,
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    calls = {name: len(re.findall(rf"{stem}_{name}[_.\d]* = ", text))
-             for name in ("fwd", "bwd_dq", "bwd_dkv")}
-    assert calls == {"fwd": 1, "bwd_dq": pairs, "bwd_dkv": pairs}
-    assert text.count("tpu_custom_call") == 1 + 2 * pairs
+    assert _calls(text, f"{stem}_", ("fwd", "bwd_dq", "bwd_dkv")) == {
+        "fwd": 1, "bwd_dq": 1, "bwd_dkv": 1}
+    assert text.count("tpu_custom_call") == 3
+    assert "scatter" not in text
     other = "flash_win_fwd" if window is None else "flash_fwd"
     assert not re.findall(rf"[(_%]{other}[_.\d]* = ", text)
 
@@ -197,6 +210,16 @@ def test_head_and_dense_loss_at_the_training_cell_shape(one_chip, on_chip):
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2 * logits_bytes + (64 << 20)
     assert ".remat" not in compiled.as_text()
+
+
+def _partial_sums_into(text, shapes):
+    """The instructions of the compiled ``text`` that add or write partial
+    sums into a float32 array of one of ``shapes`` (XLA's lowering of
+    ``.at[...].add``): what a backward pass that walked pairs of calls
+    left to XLA."""
+    return [line for line in text.splitlines()
+            if any(f"f32[{shape}]" in line for shape in shapes)
+            and ("scatter" in line or "dynamic-update-slice" in line)]
 
 
 def _cell_step_compiled(sharding, family, config, traffic):
@@ -321,8 +344,9 @@ def test_lfm2_cell_whole_step(one_chip, on_chip):
     calls = {name: len(re.findall(rf"%{name}[.\d]* = ", text))
              for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                           "ragged-dot-none")}
-    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 10,
-                     "flash_bwd_dkv": 10, "ragged-dot-none": 48}
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 1,
+                     "flash_bwd_dkv": 1, "ragged-dot-none": 48}
+    assert not _partial_sums_into(text, ("2,32,8192,64", "2,8,8192,64"))
     assert compiled.cost_analysis()["flops"] == pytest.approx(26.892e12,
                                                               rel=0.01)
     assert nbytes == pytest.approx(14.88e9, rel=0.01) and nbytes < 15.5e9
@@ -337,16 +361,20 @@ def test_smallthinker_cell_whole_step(one_chip, on_chip):
     forward kernel is there ONCE (under a bare ``jax.checkpoint`` it was
     there twice: 2 and 6) while the norms, projections, rotary embedding,
     router and experts are made again in the backward pass: layer 0's
-    attention under the old names, layers 1-3 under ``flash_win_*`` with
-    21 pairs of backward calls each against the global layer's 36; all 43
-    leaves' gradients under the barrier. The kept 0.48 GB do not raise the
-    peak: 12.9 GB of the chip's 16.9 as before (without recomputation the
-    compiler's analysis reads 16.84 GB after rematerialising operations of
-    its own choice)."""
+    attention under the old names, layers 1-3 under ``flash_win_*``, each
+    layer's backward pass one dq call and one dk/dv call (36 and 21 pairs
+    of calls, their float32 partial sums added up by XLA, before the
+    calls streamed the sequence); all 43 leaves' gradients under the
+    barrier. 13.34 GB of the chip's 16.9 (12.90 while the backward pass
+    walked pairs: the peak was then in layer 0's backward attention, and
+    is now in layer 3's expert backward, where XLA holds the four layers'
+    kept flash outputs; without recomputation the compiler's analysis
+    reads 16.84 GB after rematerialising operations of its own
+    choice)."""
     from paddle_tpu.utils import telemetry
     metrics = telemetry.default_tracer().metrics
-    names = ("attn.flash.window", "recompute.regions",
-             "recompute.regions_keeping")
+    names = ("attn.flash.window", "attn.flash.bwd_calls",
+             "recompute.regions", "recompute.regions_keeping")
     before = {name: metrics.value(name) or 0 for name in names}
     compiled, nbytes, leaves = _cell_step_compiled(
         one_chip, "lm_smallthinker", "smallthinker_21b_a3b_ep4_l4_train",
@@ -357,10 +385,11 @@ def test_smallthinker_cell_whole_step(one_chip, on_chip):
              for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                           "flash_win_fwd", "flash_win_bwd_dq",
                           "flash_win_bwd_dkv")}
-    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 36, "flash_bwd_dkv": 36,
-                     "flash_win_fwd": 3, "flash_win_bwd_dq": 63,
-                     "flash_win_bwd_dkv": 63}
-    assert nbytes == pytest.approx(12.9e9, rel=0.02) and nbytes < 13.5e9
+    assert calls == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                     "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
+                     "flash_win_bwd_dkv": 3}
+    assert not _partial_sums_into(text, ("1,28,16384,128", "1,4,16384,128"))
+    assert nbytes == pytest.approx(13.34e9, rel=0.01) and nbytes < 13.5e9
     assert leaves == 43
     took = {name: metrics.value(name) - before[name] for name in names}
     # the three window layers' calls counted themselves (a layer is
@@ -369,6 +398,8 @@ def test_smallthinker_cell_whole_step(one_chip, on_chip):
     assert took["attn.flash.window"] and took["attn.flash.window"] % 3 == 0
     assert took["recompute.regions"] == took["recompute.regions_keeping"] \
         == took["attn.flash.window"] // 3 * 4
+    # and each layer's backward pass made its two calls: 8 a trace
+    assert took["attn.flash.bwd_calls"] == took["attn.flash.window"] // 3 * 8
 
 
 # -- the gate: what the chip's compiler refuses never reaches it ------------

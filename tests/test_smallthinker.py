@@ -42,35 +42,58 @@ def _qkv(seq, h, hk, d=8, b=2, seed=0):
                                               jnp.float32)
 
 
-# (seq, window, block, the backward pass's chunk, q heads, kv heads)
+# (seq, window, block, q heads, kv heads, head size); the backward pass
+# streams sixteen blocks a step, so a sequence of more than sixteen
+# blocks is walked in several chunks
 WINDOW_CASES = [
-    (128, 40, 32, None, 4, 2),      # smaller than the sequence, no
+    (128, 40, 32, 4, 2, 8),         # smaller than the sequence, no
                                     # multiple of the block
-    (128, 32, 32, None, 4, 2),      # one block
-    (128, 128, 32, None, 4, 2),     # the sequence itself
-    (128, 200, 32, None, 4, 2),     # larger than the sequence
-    (128, 1, 32, None, 4, 2),       # a query sees itself alone
-    (128, 40, 32, None, 7, 1),      # SmallThinker's group of seven
-    (256, 40, 32, 64, 7, 1),        # the backward pass in [q, k] chunk
-    (256, 100, 32, 64, 4, 2),       # pairs: a window inside a chunk,
-    (256, 64, 32, 64, 4, 2),        # across two, at a chunk's length
-    (256, 77, 64, 128, 4, 4),
+    (128, 32, 32, 4, 2, 8),         # one block
+    (128, 128, 32, 4, 2, 8),        # the sequence itself
+    (128, 200, 32, 4, 2, 8),        # larger than the sequence
+    (128, 1, 32, 4, 2, 8),          # a query sees itself alone
+    (128, 40, 32, 7, 1, 8),         # SmallThinker's group of seven
+    (256, 40, 8, 7, 1, 8),          # 32 blocks: two chunks
+    (256, 100, 32, 4, 2, 8),        # wider than a block
+    (256, 64, 32, 4, 2, 8),         # two blocks
+    (256, 77, 64, 4, 4, 8),         # blocks of 64: one chunk
+    (1024, 200, 16, 7, 1, 8),       # 64 blocks, 4 chunks: the band
+                                    # wider than a block, far narrower
+                                    # than the sequence
+    (512, 100, 16, 4, 2, 64),       # two chunks at a head of 64
 ]
 
 
-@pytest.mark.parametrize("seq,window,block,chunk,h,hk", WINDOW_CASES)
-def test_windowed_kernels_against_the_dense_reference(
-        monkeypatch, seq, window, block, chunk, h, hk):
+@pytest.mark.parametrize("seq,window,block,h,hk,d", WINDOW_CASES)
+def test_windowed_kernels_against_the_dense_reference(seq, window, block,
+                                                     h, hk, d):
     """Interpret mode, float32 on both sides: the orders of the sums
     differ and nothing else, 2e-5 absolute is twenty roundings of values
     near 1."""
-    if chunk:
-        monkeypatch.setattr(pf, "BWD_SEQ_CHUNK", chunk)
-    q, k, v, do = _qkv(seq, h, hk)
+    q, k, v, do = _qkv(seq, h, hk, d)
     kernel = lambda q, k, v: pf.flash_attention_pallas(
         q, k, v, True, None, block, block, window)
-    dense = lambda q, k, v: _sdpa_core(q, k, v, None, True, 8 ** -0.5,
+    dense = lambda q, k, v: _sdpa_core(q, k, v, None, True, d ** -0.5,
                                        window=window)
+    np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * do), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_segments_over_many_chunks_against_the_dense_reference(causal):
+    """Packed sequences over 32 blocks of 16 (two chunks a side), group
+    seven: the segment compare in every tile the streamed kernels walk."""
+    from paddle_tpu.ops.flash_attention import _sdpa_segmented_core
+    q, k, v, do = _qkv(512, 7, 1, b=1)
+    seg = jnp.asarray(np.repeat(np.arange(5), [70, 130, 44, 200, 68])[None],
+                      jnp.int32)
+    kernel = lambda q, k, v: pf.flash_attention_pallas_segmented(
+        q, k, v, seg, seg, causal, None, 16, 16)
+    dense = lambda q, k, v: _sdpa_segmented_core(q, k, v, seg, seg, causal,
+                                                 8 ** -0.5)
     np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * do), (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * do), (0, 1, 2))(q, k, v)
@@ -90,10 +113,9 @@ def _tiles_in_band(i, n, block, window, of_keys):
             if j * block + block - 1 >= lo and j * block < hi + window]
 
 
-@pytest.mark.parametrize("seq,window,block,chunk,h,hk", [
+@pytest.mark.parametrize("seq,window,block,h,hk,d", [
     c for c in WINDOW_CASES if c[1] in (40, 64, 77, 100)])
-def test_no_tile_outside_the_band_is_computed(monkeypatch, seq, window,
-                                              block, chunk, h, hk):
+def test_no_tile_outside_the_band_is_computed(seq, window, block, h, hk, d):
     """What the kernels' loops visit, observed and not reckoned: with
     every key tile outside a query tile's band set to NaN (k and v), that
     tile's output and dq stay finite only if the forward and dq loops
@@ -103,9 +125,7 @@ def test_no_tile_outside_the_band_is_computed(monkeypatch, seq, window,
     visit every tile of the band is the comparison with the dense
     reference above. A kernel that walked the causal tiles and only
     masked fails on the first tile past the window."""
-    if chunk:
-        monkeypatch.setattr(pf, "BWD_SEQ_CHUNK", chunk)
-    q, k, v, do = _qkv(seq, h, hk, b=1)
+    q, k, v, do = _qkv(seq, h, hk, d, b=1)
     n = seq // block
 
     @jax.jit
@@ -155,23 +175,22 @@ def test_the_window_bites_and_counts_the_querys_own_key():
         pf.flash_attention_pallas(q, k, v, False, None, 32, 32, 4)
 
 
-def test_no_window_is_the_old_program_to_the_bit(monkeypatch):
+def test_no_window_is_the_old_program_to_the_bit():
     """``window=None`` traces the program the accepted cells compiled: the
     call without the argument and the call with None are one jaxpr, with
     the kernels' old names; and its result is, bit for bit, that of a
-    window too wide to cut anything."""
-    monkeypatch.setattr(pf, "BWD_SEQ_CHUNK", 64)
-    q, k, v, do = _qkv(128, 4, 2)
+    window too wide to cut anything (32 blocks of 8: two chunks)."""
+    q, k, v, do = _qkv(256, 4, 2)
 
     def both(fn):
         return jax.vjp(fn, q, k, v)
 
     old = lambda q, k, v: pf.flash_attention_pallas(q, k, v, True, None,
-                                                    32, 32)
+                                                    8, 8)
     new = lambda q, k, v: pf.flash_attention_pallas(q, k, v, True, None,
-                                                    32, 32, None)
+                                                    8, 8, None)
     wide = lambda q, k, v: pf.flash_attention_pallas(q, k, v, True, None,
-                                                     32, 32, 128)
+                                                     8, 8, 256)
     text = {}
     for name, fn in (("old", old), ("new", new), ("wide", wide)):
         text[name] = str(jax.make_jaxpr(
@@ -186,52 +205,86 @@ def test_no_window_is_the_old_program_to_the_bit(monkeypatch):
         assert (np.asarray(a) == np.asarray(b)).all()
 
 
+def _walked(steps):
+    """The (kept block, streamed block) tiles a backward call's table
+    walks, and those it masks."""
+    walked, masked = set(), set()
+    for row, _, lo, a, b, hi, _, _ in steps.reshape(-1, pf._FIELDS):
+        walked |= {(row, j) for j in range(lo, hi)}
+        masked |= {(row, j) for j in list(range(lo, a)) + list(range(b, hi))}
+    return walked, masked
+
+
 def test_the_tiles_at_the_cells_shape_by_hand():
     """1 x 16,384 under 4,096 keys at tiles of 512: query tile i sees key
     tiles i - 8 .. i (the first key of tile i - 8 is the one its first
     query no longer sees, the others of that tile it does): 36 + 24 x 9 =
     252 of the 528 causal tiles, for each of the three kernels and each
     of the 28 heads; kernels that walked every causal tile and masked
-    would read 528 / 252 = 2.1."""
+    would read 528 / 252 = 2.1. The backward calls walk exactly those
+    tiles, and mask only the 32 the diagonal cuts and the 24 the band's
+    lower edge cuts."""
     per_kernel = sum(len(_tiles_in_band(i, 32, 512, 4096, of_keys=True))
                      for i in range(32))
     assert per_kernel == sum(min(i + 1, 9) for i in range(32)) == 252
     assert per_kernel == sum(
         len(_tiles_in_band(j, 32, 512, 4096, of_keys=False))
         for j in range(32))
-    # the backward pass's pairs: 8 chunks of 2,048 against the three that
-    # reach into their band
-    pairs = list(pf._bwd_pairs(16384, 16384, True, 4096))
-    assert len(pairs) == 21
-    cut_by_diagonal = [p for p in pairs if p[4]]
-    cut_by_window = [p for p in pairs if p[5] is not None]
-    assert len(cut_by_diagonal) == 8 and len(cut_by_window) == 6
-    assert len(list(pf._bwd_pairs(16384, 16384, True, None))) == 36
+    for window, tiles, cut, steps in ((4096, 252, 56, 40),
+                                      (None, 528, 32, 48)):
+        dq = pf._bwd_steps(pf._dq_spans(32, 32, 512, 512, 0, True, window),
+                           16)
+        dkv = pf._bwd_steps(pf._dkv_spans(32, 32, 512, 512, 0, True,
+                                          window), 16)
+        (dq_tiles, dq_cut), (dkv_tiles, dkv_cut) = _walked(dq), _walked(dkv)
+        # dq's rows are query tiles, dk/dv's key tiles: the same pairs
+        assert dq_tiles == {(j, i) for i, j in dkv_tiles}
+        assert dq_cut == {(j, i) for i, j in dkv_cut}
+        assert len(dq_tiles) == tiles and len(dq_cut) == cut
+        if window is not None:
+            assert dq_tiles == {(i, j) for i in range(32) for j in
+                                _tiles_in_band(i, 32, 512, window, True)}
+        else:
+            assert dq_tiles == {(i, j) for i in range(32)
+                                for j in range(i + 1)}
+        # grid steps of sixteen blocks a step, per (batch, head): every
+        # step meets at least one tile it walks
+        assert len(dq) // pf._FIELDS == len(dkv) // pf._FIELDS == steps
+        for table in (dq, dkv):
+            fields = table.reshape(-1, pf._FIELDS)
+            assert (fields[:, pf._HI] > fields[:, pf._LO]).all()
 
 
-def test_pairs_alike_are_traced_once_and_called_each(monkeypatch):
-    """256 positions in chunks of 64: ten pairs under the diagonal, four
-    cut by it (each with positions of its own) and six whole ones, which
-    read no position and are ONE trace of the two kernels; every pair is
-    still its own two calls in the program. Under a window of 160 keys:
-    four on the diagonal, the three next to it whole (one trace), three
-    further down cut by the band's edge, the last below the band and left
-    out."""
-    monkeypatch.setattr(pf, "BWD_SEQ_CHUNK", 64)
-    traced = []
-    kernel = pf._bwd_dq_kernel
-    monkeypatch.setattr(pf, "_bwd_dq_kernel", lambda *a, **kw: (
-        traced.append((kw["q_base"], kw["k_base"])), kernel(*a, **kw))[1])
+@pytest.mark.parametrize("window", [None, 160])
+def test_the_backward_of_sixteen_blocks_is_two_kernel_calls(window):
+    """512 positions at blocks of 32: one dq call and one dk/dv call
+    over the whole sequence, under the names the rooflines read, and no
+    partial gradient added up outside them."""
+    q, k, v, do = _qkv(512, 4, 2)
+    text = str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda *a: pf.flash_attention_pallas(*a, True, None, 32, 32, window),
+        q, k, v)[1](do))(q, k, v))
+    stem = "flash" if window is None else "flash_win"
+    for name in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert text.count(f"name={stem}_{name}") == 1, name
+    assert text.count("pallas_call") == 3
+    assert "scatter" not in text and "dynamic_update_slice" not in text
+
+
+def test_the_counter_reads_two_backward_calls_a_traced_layer():
+    """``attn.flash.bwd_calls`` counts where the backward calls are made:
+    two for each traced backward pass of a layer, none for a forward
+    pass alone."""
+    reg = telemetry.default_tracer().metrics
     q, k, v, do = _qkv(256, 4, 2)
-    for window, alike, calls in ((None, 5, 10), (160, 8, 10)):
-        jax.clear_caches()
-        del traced[:]
-        text = str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
-            lambda *a: pf.flash_attention_pallas(
-                *a, True, None, 32, 32, window), q, k, v)[1](do))(q, k, v))
-        assert len(traced) == alike and traced.count((0, 0)) == 2
-        stem = "flash_bwd_dq" if window is None else "flash_win_bwd_dq"
-        assert text.count(f"name={stem}") == calls
+    layer = lambda x, w: pf.flash_attention_pallas(x, k, v, True, None, 32,
+                                                   32, w)
+    before = reg.value("attn.flash.bwd_calls") or 0
+    jax.make_jaxpr(lambda q: layer(layer(q, None), 64))(q)
+    assert (reg.value("attn.flash.bwd_calls") or 0) == before
+    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        layer(layer(q, None), 64) * do)))(q)
+    assert reg.value("attn.flash.bwd_calls") == before + 4
 
 
 def test_the_op_counts_what_took_a_window():

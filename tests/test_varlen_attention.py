@@ -208,31 +208,28 @@ class TestAttentionDropout:
                                   dropout=0.1)
 
 
-def test_chunked_backward_matches_single_call(monkeypatch):
-    """Long-seq backward tiling (VMEM-bounded [q-chunk, k-chunk] pair
-    calls): grads must equal the single-call path exactly. Chunk size
-    forced tiny so the tiling engages on CPU-sized inputs."""
+def test_chunked_backward_matches_single_call():
+    """The backward pass is one dq call and one dk/dv call that stream the
+    other side sixteen blocks a step: at one, two and four chunks of a
+    sequence (blocks of 8), causal or not, its gradients are those of
+    the dense reference."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     rng = np.random.RandomState(9)
-    b, s, h, hk, d = 1, 128, 4, 2, 8
-    q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, hk, s, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, hk, s, d), jnp.float32)
-
-    def loss(q_, k_, v_, causal):
-        out = fa.flash_attention_pallas(
-            q_.swapaxes(1, 2), k_.swapaxes(1, 2), v_.swapaxes(1, 2),
-            causal, None, 32, 32)
-        return jnp.sum(out ** 2)
-
-    for causal in (True, False):
-        ref = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, causal)
-        monkeypatch.setattr(fa, "BWD_SEQ_CHUNK", 32)
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, causal)
-        monkeypatch.setattr(fa, "BWD_SEQ_CHUNK", 4096)
-        for g, r in zip(got, ref):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
-                                       atol=2e-4, rtol=2e-4)
+    b, h, hk, d = 1, 4, 2, 8
+    for s in (128, 256, 512):
+        q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
+        k = jnp.asarray(rng.randn(b, s, hk, d), jnp.float32)
+        v = jnp.asarray(rng.randn(b, s, hk, d), jnp.float32)
+        for causal in (True, False):
+            def loss(fn):
+                return lambda *a: jnp.sum(fn(*a) ** 2)
+            got = jax.grad(loss(lambda *a: fa.flash_attention_pallas(
+                *a, causal, None, 8, 8)), argnums=(0, 1, 2))(q, k, v)
+            ref = jax.grad(loss(lambda *a: flash_attention_reference(
+                *a, causal=causal)), argnums=(0, 1, 2))(q, k, v)
+            for g, r in zip(got, ref):
+                np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                           atol=2e-4, rtol=2e-4)
