@@ -19,6 +19,9 @@ from .smallthinker import (  # noqa: F401
     SmallThinkerConfig, SmallThinkerForCausalLM, SmallThinkerModel,
     smallthinker_tiny,
 )
+from .laguna import (  # noqa: F401
+    LagunaConfig, LagunaForCausalLM, LagunaModel, laguna_tiny,
+)
 from .dit import (  # noqa: F401
     DiT, DiTConfig, dit_tiny, dit_s_2, dit_xl_2,
 )

@@ -181,9 +181,10 @@ class MoEShareLayer(Layer):
     SmallThinker). The router
     (``gate_weight`` [d_model, num_experts]) is whole on every share and
     follows one of two published rules. ``score_func="softmax"``
-    (Keye-VL-2.0, SmallThinker, Qwen3-MoE): softmax in float32, the
-    ``top_k`` largest,
-    divided by their sum when ``norm_topk_prob``. ``"sigmoid"`` (LFM2-MoE,
+    (Keye-VL-2.0, SmallThinker, Laguna, Qwen3-MoE): softmax in float32,
+    the ``top_k`` largest,
+    divided by their sum when ``norm_topk_prob``, times
+    ``routed_scaling_factor``. ``"sigmoid"`` (LFM2-MoE,
     DeepSeek-V3): ``s = sigmoid(logits)`` in float32; with
     ``expert_bias=True`` the ``top_k`` largest of ``s + expert_bias`` are
     chosen, ``expert_bias`` [num_experts] a float32 buffer that starts at
@@ -230,10 +231,8 @@ class MoEShareLayer(Layer):
         if score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"score_func {score_func!r}; there are: "
                              "softmax, sigmoid")
-        if score_func == "softmax" and (expert_bias
-                                        or routed_scaling_factor != 1.0):
-            raise ValueError("the softmax rule has no selection bias and "
-                             "no scaling factor")
+        if score_func == "softmax" and expert_bias:
+            raise ValueError("the softmax rule has no selection bias")
         from ...ops.moe import ACTIVATIONS
         if activation not in ACTIVATIONS:
             raise ValueError(f"activation {activation!r}; there are: "
@@ -278,8 +277,9 @@ class MoEShareLayer(Layer):
             more = list(more)
             bias = more.pop(0) if has_bias else None
             routed_on = more.pop(0) if more else None
-            route = moe.route_softmax if self.score_func == "softmax" \
-                else functools.partial(
+            route = functools.partial(
+                moe.route_softmax, scaling=self.routed_scaling_factor) \
+                if self.score_func == "softmax" else functools.partial(
                     moe.route_sigmoid, expert_bias=bias,
                     scaling=self.routed_scaling_factor)
             out, rows, walked = moe.moe_share_forward(

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import contextlib
 import functools
 
 import jax
@@ -190,15 +189,19 @@ def moe_ragged_forward(x, gate_w, w1, w2, top_k: int,
     return out.reshape(b, s, d).astype(x.dtype), aux_loss, stats
 
 
-def route_softmax(logits, top_k: int, norm_topk_prob: bool = True):
+def route_softmax(logits, top_k: int, norm_topk_prob: bool = True,
+                  scaling: float = 1.0):
     """Keye-VL-2.0's (Qwen3-MoE's) routing rule: softmax over ALL the
     float32 ``logits`` [T, E], the ``top_k`` largest, their weights
-    divided by their sum when ``norm_topk_prob``. -> (top_i [T, k],
-    gates [T, k])."""
+    divided by their sum when ``norm_topk_prob``, times ``scaling``
+    (Laguna's ``moe_routed_scaling_factor``; at 1.0 no multiply is
+    traced). -> (top_i [T, k], gates [T, k])."""
     probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
     top_p, top_i = jax.lax.top_k(probs, top_k)                 # [T, k]
     gates = top_p / jnp.sum(top_p, -1, keepdims=True) \
         if norm_topk_prob else top_p
+    if scaling != 1.0:
+        gates = gates * scaling
     return top_i, gates
 
 
@@ -275,13 +278,11 @@ def moe_share_forward(x, gate_w, w_gate, w_up, w_down, top_k: int,
     if activation not in ACTIVATIONS:
         raise ValueError(f"activation {activation!r}; there are: "
                          f"{', '.join(ACTIVATIONS)}")
-    # a router with an input of its own is a step of its own in the trace:
-    # it belongs to another place of the layer than the experts it is
-    # computed beside
-    routed_on, scope = (tokens, contextlib.nullcontext()) \
-        if router_input is None \
-        else (router_input.reshape(t, d), jax.named_scope("router"))
-    with scope:
+    # the router is a step of its own in the trace, beside the experts it
+    # is computed with
+    routed_on = tokens if router_input is None \
+        else router_input.reshape(t, d)
+    with jax.named_scope("router"):
         logits = routed_on.astype(jnp.float32) @ gate_w.astype(jnp.float32)
         top_i, gates = route(logits, top_k, norm_topk_prob)
 

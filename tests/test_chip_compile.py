@@ -402,6 +402,50 @@ def test_smallthinker_cell_whole_step(one_chip, on_chip):
     assert took["attn.flash.bwd_calls"] == took["attn.flash.window"] // 3 * 8
 
 
+def test_laguna_cell_whole_step(one_chip, on_chip):
+    """The Laguna cell's whole step (published layers 0-4, one chip's 8 of
+    256 experts, batch 1 x 8,192, ``use_recompute`` on with each layer's
+    flash output kept): fits the chip at 15.99 GB, under the 16.0 the
+    cell is held to (16.04 while the router took the experts' input a
+    second time as its own ``router_input``, 16.16 with the window layers
+    keeping nothing: the peak lies in layer 3's expert backward either
+    way). The full layers' attention under the old names at 48 heads, the
+    window layers' under ``flash_win_*`` at 72, each forward kernel ONCE and each
+    layer's backward pass one dq call and one dk/dv call; 48 grouped
+    matmuls of the four expert layers; all 69 leaves' gradients under the
+    barrier. One trace of the step counts five gated layers, two under
+    YaRN over 64 dimensions, four shared experts, three windows of 512
+    and ten backward calls."""
+    from paddle_tpu.utils import telemetry
+    metrics = telemetry.default_tracer().metrics
+    names = ("attn.gate.per_head", "rope.yarn", "moe.shared_expert",
+             "attn.flash.window", "attn.flash.bwd_calls",
+             "recompute.regions_keeping")
+    before = {name: metrics.value(name) or 0 for name in names}
+    compiled, nbytes, leaves = _cell_step_compiled(
+        one_chip, "lm_laguna", "laguna_s21_ep32_l5_train", "train_b1_s8192")
+    text = compiled.as_text()
+    assert ".remat" not in text
+    calls = {name: len(re.findall(rf"%{name}[.\d]* = ", text))
+             for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                          "flash_win_fwd", "flash_win_bwd_dq",
+                          "flash_win_bwd_dkv", "ragged-dot-none")}
+    assert calls == {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
+                     "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
+                     "flash_win_bwd_dkv": 3, "ragged-dot-none": 48}
+    assert not _partial_sums_into(text, ("1,72,8192,128", "1,48,8192,128",
+                                         "1,8,8192,128"))
+    assert nbytes <= 16.0e9
+    assert leaves == 69
+    took = {name: metrics.value(name) - before[name] for name in names}
+    assert took == {"attn.gate.per_head": 5, "rope.yarn": 2,
+                    "moe.shared_expert": 4, "attn.flash.window": 3,
+                    "attn.flash.bwd_calls": 10,
+                    "recompute.regions_keeping": 5}
+    assert metrics.value("rope.rotary_dim") == 64
+    assert metrics.value("attn.flash.window_size") == 512
+
+
 # -- the gate: what the chip's compiler refuses never reaches it ------------
 
 # Keye-VL-2.0's learned sparse attention at the benchmark cell's widths:

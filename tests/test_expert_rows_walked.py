@@ -61,7 +61,8 @@ def test_the_entry_is_the_files_and_lists_the_expert_cells():
             "unit", "better", "source", "layer", "moves")},
         "workloads": ["keye_vl2_ep8_l4_train_s8192",
                       "lfm2_ep4_l5_train_s8192",
-                      "smallthinker_ep4_l4_train_s16384"]}
+                      "smallthinker_ep4_l4_train_s16384",
+                      "laguna_ep32_l5_train_s8192"]}
     assert (spec["better"], spec["source"]) == ("lower", "program_counter")
 
 
