@@ -20,15 +20,22 @@ Kernels (each ``pallas_call`` is named; a device trace shows the name):
 ``topk_select``         I -> mask: a radix select on the scores' bits, 32
                         counting passes for the ``topk``-th largest value
                         of a row and ``log2(seq)`` more for the ties
-``sparse_attn_fwd``     online-softmax attention under the mask
+``sparse_attn_fwd``     online-softmax attention under the mask, a step a
+                        query tile and head, its key blocks in a loop
 ``indexer_loss_rows``   the heads' summed probabilities under the mask (the
                         indexer's target) folded with its scores into the
-                        loss's sums per row
-``sparse_attn_bwd_dq``  dq, a query tile's keys innermost
-``sparse_attn_bwd_dkv`` dk and dv, a key tile's queries and, innermost,
-                        the heads; the same probabilities gather into the
-                        target once more and the tile ends in the loss's
-                        gradient with respect to the scores
+                        loss's sums per row, a step a causal tile, its
+                        heads in a loop
+``sparse_attn_bwd_dq``  dq, a step a query tile and head, its key blocks
+                        in a loop
+``sparse_attn_bwd_dkv`` dk and dv, a step a causal tile, a key tile's
+                        query tiles in order, its heads in a loop; the
+                        same probabilities gather into the target once
+                        more and the tile ends in the loss's gradient
+                        with respect to the scores
+
+No grid step of the four lies above the diagonal; each call adds its
+steps to the trace-time counter ``attn.sparse.grid_steps``.
 
 ``learned_sparse_attention`` ties them together under one ``custom_vjp``:
 it keeps q, k, v, the output, the row statistics, the mask and the
@@ -48,7 +55,9 @@ kI [b, s, di]; w [b, s, hi] float32 with the score's scales folded in.
 from __future__ import annotations
 
 import functools
+import math
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -230,6 +239,89 @@ def topk_select_reference(scores, topk):
 # ---------------------------------------------------------------------------
 # attention under the mask
 # ---------------------------------------------------------------------------
+#
+# The four kernels walk the causal tiles alone, in few long grid steps.
+# ``sparse_attn_fwd`` and ``sparse_attn_bwd_dq`` take one query tile of one
+# head a step (grid (b, nq, h), the heads innermost) and loop over its key
+# blocks up to the diagonal's inside the step: K and V of the head's key
+# head and the tile's mask row stay in VMEM, the mask row over all heads.
+# ``indexer_loss_rows`` and ``sparse_attn_bwd_dkv`` need every head of a
+# tile before its epilogue, so they take one causal tile a step and loop
+# over the heads inside it; their steps are listed at trace time
+# (``_causal_steps``) and reach the index maps as a prefetched table.
+# Every head's row statistics (lse, delta) travel as one [b, s, h] array,
+# a query tile's block of which serves all heads: a [.., s, 1] statistic
+# pads its one lane to 128, in VMEM and in HBM.
+
+# A prefetched step's fields: its query tile, its key tile, whether it is
+# the first of its row (the query tile's in ``indexer_loss_rows``, the key
+# tile's in ``sparse_attn_bwd_dkv``)
+_QT, _KT, _FIRST = range(3)
+_FIELDS = 3
+
+
+def _field(steps, t, f):
+    return steps[_FIELDS * t + f]
+
+
+def _last_k(q_i, bq, bk):
+    """The last key tile that query tile ``q_i`` needs."""
+    return (q_i * bq + bq - 1) // bk
+
+
+def _causal_steps(s, bq, bk, by_key):
+    """The causal tiles as a flat int32 table, ``_FIELDS`` a step: by
+    query tile with its key tiles innermost, or (``by_key``) by key tile
+    with its query tiles innermost."""
+    nq, nk = s // bq, s // bk
+    table = []
+    if by_key:
+        for kj in range(nk):
+            first = kj * bk // bq
+            table += [[q_i, kj, q_i == first] for q_i in range(first, nq)]
+    else:
+        for q_i in range(nq):
+            table += [[q_i, kj, kj == 0]
+                      for kj in range(_last_k(q_i, bq, bk) + 1)]
+    return np.asarray(table, np.int32).reshape(-1)
+
+
+def _padded_bytes(shape, dtype):
+    """VMEM bytes of a block: its last dimension in lanes of 128, the one
+    before it in sublane tiles (8 rows of 32 bits, packed narrower)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // itemsize
+    return (math.prod(lead) * -(-rows // sub) * sub * -(-lanes // 128) * 128
+            * itemsize)
+
+
+def _compiler_params(semantics, blocks, scratch, tile):
+    """``pallas_call`` options that ask for the VMEM a call holds: two
+    pipeline buffers of each block in ``blocks`` ((shape, dtype) pairs),
+    the ``scratch`` bytes, and eight float32 temporaries of a ``tile``
+    (scores, probabilities, their gradient, the mask's compare, ...)."""
+    need = (2 * sum(_padded_bytes(*b) for b in blocks) + scratch
+            + 8 * _padded_bytes(tile, F32))
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=need)
+
+
+def _count_steps(n):
+    telemetry.default_tracer().metrics.inc("attn.sparse.grid_steps", n)
+
+
+def _rows(stat):
+    """A [b, h, s, 1] statistic as [b, s, h]: the kernels' layout."""
+    return jnp.swapaxes(stat[..., 0], 1, 2)
+
+
+def _column(rows, hi):
+    """Head ``hi``'s column [bq, 1] of a block of rows [bq, h]: a lane
+    picked by compare and summed, exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    return jnp.sum(jnp.where(lane == hi, rows, 0.0), axis=1, keepdims=True)
+
 
 def _probs(q, k, keep, scale, row_stat):
     """(masked scores' probabilities given the rows' statistic) of one
@@ -240,20 +332,20 @@ def _probs(q, k, keep, scale, row_stat):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_sc, l_sc, acc_sc, *, scale, bq, bk, nk):
-    qi, kj = pl.program_id(2), pl.program_id(3)
+                m_sc, l_sc, acc_sc, *, scale, bq, bk):
+    """One query tile of one head: an online softmax over the key blocks
+    up to the diagonal's, in order. The tile's log-sum-exp goes into its
+    head's lane of the rows block, which stays in VMEM over the heads."""
+    q_i, hi = pl.program_id(1), pl.program_id(2)
+    q = q_ref[0, 0]
+    m_sc[...] = jnp.full(m_sc.shape, _NEG, F32)
+    l_sc[...] = jnp.zeros(l_sc.shape, F32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
 
-    @pl.when(kj == 0)
-    def _():
-        m_sc[...] = jnp.full(m_sc.shape, _NEG, F32)
-        l_sc[...] = jnp.zeros(l_sc.shape, F32)
-        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
-
-    @pl.when(kj * bk <= qi * bq + bq - 1)
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
+    def block(kj, carry):
+        keys = pl.ds(pl.multiple_of(kj * bk, bk), bk)
+        keep = mask_ref[0, :, keys].astype(jnp.int32) > 0
+        s = jax.lax.dot_general(q, k_ref[0, 0, keys], (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32) * scale
         s = jnp.where(keep, s, _NEG)
         m_prev = m_sc[...]
@@ -262,144 +354,134 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)
         l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, 0, keys], (((1,), (0,)), ((), ())),
             preferred_element_type=F32)
         m_sc[...] = m_new
+        return carry
 
-    @pl.when(kj == nk - 1)
-    def _():
-        l = jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_sc[...] + jnp.log(l)
-
-
-def _tiles(s, block_q, block_k):
-    bq, bk = _block(s, block_q), _block(s, block_k)
-
-    def last_k(q_i):                     # last key tile a query tile needs
-        return (q_i * bq + bq - 1) // bk
-
-    def first_q(kj):                     # first query tile a key tile meets
-        return (kj * bk) // bq
-    return bq, bk, last_k, first_q
+    jax.lax.fori_loop(0, _last_k(q_i, bq, bk) + 1, block, 0)
+    l = jnp.maximum(l_sc[...], 1e-30)
+    o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, lse_ref.shape[1:], 1)
+    lse_ref[0] = jnp.where(lane == hi, m_sc[...] + jnp.log(l), lse_ref[0])
 
 
 def sparse_attn_fwd(q, k, v, mask, scale, block_q=512, block_k=512):
     """(out [b, h, s, d], lse [b, h, s, 1] float32)."""
     b, h, s, d = q.shape
     g = h // k.shape[1]
-    bq, bk, last_k, _ = _tiles(s, block_q, block_k)
-    nk = s // bk
-
-    def kv_map(bi, hi, q_i, kj):
-        return (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)
-
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
-        grid=(b, h, s // bq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, q_i, kj:
-                         (bi, hi, q_i, 0)),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, bq, bk), lambda bi, hi, q_i, kj:
-                         (bi, q_i, jnp.minimum(kj, last_k(q_i)))),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, q_i, kj:
-                         (bi, hi, q_i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, q_i, kj:
-                         (bi, hi, q_i, 0)),
-        ],
+    bq, bk = _block(s, block_q), _block(s, block_k)
+    grid = (b, s // bq, h)
+    _count_steps(math.prod(grid))
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, q_i, hi: (bi, hi, q_i, 0))
+    kv_spec = pl.BlockSpec((1, 1, s, d), lambda bi, q_i, hi:
+                           (bi, hi // g, 0, 0))
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec((1, bq, s), lambda bi, q_i, hi: (bi, q_i, 0))],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, bq, h), lambda bi, q_i, hi: (bi, q_i, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, s, 1), F32)],
+                   jax.ShapeDtypeStruct((b, s, h), F32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), F32), pltpu.VMEM((bq, 1), F32),
                         pltpu.VMEM((bq, d), F32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
+        # at the Keye widths (s 8192, blocks of 512, d 128): K and V of a
+        # key head (2 x 2 MiB) and the mask row (4 MiB), buffered twice,
+        # 16 MiB of the 25.75 MiB asked; eight float32 tiles 8 MiB
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            [((bq, d), q.dtype), ((s, d), k.dtype), ((s, d), v.dtype),
+             ((bq, s), mask.dtype), ((bq, d), q.dtype), ((bq, h), F32)],
+            2 * _padded_bytes((bq, 1), F32) + _padded_bytes((bq, d), F32),
+            (bq, bk)),
         interpret=_interpret(),
         name="sparse_attn_fwd",
     )(q, k, v, mask)
+    return out, jnp.swapaxes(lse, 1, 2)[..., None]
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   mask_ref, dq_ref, acc_sc, *, scale, bq, bk, nk):
-    qi, kj = pl.program_id(2), pl.program_id(3)
+                   mask_ref, dq_ref, acc_sc, *, scale, bq, bk):
+    """One query tile of one head: dq over the key blocks up to the
+    diagonal's, in order, written once."""
+    q_i, hi = pl.program_id(1), pl.program_id(2)
+    q, do = q_ref[0, 0], do_ref[0, 0]
+    lse, delta = _column(lse_ref[0], hi), _column(delta_ref[0], hi)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
 
-    @pl.when(kj == 0)
-    def _():
-        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
-
-    @pl.when(kj * bk <= qi * bq + bq - 1)
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
-        k = k_ref[0, 0]
-        p = _probs(q_ref[0, 0], k, keep, scale, lse_ref[0, 0])
-        dp = jax.lax.dot_general(do_ref[0, 0], v_ref[0, 0],
+    def block(kj, carry):
+        keys = pl.ds(pl.multiple_of(kj * bk, bk), bk)
+        keep = mask_ref[0, :, keys].astype(jnp.int32) > 0
+        k = k_ref[0, 0, keys]
+        p = _probs(q, k, keep, scale, lse)
+        dp = jax.lax.dot_general(do, v_ref[0, 0, keys],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - delta_ref[0, 0]) * scale
+        ds = p * (dp - delta) * scale
         acc_sc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=F32)
+        return carry
 
-    @pl.when(kj == nk - 1)
-    def _():
-        dq_ref[0, 0] = acc_sc[...].astype(dq_ref.dtype)
+    jax.lax.fori_loop(0, _last_k(q_i, bq, bk) + 1, block, 0)
+    dq_ref[0, 0] = acc_sc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(steps, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     mask_ref, sc_ref, z_ref, lsei_ref,
                     dk_ref, dv_ref, ds_ref, tgt,
-                    *, scale, bq, bk, heads, group):
-    """One (key tile, query tile) of dk and dv, the heads in the innermost
-    grid axis, and of the indexer's side of the same tile: every head's
-    probabilities are made once, feed dk / dv of the head's key head (the
-    output blocks hold all key heads and stay in VMEM over a key tile's
-    queries and heads) and gather, heads summed, in ``tgt``. After the
-    last head the tile's target meets the indexer's scores: the loss's
-    gradient with respect to the scores, softmax(scores) - target / z
-    over the kept keys, times the number of queries."""
-    kj, qi, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    live = kj * bk <= qi * bq + bq - 1
-    last = hi == heads - 1
+                    *, scale, bq, heads, group):
+    """One causal (key tile, query tile) of dk and dv, the heads in a loop,
+    and of the indexer's side of the same tile: every head's probabilities
+    are made once, feed dk / dv of the head's key head (the output blocks
+    hold all key heads and stay in VMEM over a key tile's query tiles) and
+    gather, heads summed, in ``tgt``. After the last head the tile's target
+    meets the indexer's scores: the loss's gradient with respect to the
+    scores, softmax(scores) - target / z over the kept keys, times the
+    number of queries, into the key tile's column, which stays in VMEM
+    and is written whole: zeros above the tile's first causal query
+    tile."""
+    t = pl.program_id(1)
+    q_i = _field(steps, t, _QT)
 
-    @pl.when((qi == 0) & (hi == 0))
+    @pl.when(_field(steps, t, _FIRST) == 1)
     def _():
         dk_ref[...] = jnp.zeros(dk_ref.shape, F32)
         dv_ref[...] = jnp.zeros(dv_ref.shape, F32)
 
-    @pl.when(live & (hi == 0))
-    def _():
-        tgt[...] = jnp.zeros((bq, bk), F32)
+        def dead(i, carry):
+            ds_ref[0, pl.ds(pl.multiple_of(i * bq, bq), bq)] = jnp.zeros(
+                (bq, ds_ref.shape[2]), F32)
+            return carry
+        jax.lax.fori_loop(0, q_i, dead, 0)
 
-    @pl.when(live)
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
+    keep = mask_ref[0].astype(jnp.int32) > 0
+    lse, delta = lse_ref[0], delta_ref[0]
+    tgt[...] = jnp.zeros(tgt.shape, F32)
+
+    def head(hi, carry):
         kh = hi // group
-        q, do = q_ref[0, 0], do_ref[0, 0]
-        p = _probs(q, k_ref[0, kh], keep, scale, lse_ref[0, 0])
+        q, do = q_ref[0, hi], do_ref[0, hi]
+        p = _probs(q, k_ref[0, kh], keep, scale, _column(lse, hi))
         tgt[...] += p
         dv_ref[0, kh] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=F32)
         dp = jax.lax.dot_general(do, v_ref[0, kh], (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-        ds = p * (dp - delta_ref[0, 0]) * scale
+        ds = p * (dp - _column(delta, hi)) * scale
         dk_ref[0, kh] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=F32)
+        return carry
 
-    @pl.when(live & last)
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
-        soft = jnp.where(keep, jnp.exp(sc_ref[0] - lsei_ref[0]), 0.0)
-        # the heads' mean: the division once a tile, not once a head
-        ds_ref[0] = soft - tgt[...] * (1.0 / heads) / z_ref[0]
-
-    @pl.when(jnp.logical_not(live) & last)
-    def _():
-        ds_ref[0] = jnp.zeros((bq, bk), F32)
+    jax.lax.fori_loop(0, heads, head, 0)
+    soft = jnp.where(keep, jnp.exp(sc_ref[0] - lsei_ref[0]), 0.0)
+    # the heads' mean: the division once a tile, not once a head
+    ds_ref[0, pl.ds(pl.multiple_of(q_i * bq, bq), bq)] = \
+        soft - tgt[...] * (1.0 / heads) / z_ref[0]
 
 
 def sparse_attn_bwd(q, k, v, out, lse, do, mask, scores, rows, scale,
@@ -412,133 +494,124 @@ def sparse_attn_bwd(q, k, v, out, lse, do, mask, scores, rows, scale,
     b, h, s, d = q.shape
     hk = k.shape[1]
     g = h // hk
-    bq, bk, last_k, first_q = _tiles(s, block_q, block_k)
-    nq, nk = s // bq, s // bk
-    delta = jnp.sum(out.astype(F32) * do.astype(F32), -1, keepdims=True)
+    bq, bk = _block(s, block_q), _block(s, block_k)
+    lse = _rows(lse)
+    delta = jnp.swapaxes(jnp.sum(out.astype(F32) * do.astype(F32), -1), 1, 2)
 
-    def kv_map(bi, hi, q_i, kj):
-        return (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)
-
-    def row_map(bi, hi, q_i, kj):
-        return (bi, hi, q_i, 0)
-
+    grid = (b, s // bq, h)
+    _count_steps(math.prod(grid))
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, q_i, hi: (bi, hi, q_i, 0))
+    kv_spec = pl.BlockSpec((1, 1, s, d), lambda bi, q_i, hi:
+                           (bi, hi // g, 0, 0))
+    row_spec = pl.BlockSpec((1, bq, h), lambda bi, q_i, hi: (bi, q_i, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), row_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, 1, bq, d), row_map),
-            pl.BlockSpec((1, 1, bq, 1), row_map),
-            pl.BlockSpec((1, 1, bq, 1), row_map),
-            pl.BlockSpec((1, bq, bk), lambda bi, hi, q_i, kj:
-                         (bi, q_i, jnp.minimum(kj, last_k(q_i)))),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), row_map),
+        functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+                  pl.BlockSpec((1, bq, s), lambda bi, q_i, hi: (bi, q_i, 0))],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), F32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
+        # at the Keye widths: K and V of a key head (2 x 2 MiB) and the
+        # mask row (4 MiB), buffered twice, 16 MiB of the 26 MiB asked;
+        # eight float32 tiles 8 MiB
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "parallel"),
+            [((bq, d), q.dtype), ((s, d), k.dtype), ((s, d), v.dtype),
+             ((bq, d), do.dtype), ((bq, h), F32), ((bq, h), F32),
+             ((bq, s), mask.dtype), ((bq, d), q.dtype)],
+            _padded_bytes((bq, d), F32), (bq, bk)),
         interpret=_interpret(),
         name="sparse_attn_bwd_dq",
     )(q, k, v, do, lse, delta, mask)
 
-    # the heads innermost: a dead tile (above the diagonal) asks for the
-    # blocks of the key tile's first live step, so it fetches nothing
-    def live_q(kj, q_i):
-        return jnp.maximum(q_i, first_q(kj))
-
-    def q_map(bi, kj, q_i, hi):
-        return (bi, jnp.where(q_i < first_q(kj), 0, hi), live_q(kj, q_i), 0)
-
-    def k_map(bi, kj, q_i, hi):
-        return (bi, 0, kj, 0)
-
-    def tile_map(bi, kj, q_i, hi):
-        return (bi, live_q(kj, q_i), kj)
-
-    def stat_map(bi, kj, q_i, hi):
-        return (bi, live_q(kj, q_i), 0)
-
+    # the causal tiles by key tile, each with all heads of its query tile
+    steps = _causal_steps(s, bq, bk, by_key=True)
+    grid = (b, len(steps) // _FIELDS)
+    _count_steps(math.prod(grid))
+    heads_spec = pl.BlockSpec((1, h, bq, d), lambda bi, t, st:
+                              (bi, 0, _field(st, t, _QT), 0))
+    keys_spec = pl.BlockSpec((1, hk, bk, d), lambda bi, t, st:
+                             (bi, 0, _field(st, t, _KT), 0))
+    row_spec = pl.BlockSpec((1, bq, h), lambda bi, t, st:
+                            (bi, _field(st, t, _QT), 0))
+    tile_spec = pl.BlockSpec((1, bq, bk), lambda bi, t, st:
+                             (bi, _field(st, t, _QT), _field(st, t, _KT)))
+    stat_spec = pl.BlockSpec((1, bq, 1), lambda bi, t, st:
+                             (bi, _field(st, t, _QT), 0))
     dk, dv, d_scores = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          heads=h, group=g),
-        grid=(b, nk, nq, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), q_map),
-            pl.BlockSpec((1, hk, bk, d), k_map),
-            pl.BlockSpec((1, hk, bk, d), k_map),
-            pl.BlockSpec((1, 1, bq, d), q_map),
-            pl.BlockSpec((1, 1, bq, 1), q_map),
-            pl.BlockSpec((1, 1, bq, 1), q_map),
-            pl.BlockSpec((1, bq, bk), tile_map),
-            pl.BlockSpec((1, bq, bk), tile_map),
-            pl.BlockSpec((1, bq, 1), stat_map),
-            pl.BlockSpec((1, bq, 1), stat_map),
-        ],
-        out_specs=[pl.BlockSpec((1, hk, bk, d), k_map),
-                   pl.BlockSpec((1, hk, bk, d), k_map),
-                   pl.BlockSpec((1, bq, bk), lambda bi, kj, q_i, hi:
-                                (bi, q_i, kj))],
+        functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, heads=h,
+                          group=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[heads_spec, keys_spec, keys_spec, heads_spec, row_spec,
+                      row_spec, tile_spec, tile_spec, stat_spec, stat_spec],
+            out_specs=[keys_spec, keys_spec,
+                       pl.BlockSpec((1, s, bk), lambda bi, t, st:
+                                    (bi, 0, _field(st, t, _KT)))],
+            scratch_shapes=[pltpu.VMEM((bq, bk), F32)]),
         out_shape=[jax.ShapeDtypeStruct((b, hk, s, d), F32),
                    jax.ShapeDtypeStruct((b, hk, s, d), F32),
                    jax.ShapeDtypeStruct((b, s, s), F32)],
-        scratch_shapes=[pltpu.VMEM((bq, bk), F32)],
-        # at 512 x 512 the float32 score and gradient tiles, the all-heads
-        # k / v / dk / dv blocks and the kernel's [bq, bk] temporaries
-        # pass the 16 MiB a kernel gets by default (the v5e has 128 MiB)
-        compiler_params=_params("parallel", "parallel", "arbitrary",
-                                "arbitrary", vmem_limit_bytes=48 << 20),
+        # at the Keye widths: the key tile's column of the loss's
+        # gradient (16 MiB) and q and do of all 32 heads (2 x 4 MiB),
+        # buffered twice, 48 MiB of the 67.5 MiB asked (the v5e has 128);
+        # dk and dv 2 x 2 x 1 MiB, eight float32 tiles and ``tgt`` 9 MiB
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary"),
+            [((h, bq, d), q.dtype), ((hk, bk, d), k.dtype),
+             ((hk, bk, d), v.dtype), ((h, bq, d), do.dtype), ((bq, h), F32),
+             ((bq, h), F32), ((bq, bk), mask.dtype), ((bq, bk), F32),
+             ((bq, 1), F32), ((bq, 1), F32), ((hk, bk, d), F32),
+             ((hk, bk, d), F32), ((s, bk), F32)],
+            _padded_bytes((bq, bk), F32), (bq, bk)),
         interpret=_interpret(),
         name="sparse_attn_bwd_dkv",
-    )(q, k, v, do, lse, delta, mask, scores, *rows)
+    )(jnp.asarray(steps), q, k, v, do, lse, delta, mask, scores, *rows)
     return dq, dk, dv, d_scores
 
 
-def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, sc_ref,
-                 a_ref, z_ref, m_ref, l_ref, tgt, *, scale, bq, bk, heads):
-    """One (query tile, key tile) of the indexer's loss, the heads in the
-    innermost grid axis. The heads' mean probability under the mask (the
-    target, each head's row summing to 1) gathers in VMEM; after the last
-    head the tile is folded, together with the indexer's scores, into
-    four sums per row: what the loss needs."""
-    qi, kj, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    live = kj * bk <= qi * bq + bq - 1
+def _loss_kernel(steps, q_ref, k_ref, lse_ref, mask_ref, sc_ref,
+                 a_ref, z_ref, m_ref, l_ref, tgt, *, scale, heads, group):
+    """One causal (query tile, key tile) of the indexer's loss, the heads
+    in a loop. The heads' mean probability under the mask (the target,
+    each head's row summing to 1) gathers in VMEM; after the last head
+    the tile is folded, together with the indexer's scores, into four
+    sums per row: what the loss needs. The row's sums stay in VMEM over
+    its key tiles."""
+    bq = tgt.shape[0]
 
-    @pl.when((kj == 0) & (hi == 0))
+    @pl.when(_field(steps, pl.program_id(1), _FIRST) == 1)
     def _():
         a_ref[0] = jnp.zeros((bq, 1), F32)
         z_ref[0] = jnp.zeros((bq, 1), F32)
         l_ref[0] = jnp.zeros((bq, 1), F32)
         m_ref[0] = jnp.full((bq, 1), _NEG, F32)
 
-    @pl.when(live & (hi == 0))
-    def _():
-        tgt[...] = jnp.zeros((bq, bk), F32)
+    keep = mask_ref[0].astype(jnp.int32) > 0
+    lse = lse_ref[0]
+    tgt[...] = jnp.zeros(tgt.shape, F32)
 
-    @pl.when(live)
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
-        tgt[...] += _probs(q_ref[0, 0], k_ref[0, 0], keep, scale,
-                           lse_ref[0, 0]) * (1.0 / heads)
+    def head(hi, carry):
+        tgt[...] += _probs(q_ref[0, hi], k_ref[0, hi // group], keep, scale,
+                           _column(lse, hi)) * (1.0 / heads)
+        return carry
 
-    @pl.when(live & (hi == heads - 1))
-    def _():
-        keep = mask_ref[0].astype(jnp.int32) > 0
-        t, sc = tgt[...], sc_ref[0]
-        pos = keep & (t > 0.0)
-        a_ref[0] += jnp.sum(jnp.where(
-            pos, t * (jnp.log(jnp.where(pos, t, 1.0)) - sc), 0.0),
-            axis=1, keepdims=True)
-        z_ref[0] += jnp.sum(t, axis=1, keepdims=True)
-        scm = jnp.where(keep, sc, _NEG)
-        m_prev = m_ref[0]
-        m_new = jnp.maximum(m_prev, jnp.max(scm, axis=1, keepdims=True))
-        l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) + jnp.sum(
-            jnp.where(keep, jnp.exp(scm - m_new), 0.0), axis=1,
-            keepdims=True)
-        m_ref[0] = m_new
+    jax.lax.fori_loop(0, heads, head, 0)
+    t, sc = tgt[...], sc_ref[0]
+    pos = keep & (t > 0.0)
+    a_ref[0] += jnp.sum(jnp.where(
+        pos, t * (jnp.log(jnp.where(pos, t, 1.0)) - sc), 0.0),
+        axis=1, keepdims=True)
+    z_ref[0] += jnp.sum(t, axis=1, keepdims=True)
+    scm = jnp.where(keep, sc, _NEG)
+    m_prev = m_ref[0]
+    m_new = jnp.maximum(m_prev, jnp.max(scm, axis=1, keepdims=True))
+    l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) + jnp.sum(
+        jnp.where(keep, jnp.exp(scm - m_new), 0.0), axis=1,
+        keepdims=True)
+    m_ref[0] = m_new
 
 
 def indexer_loss(q, k, lse, mask, scores, scale, block_q=512, block_k=512):
@@ -549,35 +622,43 @@ def indexer_loss(q, k, lse, mask, scores, scale, block_q=512, block_k=512):
     With the target's row sum z and the scores' row statistic lse_i, a
     row's term is sum(t (log t - score)) / z - log z + lse_i."""
     b, h, s, d = q.shape
-    g = h // k.shape[1]
-    bq, bk, last_k, _ = _tiles(s, block_q, block_k)
-
-    def head_map(bi, q_i, kj, hi):
-        return (bi, hi, q_i, 0)
-
-    def tile_map(bi, q_i, kj, hi):
-        return (bi, q_i, jnp.minimum(kj, last_k(q_i)))
-
-    row_spec = pl.BlockSpec((1, bq, 1), lambda bi, q_i, kj, hi: (bi, q_i, 0))
+    hk = k.shape[1]
+    bq, bk = _block(s, block_q), _block(s, block_k)
+    # the causal tiles by query tile, each with all heads
+    steps = _causal_steps(s, bq, bk, by_key=False)
+    grid = (b, len(steps) // _FIELDS)
+    _count_steps(math.prod(grid))
+    tile_spec = pl.BlockSpec((1, bq, bk), lambda bi, t, st:
+                             (bi, _field(st, t, _QT), _field(st, t, _KT)))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda bi, t, st:
+                            (bi, _field(st, t, _QT), 0))
     a, z, m, l = pl.pallas_call(
-        functools.partial(_loss_kernel, scale=scale, bq=bq, bk=bk, heads=h),
-        grid=(b, s // bq, s // bk, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), head_map),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, q_i, kj, hi:
-                         (bi, hi // g, jnp.minimum(kj, last_k(q_i)), 0)),
-            pl.BlockSpec((1, 1, bq, 1), head_map),
-            pl.BlockSpec((1, bq, bk), tile_map),
-            pl.BlockSpec((1, bq, bk), tile_map),
-        ],
-        out_specs=[row_spec] * 4,
+        functools.partial(_loss_kernel, scale=scale, heads=h, group=h // hk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, h, bq, d), lambda bi, t, st:
+                             (bi, 0, _field(st, t, _QT), 0)),
+                pl.BlockSpec((1, hk, bk, d), lambda bi, t, st:
+                             (bi, 0, _field(st, t, _KT), 0)),
+                pl.BlockSpec((1, bq, h), lambda bi, t, st:
+                             (bi, _field(st, t, _QT), 0)),
+                tile_spec, tile_spec],
+            out_specs=[row_spec] * 4,
+            scratch_shapes=[pltpu.VMEM((bq, bk), F32)]),
         out_shape=[jax.ShapeDtypeStruct((b, s, 1), F32)] * 4,
-        scratch_shapes=[pltpu.VMEM((bq, bk), F32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary",
-                                "arbitrary"),
+        # at the Keye widths: q of all 32 heads (4 MiB), buffered twice,
+        # 8 MiB of the 23 MiB asked; eight float32 tiles and ``tgt`` 9 MiB
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary"),
+            [((h, bq, d), q.dtype), ((hk, bk, d), k.dtype), ((bq, h), F32),
+             ((bq, bk), mask.dtype), ((bq, bk), F32)]
+            + [((bq, 1), F32)] * 4,
+            _padded_bytes((bq, bk), F32), (bq, bk)),
         interpret=_interpret(),
         name="indexer_loss_rows",
-    )(q, k, lse, mask, scores)
+    )(jnp.asarray(steps), q, k, _rows(lse), mask, scores)
     lse_i = m + jnp.log(l)
     return jnp.mean(a / z - jnp.log(z) + lse_i), (z, lse_i)
 

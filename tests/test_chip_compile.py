@@ -295,15 +295,19 @@ def test_keye_cell_whole_step(one_chip, on_chip):
     kernels, seven a layer, count for nothing there; 18.813 while the
     expert layers cut their whole 32,768-row chunk after gathering it,
     where they now gather and scatter-add blocks up to the held rows),
-    13.18 GB of the chip's 16.9 (13,184,484,352 bytes, 13,184,290,816
-    before the walk; 14.11 GB while the loss's gradient with respect to
-    the scores had a kernel of its own, ``indexer_loss_grad``: the
-    backward pass of a layer now has one kernel fewer to keep operands
-    for), all 67 leaves' gradients under the barrier, nothing
-    recomputed."""
+    12.65 GB of the chip's 16.9 (12,646,742,016 bytes; 13.18 GB,
+    13,184,484,352 bytes, while lse and delta were [2, 32, 8192, 1]
+    float32 arrays, whose one lane pads to 128 in HBM: 268 MB each, now
+    [2, 8192, 32]; 14.11 GB while the loss's gradient with respect to
+    the scores had a kernel of its own, ``indexer_loss_grad``), all 67
+    leaves' gradients under the barrier, nothing recomputed. The four
+    tile-walking kernels of a layer take 2 x 1,024 + 2 x 272 grid steps
+    (65,536 while they took one a head and tile, above the diagonal
+    too)."""
     from paddle_tpu.utils import telemetry
     metrics = telemetry.default_tracer().metrics
     folded = metrics.value("attn.sparse.target_in_backward") or 0
+    walked = metrics.value("attn.sparse.grid_steps") or 0
     compiled, nbytes, leaves = _cell_step_compiled(
         one_chip, "lm_keye_vl2", "keye_vl2_30b_a3b_ep8_l4_train",
         "train_b2_s8192")
@@ -319,8 +323,10 @@ def test_keye_cell_whole_step(one_chip, on_chip):
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 48
     assert compiled.cost_analysis()["flops"] == pytest.approx(18.810e12,
                                                               rel=0.01)
-    assert nbytes == pytest.approx(13.18e9, rel=0.02) and nbytes < 15.5e9
+    assert nbytes == pytest.approx(12.65e9, rel=0.02) and nbytes < 15.5e9
     assert leaves == 67
+    steps = metrics.value("attn.sparse.grid_steps") - walked
+    assert steps == 4 * (2 * 1024 + 2 * 272) <= 32768
 
 
 def test_lfm2_cell_whole_step(one_chip, on_chip):
@@ -480,6 +486,7 @@ def _keye_args(sh):
 def test_learned_sparse_attention_kernels_keye_widths(one_chip, on_chip,
                                                       kernel, operands, n):
     from paddle_tpu.ops.pallas import sparse_attention as sa
+    from paddle_tpu.utils import telemetry
     a = _keye_args(one_chip)
     scale = KEYE["d"] ** -0.5
     fn = {"indexer_scores": sa.indexer_scores,
@@ -488,8 +495,15 @@ def test_learned_sparse_attention_kernels_keye_widths(one_chip, on_chip,
           "indexer_loss_rows": lambda *x: sa.indexer_loss(*x, scale)[0],
           "sparse_attn_bwd": lambda *x: sa.sparse_attn_bwd(
               *x[:-2], x[-2:], scale)}[kernel]
+    metrics = telemetry.default_tracer().metrics
+    walked = metrics.value("attn.sparse.grid_steps") or 0
     text = jax.jit(fn).lower(*[a[o] for o in operands]).compile().as_text()
     assert text.count("tpu_custom_call") == n
+    # a step a (row, query tile, head) for the forward and dq kernels, a
+    # step a (row, causal tile) for the loss's and dk / dv: 2 x 136
+    steps = {"sparse_attn_fwd": 1024, "indexer_loss_rows": 272,
+             "sparse_attn_bwd": 1024 + 272}.get(kernel, 0)
+    assert (metrics.value("attn.sparse.grid_steps") or 0) - walked == steps
     # the device trace tells the kernels apart by these names
     names = ("sparse_attn_bwd_dq", "sparse_attn_bwd_dkv") \
         if kernel == "sparse_attn_bwd" else (kernel,)

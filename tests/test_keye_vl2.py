@@ -85,8 +85,17 @@ def test_selection_is_the_exact_topk_ties_to_the_lower_index(operands, ties):
     (4, 2, 16, 16, 16),         # the first row of tiles keeps every causal
                                 # key (t < topk), the others do not
     (3, 1, 32, 32, 24),         # heads that are no power of two
+    (8, 1, 8, 32, 16),          # eight heads a key head in one dk / dv
+                                # step; a key tile's column zeroed above
+                                # its first causal query tile (four tiles)
+    (4, 2, 32, 8, 16),          # eight key blocks a query tile; the first
+                                # one's loop stops at the fourth
 ])
 def test_attention_kernels_forward_target_and_backward(h, hk, bq, bk, topk):
+    from paddle_tpu.utils import telemetry
+    reg = telemetry.default_tracer().metrics
+    steps = lambda: reg.value("attn.sparse.grid_steps") or 0
+    before = steps()
     ks = jax.random.split(jax.random.PRNGKey(h * 100 + hk), 7)
     q, do = (jax.random.normal(k, (B, h, S, D)) for k in ks[:2])
     k, v = (jax.random.normal(k, (B, hk, S, D)) for k in ks[2:4])
@@ -113,17 +122,29 @@ def test_attention_kernels_forward_target_and_backward(h, hk, bq, bk, topk):
         argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, grads):
         assert close(a, b)
+    # the forward and dq kernels take a step a (row, query tile, head), the
+    # loss's and dk / dv's a step a (row, causal tile): none above the
+    # diagonal
+    assert steps() - before == 2 * B * (S // bq) * h + 2 * B * causal_tiles(
+        bq, bk)
+
+
+def causal_tiles(bq, bk):
+    return sum(kj * bk <= qi * bq + bq - 1
+               for qi in range(S // bq) for kj in range(S // bk))
 
 
 def test_the_backward_pass_counts_the_target_it_folds_in(operands):
     """One ``attn.sparse.target_in_backward`` a traced layer's backward
     pass, none for a forward pass alone; and the backward pass is three
     kernels (scores, dq, dkv with the target), no fourth for the loss's
-    gradient."""
+    gradient. ``attn.sparse.grid_steps`` takes the steps of the four
+    tile-walking calls."""
     from paddle_tpu.utils import telemetry
     q, k, v, qi, ki, w, do = operands
     reg = telemetry.default_tracer().metrics
     count = lambda: reg.value("attn.sparse.target_in_backward") or 0
+    steps = lambda: reg.value("attn.sparse.grid_steps") or 0
 
     def loss(*a):
         o, li = sa.learned_sparse_attention(*a, TOPK, SCALE)
@@ -131,9 +152,15 @@ def test_the_backward_pass_counts_the_target_it_folds_in(operands):
     before = count()
     jax.make_jaxpr(loss)(q, k, v, qi, ki, w)
     assert count() == before
+    walked = steps()
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(6))))(
         q, k, v, qi, ki, w))
     assert count() == before + 1
+    # blocks of 512 cut to the sequence: one query tile, one causal tile;
+    # forward and dq a step a (row, head), the loss and dk / dv one a row
+    block = min(512, S)
+    assert steps() - walked == 2 * B * (S // block) * H \
+        + 2 * B * causal_tiles(block, block)
     # forward: scores, selection, attention, rows; backward: the three
     assert text.count("pallas_call[") == 7
     for name in ("sparse_attn_bwd_dq", "sparse_attn_bwd_dkv",
